@@ -15,18 +15,12 @@ severity and the UN001 unit vocabulary::
 Severity semantics: ``error`` findings gate (CLI exit 1), ``warn`` findings
 print but only gate under ``--strict`` (the CI mode), ``info`` findings
 never gate.  ``severity`` accepts either the ``["CODE=level", …]`` list
-form above (parseable by the minimal fallback parser) or a
-``[tool.repro.analysis.severity]`` sub-table when ``tomllib``/``tomli``
-is available.
-
-Python 3.10 has no ``tomllib``; a minimal single-section parser handles the
-subset this block uses (strings, string lists, booleans) when neither
-``tomllib`` nor ``tomli`` is importable — no new dependency.
+form above or a ``[tool.repro.analysis.severity]`` sub-table.
 """
 from __future__ import annotations
 
 import dataclasses
-import re
+import tomllib
 from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
@@ -108,68 +102,6 @@ class AnalysisConfig:
         return DEFAULT_SEVERITY.get(code, "error")
 
 
-def _parse_toml(text: str) -> Dict:
-    try:
-        import tomllib                                   # py311+
-        return tomllib.loads(text)
-    except ImportError:
-        pass
-    try:
-        import tomli
-        return tomli.loads(text)
-    except ImportError:
-        return _parse_section(text, "tool.repro.analysis")
-
-
-def _parse_section(text: str, section: str) -> Dict:
-    """Tiny TOML-subset fallback: one named table of scalars/string lists."""
-    table: Dict = {}
-    in_section = False
-    buf = ""
-    for raw in text.splitlines():
-        line = raw.strip()
-        if line.startswith("["):
-            in_section = line == f"[{section}]"
-            continue
-        if not in_section or not line or line.startswith("#"):
-            continue
-        buf += " " + line
-        if buf.count("[") > buf.count("]"):
-            continue                                     # multi-line list
-        m = re.match(r'\s*([\w.-]+)\s*=\s*(.+)$', buf)
-        buf = ""
-        if not m:
-            continue
-        table[m.group(1)] = _parse_value(m.group(2).strip())
-    # re-nest under the dotted section path so both parsers look alike
-    out: Dict = {}
-    node = out
-    for part in section.split("."):
-        node[part] = {}
-        node = node[part]
-    node.update(table)
-    return out
-
-
-def _parse_value(v: str):
-    v = v.split("#", 1)[0].strip() if not v.startswith(("'", '"', "[")) else v
-    if v.startswith("["):
-        inner = v.strip()[1:-1]
-        items = [s.strip() for s in inner.split(",") if s.strip()]
-        return [_parse_value(s) for s in items]
-    if v.startswith(("'", '"')):
-        return v.strip()[1:-1]
-    if v in ("true", "false"):
-        return v == "true"
-    try:
-        return int(v)
-    except ValueError:
-        try:
-            return float(v)
-        except ValueError:
-            return v
-
-
 def find_root(start: Optional[Path] = None) -> Path:
     """Nearest ancestor holding a ``pyproject.toml`` (else ``start``)."""
     p = (start or Path.cwd()).resolve()
@@ -186,7 +118,7 @@ def load_config(root: Optional[Path] = None) -> AnalysisConfig:
     pyproject = root / "pyproject.toml"
     block: Dict = {}
     if pyproject.is_file():
-        data = _parse_toml(pyproject.read_text())
+        data = tomllib.loads(pyproject.read_text())
         block = data.get("tool", {}).get("repro", {}).get("analysis", {})
 
     def tup(key: str, default: Tuple[str, ...]) -> Tuple[str, ...]:
@@ -207,8 +139,8 @@ def load_config(root: Optional[Path] = None) -> AnalysisConfig:
 
 
 def _parse_severity(val) -> Tuple[Tuple[str, str], ...]:
-    """Per-rule severity overrides: a ``{"SH001": "warn"}`` sub-table (full
-    TOML parsers) or the ``["SH001=warn"]`` list form (fallback parser)."""
+    """Per-rule severity overrides: a ``{"SH001": "warn"}`` sub-table or
+    the ``["SH001=warn"]`` list form."""
     if val is None:
         return ()
     if isinstance(val, dict):

@@ -268,7 +268,41 @@ def _build_opp_tables(db: ResourceDB, apps: Sequence[Application],
 # The per-window DTPM transition — one function, two drivers
 # --------------------------------------------------------------------------
 
-def _window_step(tables: SimTables, valid_j, window, up, cap, A_rc, B_rc,
+def fixed_order_sum(x):
+    """Sum over the leading axis in one fixed pairwise order: the axis is
+    zero-padded to a power of two and halved by elementwise adds.
+
+    Every sum on the result path goes through here, never through a
+    contraction or ``jnp.sum``.  XLA groups the terms of a reduce (and of a
+    dot, which the TPU also runs through bfloat16 passes at DEFAULT
+    precision) differently per backend, compiled lane width and padded
+    extent; this order depends on none of them, and trailing zero terms —
+    inert PE padding (DESIGN.md §5) — leave the result bit-identical.  So
+    a padded, vmapped, sharded or chunked lane equals its single run on
+    any device (DESIGN.md §1, §13).
+    """
+    x = jnp.asarray(x)
+    n = x.shape[0]
+    width = 1 << max(n - 1, 0).bit_length()
+    if width != n:
+        x = jnp.concatenate([x, jnp.zeros((width - n,) + x.shape[1:],
+                                          x.dtype)])
+    while x.shape[0] > 1:
+        half = x.shape[0] // 2
+        x = x[:half] + x[half:]
+    return x[0]
+
+
+def _slot_sum(values, slot, num_slots: int):
+    """(num_slots,) sums of ``values`` grouped by the same-shaped integer
+    ``slot``: a masked :func:`fixed_order_sum`, never a one-hot
+    contraction."""
+    hit = slot[..., None] == jnp.arange(num_slots, dtype=slot.dtype)
+    terms = jnp.where(hit, values[..., None], 0.0)
+    return fixed_order_sum(terms.reshape(-1, num_slots))
+
+
+def _window_step(tables: SimTables, valid_j, window, up, cap, Am1_rc, B_rc,
                  st, carry):
     """One sampling window: utilisation → governor step, window power →
     exact RC step, temperature → throttle clamp (ref kernel order).
@@ -288,26 +322,21 @@ def _window_step(tables: SimTables, valid_j, window, up, cap, A_rc, B_rc,
     ov = jnp.clip(jnp.minimum(st["finish"], w1)
                   - jnp.maximum(st["start"], w0), 0.0, window)
     ov = jnp.where(committed, ov, 0.0)                         # (J, T)
-    dom_oh = jax.nn.one_hot(tables.pe_domain[st["onpe"]],
-                            tables.opp_freq.shape[0],
-                            dtype=jnp.float32)                 # (J, T, C)
     cpu_w = tables.pe_is_cpu[st["onpe"]]                       # (J, T)
-    busy_dom = jnp.einsum("jt,jtc->c", ov * cpu_w, dom_oh)
+    busy_dom = _slot_sum(ov * cpu_w, tables.pe_domain[st["onpe"]],
+                         tables.opp_freq.shape[0])             # (C,)
     util = busy_dom / jnp.maximum(window * tables.domain_cpu, 1e-9)
     proposed = ondemand_index(tables.opp_freq, tables.num_opp, up, util,
                               xp=jnp)
     # realised per-node window power: active at the latched OPP + idle
     P = tables.num_pes
-    pe_oh = jax.nn.one_hot(st["onpe"], P, dtype=jnp.float32)   # (J, T, P)
     p_task = tables.power_active_opp[st["onpe"], st["onopp"]]  # (J, T)
-    e_act = jnp.einsum("jt,jtp->p", ov * p_task, pe_oh)        # (P,) W·us
-    busy_pe = jnp.einsum("jt,jtp->p", ov, pe_oh)
+    e_act = _slot_sum(ov * p_task, st["onpe"], P)              # (P,) W·us
+    busy_pe = _slot_sum(ov, st["onpe"], P)
     idle_frac = 1.0 - jnp.clip(busy_pe / window, 0.0, 1.0)
     p_pe = e_act / window + tables.power_idle * idle_frac      # (P,) W
-    node_oh = jax.nn.one_hot(tables.node_of_pe, _thermal.NUM_NODES,
-                             dtype=jnp.float32)                # (P, 3)
-    node_p = p_pe @ node_oh                                    # (3,) W
-    temps = _thermal.exact_step_jax(temps, node_p, A_rc, B_rc)
+    node_p = _slot_sum(p_pe, tables.node_of_pe, _thermal.NUM_NODES)  # (3,)
+    temps = _thermal.exact_step_jax(temps, node_p, Am1_rc, B_rc)
     peak = jnp.maximum(peak, jnp.max(temps[:3]))
     opp_idx = throttle_index(proposed, temps[tables.domain_node], cap,
                              xp=jnp)
@@ -380,7 +409,7 @@ def _epoch_scan(tables: SimTables, policy: str, num_jobs: int,
         up = jnp.asarray(gov.up_threshold, jnp.float32)
         cap = jnp.asarray(gov.thermal_cap_c, jnp.float32)
         # exact per-window RC update (DESIGN.md §6): unconditionally stable
-        A_rc, B_rc = _thermal.exact_step_matrices_jax(gov.thermal_dt_s)
+        Am1_rc, B_rc = _thermal.exact_step_matrices_jax(gov.thermal_dt_s)
         amb = jnp.asarray(_thermal.T_AMBIENT_C, jnp.float32)
         state.update(
             onopp=jnp.zeros((J, T), jnp.int32),          # OPP latched at commit
@@ -396,7 +425,7 @@ def _epoch_scan(tables: SimTables, policy: str, num_jobs: int,
     def advance_window(st, carry):
         """Advance one sampling window; the telemetry aux is dropped here
         (dead code the compiler eliminates — the program is unchanged)."""
-        return _window_step(tables, valid_j, window, up, cap, A_rc, B_rc,
+        return _window_step(tables, valid_j, window, up, cap, Am1_rc, B_rc,
                             st, carry)[0]
 
     def apply_faults(st, fire):
@@ -544,19 +573,16 @@ def _epoch_scan(tables: SimTables, policy: str, num_jobs: int,
         st = dict(st, opp_idx=opp_idx, next_w=next_w, temps=temps,
                   peak_t=peak)
     job_finish = jnp.max(jnp.where(valid_j, st["finish"], 0.0), axis=1)
-    avg_latency = jnp.mean(job_finish - arrival)
-    # energy: active while busy + idle leakage elsewhere  (uJ = W * us)
-    onpe_oh = (jax.nn.one_hot(st["onpe"], tables.num_pes, dtype=jnp.float32)
-               * jnp.where(valid_j, 1.0, 0.0)[..., None])
-    if dtpm:
-        p_task = tables.power_active_opp[st["onpe"], st["onopp"]]       # (J, T)
-        e_active = jnp.sum(jnp.where(valid_j, busy * p_task, 0.0))
-    else:
-        e_active = jnp.sum(jnp.where(valid_j, busy, 0.0)[..., None]
-                           * onpe_oh * tables.power_active[None, None, :])
-    busy_per_pe = jnp.sum(
-        jnp.where(valid_j, busy, 0.0)[..., None] * onpe_oh, axis=(0, 1))
-    e_idle = jnp.sum(tables.power_idle * jnp.maximum(makespan - busy_per_pe, 0.0))
+    avg_latency = fixed_order_sum(job_finish - arrival) / J
+    # energy: active while busy + idle leakage elsewhere  (uJ = W * us);
+    # active power is gathered per task
+    busy = jnp.where(valid_j, busy, 0.0)
+    p_task = (tables.power_active_opp[st["onpe"], st["onopp"]] if dtpm
+              else tables.power_active[st["onpe"]])                     # (J, T)
+    e_active = fixed_order_sum((busy * p_task).reshape(-1))
+    busy_per_pe = _slot_sum(busy, st["onpe"], tables.num_pes)           # (P,)
+    e_idle = fixed_order_sum(
+        tables.power_idle * jnp.maximum(makespan - busy_per_pe, 0.0))
     energy_j = (e_active + e_idle) * 1e-6                # W·us -> J
 
     out = dict(
@@ -690,11 +716,11 @@ def _telemetry_scan_dtpm(tables: SimTables, gov: GovernorPolicy,
     window = jnp.asarray(gov.sample_window_us, jnp.float32)
     up = jnp.asarray(gov.up_threshold, jnp.float32)
     cap = jnp.asarray(gov.thermal_cap_c, jnp.float32)
-    A_rc, B_rc = _thermal.exact_step_matrices_jax(gov.thermal_dt_s)
+    Am1_rc, B_rc = _thermal.exact_step_matrices_jax(gov.thermal_dt_s)
     st = dict(scheduled=scheduled, start=start, finish=finish,
               onpe=onpe, onopp=onopp)
     step = functools.partial(_window_step, tables, valid_j, window, up, cap,
-                             A_rc, B_rc, st)
+                             Am1_rc, B_rc, st)
     carry0 = (jnp.zeros((C,), jnp.int32), window,
               jnp.full((4,), _thermal.T_AMBIENT_C, jnp.float32),
               jnp.float32(_thermal.T_AMBIENT_C))
@@ -721,13 +747,10 @@ def _telemetry_scan_static(tables: SimTables, app_idx, scheduled, start,
     P = tables.num_pes
     C = num_domains
     window = jnp.asarray(window_us, jnp.float32)
-    A_rc, B_rc = _thermal.exact_step_matrices_jax(window * 1e-6)
+    Am1_rc, B_rc = _thermal.exact_step_matrices_jax(window * 1e-6)
     committed = scheduled & valid_j
-    dom_oh = jax.nn.one_hot(tables.pe_domain[onpe], C, dtype=jnp.float32)
+    dom_of_task = tables.pe_domain[onpe]
     cpu_w = tables.pe_is_cpu[onpe]
-    pe_oh = jax.nn.one_hot(onpe, P, dtype=jnp.float32)
-    node_oh = jax.nn.one_hot(tables.node_of_pe, _thermal.NUM_NODES,
-                             dtype=jnp.float32)
     domain_cpu = jnp.zeros((C,), jnp.float32).at[tables.pe_domain].add(
         tables.pe_is_cpu)
     p_task = tables.power_active[onpe]                         # (J, T)
@@ -739,14 +762,14 @@ def _telemetry_scan_static(tables: SimTables, app_idx, scheduled, start,
         ov = jnp.clip(jnp.minimum(finish, w1) - jnp.maximum(start, w0),
                       0.0, window)
         ov = jnp.where(committed, ov, 0.0)
-        busy_dom = jnp.einsum("jt,jtc->c", ov * cpu_w, dom_oh)
+        busy_dom = _slot_sum(ov * cpu_w, dom_of_task, C)
         util = busy_dom / jnp.maximum(window * domain_cpu, 1e-9)
-        e_act = jnp.einsum("jt,jtp->p", ov * p_task, pe_oh)
-        busy_pe = jnp.einsum("jt,jtp->p", ov, pe_oh)
+        e_act = _slot_sum(ov * p_task, onpe, P)
+        busy_pe = _slot_sum(ov, onpe, P)
         idle_frac = 1.0 - jnp.clip(busy_pe / window, 0.0, 1.0)
         p_pe = e_act / window + tables.power_idle * idle_frac
-        node_p = p_pe @ node_oh
-        temps = _thermal.exact_step_jax(temps, node_p, A_rc, B_rc)
+        node_p = _slot_sum(p_pe, tables.node_of_pe, _thermal.NUM_NODES)
+        temps = _thermal.exact_step_jax(temps, node_p, Am1_rc, B_rc)
         return temps, dict(util=util, power_w=node_p, temps_c=temps)
 
     _, ys = jax.lax.scan(body, jnp.full((4,), _thermal.T_AMBIENT_C,

@@ -127,37 +127,53 @@ def _rc_spectral():
 
 def exact_step_matrices_jax(dt_s):
     """Traceable (jnp) twin of :func:`exact_step_matrices` — the single
-    definition the DTPM kernel and ``dse.thermal_jax`` consume.
+    definition the DTPM kernel and ``dse.thermal_jax`` consume.  Returns
+    ``(A − I, B)``, the increment form :func:`exact_step_jax` applies.
 
-    Computed spectrally — A = Σ e^{λ_j·dt} P_j, B = Σ (e^{λ_j·dt}−1)/λ_j P_j
-    with host-precomputed constant λ/P — instead of a traced ``expm``:
-    elementwise exps plus a fixed-order unrolled sum have lane-batch-width
-    independent rounding, so vmapped thermal lanes are bit-for-bit stable
-    under the sharded/chunked sweep executor (DESIGN.md §13), where XLA's
-    batched-``expm`` linalg was not.
+    Computed spectrally — A − I = Σ expm1(λ_j·dt) P_j, B = Σ expm1(λ_j·dt)/
+    λ_j P_j with host-precomputed constant λ/P — instead of a traced
+    ``expm``: elementwise transcendentals plus a fixed-order unrolled sum
+    have lane-batch-width independent rounding, so vmapped thermal lanes
+    are bit-for-bit stable under the sharded/chunked sweep executor
+    (DESIGN.md §13), where XLA's batched-``expm`` linalg was not.  ``expm1``
+    keeps the small per-window decays exact in float32: ``exp(λ·dt) − 1``
+    cancels all but a few bits when ``|λ·dt|`` ≪ 1 and so magnifies any
+    ``exp`` rounding, which differs between the host and the TPU.
     """
     import jax.numpy as jnp
     lam, proj = _rc_spectral()
     dt = jnp.asarray(dt_s, jnp.float32)
-    A = B = None
+    Am1 = B = None
     for j in range(len(lam)):
         lam_j = jnp.float32(lam[j])
         p_j = jnp.asarray(proj[j], jnp.float32)
-        e_j = jnp.exp(lam_j * dt)
-        a_t = e_j[..., None, None] * p_j
-        b_t = ((e_j - 1.0) / lam_j)[..., None, None] * p_j
-        A = a_t if A is None else A + a_t
+        d_j = jnp.expm1(lam_j * dt)
+        a_t = d_j[..., None, None] * p_j
+        b_t = (d_j / lam_j)[..., None, None] * p_j
+        Am1 = a_t if Am1 is None else Am1 + a_t
         B = b_t if B is None else B + b_t
-    return A, B
+    return Am1, B
 
 
-def exact_step_jax(temps, power_w, A, B):
-    """Traceable twin of :func:`exact_step` on (4,) temps / (3,) node power."""
+def _matvec(M, x):
+    """``M @ x`` as a fixed-order sum of columns: a dot would run through
+    bfloat16 passes on the TPU at DEFAULT precision, in an order that
+    changes with the compiled lane width (DESIGN.md §1)."""
+    out = M[:, 0] * x[0]
+    for k in range(1, M.shape[1]):
+        out = out + M[:, k] * x[k]
+    return out
+
+
+def exact_step_jax(temps, power_w, Am1, B):
+    """Traceable twin of :func:`exact_step` on (4,) temps / (3,) node power:
+    ``x + (A − I)·x + B·u`` with ``(Am1, B)`` from
+    :func:`exact_step_matrices_jax`."""
     import jax.numpy as jnp
     u = jnp.concatenate([
         jnp.asarray(power_w, jnp.float32) / jnp.asarray(C_NODE, jnp.float32),
         jnp.full((1,), T_AMBIENT_C / (R_BOARD_AMB * C_BOARD), jnp.float32)])
-    return A @ temps + B @ u
+    return temps + (_matvec(Am1, temps) + _matvec(B, u))
 
 
 def step(state: ThermalState, power_w: np.ndarray, dt_s: float) -> ThermalState:

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import thermal as _ref
+from ..core.simkernel_jax import fixed_order_sum
 
 T_AMBIENT_C = jnp.float32(_ref.T_AMBIENT_C)
 R_TO_BOARD = jnp.asarray(_ref.R_TO_BOARD, jnp.float32)     # (3,) K/W
@@ -36,7 +37,7 @@ C_BOARD = jnp.float32(_ref.C_BOARD)
 
 def steady_state(power_w: jnp.ndarray) -> jnp.ndarray:
     """Analytical steady state for constant (3,) node power -> (4,) temps."""
-    tb = T_AMBIENT_C + R_BOARD_AMB * jnp.sum(power_w)
+    tb = T_AMBIENT_C + R_BOARD_AMB * fixed_order_sum(power_w)
     return jnp.concatenate([tb + R_TO_BOARD * power_w, tb[None]])
 
 
@@ -89,28 +90,17 @@ def binned_power_trace(start_us: jnp.ndarray, finish_us: jnp.ndarray,
     overlap = (jnp.minimum(f, edges + dt_us)
                - jnp.maximum(s, edges))                            # (J,T,K)
     overlap = jnp.clip(overlap, 0.0, dt_us)
-    pe_onehot = jax.nn.one_hot(onpe, P, dtype=jnp.float32)         # (J,T,P)
-    pe_onehot = pe_onehot * jnp.where(valid, 1.0, 0.0)[..., None]
-    busy = jnp.einsum("jtk,jtp->kp", overlap, pe_onehot)           # (K,P)
+    # masked fixed-order sums, not one-hot contractions (DESIGN.md §1)
+    hit = ((onpe[..., None] == jnp.arange(P, dtype=onpe.dtype))
+           & valid[..., None])                                     # (J,T,P)
+    terms = jnp.where(hit[:, :, None, :], overlap[..., None], 0.0)
+    busy = fixed_order_sum(terms.reshape((-1,) + terms.shape[2:]))  # (K,P)
     util = jnp.clip(busy / dt_us, 0.0, 1.0)
     power_pe = power_active * util + power_idle * (1.0 - util)     # (K,P)
-    node_onehot = jax.nn.one_hot(node_of_pe, _ref.NUM_NODES,
-                                 dtype=jnp.float32)                # (P,3)
-    return power_pe @ node_onehot, dt_us * 1e-6
-
-
-def rc_state_matrix() -> jnp.ndarray:
-    """(4, 4) continuous-time state matrix M of the linear RC network —
-    the jnp view of :func:`repro.core.thermal.rc_state_matrix` (one
-    definition shared with the reference integrator and the DTPM kernels)."""
-    return jnp.asarray(_ref.rc_state_matrix(), jnp.float32)
-
-
-def exact_step_matrices(dt_s) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """(A, B) of the exact piecewise-constant update x' = A x + B u — the
-    ``lax``-traceable twin of ``repro.core.thermal.exact_step_matrices``
-    (one definition, shared with the DTPM kernel's inline thermal loop)."""
-    return _ref.exact_step_matrices_jax(dt_s)
+    on_node = node_of_pe[:, None] == jnp.arange(_ref.NUM_NODES)    # (P,3)
+    node_p = fixed_order_sum(
+        jnp.where(on_node, power_pe[..., None], 0.0).swapaxes(0, 1))  # (K,3)
+    return node_p, dt_us * 1e-6
 
 
 def peak_temperature(power_trace_w: jnp.ndarray, dt_s: jnp.ndarray,
@@ -126,15 +116,13 @@ def peak_temperature(power_trace_w: jnp.ndarray, dt_s: jnp.ndarray,
     makespan is workload-dependent, so no dt bound can be assumed here).
     """
     power_trace_w = jnp.asarray(power_trace_w, jnp.float32)
-    A, B = exact_step_matrices(dt_s)
-    amb_drive = T_AMBIENT_C / (R_BOARD_AMB * C_BOARD)
-    t0 = steady_state(jnp.mean(power_trace_w, axis=0))
+    Am1, B = _ref.exact_step_matrices_jax(dt_s)
     K = power_trace_w.shape[0]
+    t0 = steady_state(fixed_order_sum(power_trace_w) / K)
     idx = jnp.arange(K * repeats, dtype=jnp.int32) % K
 
     def step(temps, k):
-        u = jnp.concatenate([power_trace_w[k] / C_NODE, amb_drive[None]])
-        nxt = A @ temps + B @ u
+        nxt = _ref.exact_step_jax(temps, power_trace_w[k], Am1, B)
         return nxt, jnp.max(nxt[:3])
 
     _, peaks = jax.lax.scan(step, t0, idx)

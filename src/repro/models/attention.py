@@ -21,11 +21,8 @@ import jax.numpy as jnp
 
 from functools import partial
 
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-try:
-    from jax import shard_map                      # jax >= 0.8
-except ImportError:                                # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 from ..configs.base import ModelConfig
 from ..sharding import current_mesh, logical_to_pspec, mesh_axis, shard
@@ -147,12 +144,9 @@ def _attend_blocked(q, k, v, *, causal: bool, window: Optional[int],
 
 def _in_manual_region() -> bool:
     """True inside a partial-manual shard_map (e.g. pipeline 'pod' stages)."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        return am is not None and bool(am.shape) and any(
-            t == jax.sharding.AxisType.Manual for t in am.axis_types)
-    except Exception:       # pragma: no cover
-        return False
+    am = jax.sharding.get_abstract_mesh()
+    return am is not None and bool(am.shape) and any(
+        t == jax.sharding.AxisType.Manual for t in am.axis_types)
 
 
 def _attend_cp(q, k, v, *, causal: bool, softcap: Optional[float],
@@ -245,23 +239,14 @@ def _attend_cp(q, k, v, *, causal: bool, softcap: Optional[float],
 
     # manual ONLY over the sequence axis: batch/model stay auto, so this
     # composes under an outer (pipeline) shard_map that has 'pod' manual
-    mesh_arg = mesh
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and am.shape and any(
-                t == jax.sharding.AxisType.Manual for t in am.axis_types):
-            mesh_arg = am
-    except Exception:       # pragma: no cover — older jax
-        pass
-    kw = dict(mesh=mesh_arg,
-              in_specs=(P(None, seq_ax, None, None), P(None, None, None, None),
-                        P(None, None, None, None)),
-              out_specs=P(None, seq_ax, None, None),
-              axis_names={seq_ax})
-    try:
-        fn = shard_map(body, check_vma=False, **kw)      # jax >= 0.8
-    except TypeError:                                    # pragma: no cover
-        fn = shard_map(body, check_rep=False, **kw)
+    mesh_arg = (jax.sharding.get_abstract_mesh() if _in_manual_region()
+                else mesh)
+    fn = shard_map(body, mesh=mesh_arg,
+                   in_specs=(P(None, seq_ax, None, None),
+                             P(None, None, None, None),
+                             P(None, None, None, None)),
+                   out_specs=P(None, seq_ax, None, None),
+                   axis_names={seq_ax}, check_vma=False)
     return fn(q, k, v)
 
 

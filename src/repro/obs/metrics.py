@@ -152,8 +152,9 @@ def run_manifest(scenario=None, backend: Optional[str] = None,
                  **extra) -> Dict:
     """A self-describing record of one run: what ran, where, how compiled.
 
-    Fields: schema tag, UTC timestamp, python/JAX versions, device platform
-    and kind, total jit compile count plus the full counter/timer snapshot,
+    Fields: schema tag, UTC timestamp, python/JAX versions, device platform,
+    kind and count (an error from JAX's device query propagates), total jit
+    compile count plus the full counter/timer snapshot,
     and — when given — the scenario label/hash and backend.  ``extra``
     key-values (wall times, bench name, …) are merged verbatim.
     """
@@ -164,14 +165,13 @@ def run_manifest(scenario=None, backend: Optional[str] = None,
         "python": _platform.python_version(),
         "host_platform": _platform.platform(),
     }
-    try:
-        import jax
-        man["jax_version"] = jax.__version__
-        man["device_platform"] = jax.default_backend()
-        man["device_kind"] = jax.devices()[0].device_kind
-        man["device_count"] = jax.device_count()
-    except Exception:                                      # noqa: BLE001
-        man["device_platform"] = "unavailable"
+    # no fallback: the manifest names the device the run used, or the
+    # backend's error propagates
+    import jax
+    man["jax_version"] = jax.__version__
+    man["device_platform"] = jax.default_backend()
+    man["device_kind"] = jax.devices()[0].device_kind
+    man["device_count"] = jax.device_count()
     if scenario is not None:
         man["scenario"] = scenario.label()
         man["scenario_hash"] = scenario_hash(scenario)

@@ -1,6 +1,7 @@
-"""Sharded + chunked lane execution for sweep grids (DESIGN.md §13).
+"""The sweep's grid programs and their sharded + chunked lane execution
+(DESIGN.md §13).
 
-The sweep's compiled grid programs are embarrassingly parallel along their
+The compiled grid programs are embarrassingly parallel along their
 leading *lane* axis — the stacked design axis of ``SimTables`` (static
 sweeps) or, when the policy grid is the wide one, the stacked
 :class:`~repro.core.dvfs.GovernorPolicy` axis (dynamic DTPM sweeps).  This
@@ -15,8 +16,7 @@ module scales that axis two ways, composably:
 * **chunked streaming** — lanes stream through ONE compiled program in
   fixed-shape chunks (``sweep(..., chunk=N)``): the stacked lane tensors
   stay host-resident (numpy leaves) and only one chunk is device-resident
-  at a time, with the chunk's input buffers donated back to XLA, so peak
-  device memory is O(chunk), not O(grid).
+  at a time, so peak device memory is O(chunk), not O(grid).
 
 Both paths pad the lane count up to the chunk/device quantum by repeating
 lane 0.  Unlike ``dse.batch``'s *in-kernel* inert padding (BIG latency,
@@ -33,7 +33,6 @@ recent launch), ``scenario.shard.pad_lanes`` (inert lanes added) and
 from __future__ import annotations
 
 import functools
-import warnings
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -52,9 +51,9 @@ shard_devices = _metrics.counter("scenario.shard.devices")
 shard_pad_lanes = _metrics.counter("scenario.shard.pad_lanes")
 # cumulative fixed-shape chunks streamed through the grid programs
 sweep_chunks = _metrics.counter("scenario.sweep.chunks")
-# the sweep's one-program-per-policy-shape trace counter (same registry
-# entry as ``sweep.compile_count``; looked up here, not in the jitted
-# bodies, so the registry is never touched under trace)
+# the sweep's one-program-per-policy-shape trace counter (re-exported as
+# ``sweep.compile_count``; looked up here, not in the jitted bodies, so the
+# registry is never touched under trace)
 _compile_count = _metrics.counter("scenario.sweep.compile_count")
 
 
@@ -88,13 +87,10 @@ def pad_lane_axis(tree, lanes: int, width: int, axis: int = 0):
 
 
 def _device_put_lanes(tree, mesh):
-    """Place a chunk's lane tensors: sharded over the lane mesh when one is
-    installed, default single-device placement otherwise."""
-    if mesh is None:
-        return jax.tree_util.tree_map(jnp.asarray, tree)
-    sharding = lane_sharding(mesh)
-    return jax.tree_util.tree_map(
-        lambda x: jax.device_put(jnp.asarray(x), sharding), tree)
+    """Place a chunk's host lane tensors: straight onto the lane sharding
+    when a mesh is installed (never staged through one device first),
+    default single-device placement otherwise."""
+    return jax.device_put(tree, None if mesh is None else lane_sharding(mesh))
 
 
 def _slice_lanes(tree, lo: int, hi: int, axis: int = 0):
@@ -103,19 +99,17 @@ def _slice_lanes(tree, lo: int, hi: int, axis: int = 0):
 
 
 # --------------------------------------------------------------------------
-# The jitted chunk programs — one trace per (policy shape, chunk width).
-# The lane-chunk arguments are donated: each chunk's buffers are freshly
-# device_put by the streamer, so XLA may reuse them for the outputs and the
-# previous chunk never outlives its step.
+# The grid programs — ONE trace per (policy shape, lane width).  The plain
+# sweep launches them on whole device-resident grids, the streamer below on
+# host-staged (and optionally sharded) chunks.  Nothing is donated: the
+# outputs share no shape with the lane inputs, so no buffer could alias.
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.jit,
-                   static_argnames=("policy", "num_jobs", "bins", "repeats"),
-                   donate_argnames=("tables", "node_of_pe"))
-def _chunk_static(tables, node_of_pe, arrival, app_idx, policy, num_jobs,
-                  bins, repeats):
-    """Static-governor chunk: schedule simulation + RC thermal scan for the
-    (Dc, S) lane chunk — same fused body as ``sweep._sweep_grid``."""
+@functools.partial(jax.jit, static_argnames=("policy", "num_jobs", "bins",
+                                             "repeats"))
+def _sweep_grid(tables, node_of_pe, arrival, app_idx, policy, num_jobs,
+                bins, repeats):
+    """Schedule simulation + thermal scan for (D, S) lanes, ONE program."""
     _compile_count.inc()  # lint: waive JX003 -- deliberate: counts compiles, python body runs per trace
     out = _simulate_grid(tables, policy, num_jobs, arrival, app_idx)
     temps = peak_temperature_grid(out, node_of_pe, tables.power_active,
@@ -124,7 +118,11 @@ def _chunk_static(tables, node_of_pe, arrival, app_idx, policy, num_jobs,
     return out, temps
 
 
-def _dtpm_grid(tables, gov, arrival, app_idx, policy, num_jobs):
+@functools.partial(jax.jit, static_argnames=("policy", "num_jobs"))
+def _sweep_grid_dtpm(tables, gov, arrival, app_idx, policy, num_jobs):
+    """Closed-loop DTPM lanes: (D designs, G policies, S traces) in ONE
+    program.  Peak temperature comes from the kernel's inline RC loop (the
+    one the throttle feedback integrates), so no post-hoc thermal scan."""
     _compile_count.inc()  # lint: waive JX003 -- deliberate: counts compiles, python body runs per trace
     per_trace = jax.vmap(
         lambda tb, g, a, i: _simulate_dtpm(tb, policy, num_jobs, a, i, g),
@@ -134,26 +132,15 @@ def _dtpm_grid(tables, gov, arrival, app_idx, policy, num_jobs):
     return per_design(tables, gov, arrival, app_idx)
 
 
-# Two donation variants of the same DTPM grid: only the streamed lane
-# argument is freshly allocated per chunk (the other is reused across
-# chunks and must not be donated).
-_chunk_dtpm_design = functools.partial(
-    jax.jit, static_argnames=("policy", "num_jobs"),
-    donate_argnames=("tables",))(_dtpm_grid)
-_chunk_dtpm_policy = functools.partial(
-    jax.jit, static_argnames=("policy", "num_jobs"),
-    donate_argnames=("gov",))(_dtpm_grid)
+@functools.partial(jax.jit, static_argnames=("policy", "num_jobs", "bins",
+                                             "repeats", "scan_steps"))
+def _sweep_grid_faults(tables, node_of_pe, fplans, arrival, app_idx, policy,
+                       num_jobs, bins, repeats, scan_steps):
+    """Fail-stop lanes (F fault plans, D designs, S traces), ONE program.
 
-
-@functools.partial(jax.jit,
-                   static_argnames=("policy", "num_jobs", "bins", "repeats",
-                                    "scan_steps"),
-                   donate_argnames=("tables", "node_of_pe"))
-def _chunk_static_faults(tables, node_of_pe, fplans, arrival, app_idx,
-                         policy, num_jobs, bins, repeats, scan_steps):
-    """Fail-stop static chunk: (F, Dc, S) lanes — same fused body as
-    ``sweep._sweep_grid_faults``.  ``fplans`` is NOT donated: the (F, P)
-    plan stack is reused by every chunk (DESIGN.md §14)."""
+    The fault axis is outermost so the design axis stays streamable by the
+    chunked/sharded executor; the thermal scan vmaps per fault lane over the
+    same (D, S) grid program the fault-free path uses (DESIGN.md §14)."""
     _compile_count.inc()  # lint: waive JX003 -- deliberate: counts compiles, python body runs per trace
     out = _simulate_grid_faults(tables, policy, num_jobs, arrival, app_idx,
                                 fplans, scan_steps)
@@ -163,8 +150,12 @@ def _chunk_static_faults(tables, node_of_pe, fplans, arrival, app_idx,
     return out, temps
 
 
-def _dtpm_grid_faults(tables, gov, fplans, arrival, app_idx, policy,
-                      num_jobs, scan_steps):
+@functools.partial(jax.jit,
+                   static_argnames=("policy", "num_jobs", "scan_steps"))
+def _sweep_grid_dtpm_faults(tables, gov, fplans, arrival, app_idx, policy,
+                            num_jobs, scan_steps):
+    """Fail-stop DTPM lanes: (F fault plans, D designs, G policies,
+    S traces) through the closed-loop kernel in ONE program."""
     _compile_count.inc()  # lint: waive JX003 -- deliberate: counts compiles, python body runs per trace
     per_trace = jax.vmap(
         lambda tb, g, a, i, fp: _simulate_dtpm(tb, policy, num_jobs, a, i, g,
@@ -174,16 +165,6 @@ def _dtpm_grid_faults(tables, gov, fplans, arrival, app_idx, policy,
     per_design = jax.vmap(per_policy, in_axes=(0, None, None, None, None))
     per_fault = jax.vmap(per_design, in_axes=(None, None, None, None, 0))
     return per_fault(tables, gov, arrival, app_idx, fplans)
-
-
-# ``fplans`` never donates (reused across chunks), so the faulted DTPM grid
-# keeps the same two lane-donation variants as the fault-free one.
-_chunk_dtpm_design_faults = functools.partial(
-    jax.jit, static_argnames=("policy", "num_jobs", "scan_steps"),
-    donate_argnames=("tables",))(_dtpm_grid_faults)
-_chunk_dtpm_policy_faults = functools.partial(
-    jax.jit, static_argnames=("policy", "num_jobs", "scan_steps"),
-    donate_argnames=("gov",))(_dtpm_grid_faults)
 
 
 # --------------------------------------------------------------------------
@@ -207,12 +188,7 @@ def _stream(lane_tree, lanes: int, chunk: Optional[int], mesh,
             shard_pad_lanes.inc(width - (hi - lo))
             piece = pad_lane_axis(piece, hi - lo, width)
         sweep_chunks.inc()
-        with warnings.catch_warnings():
-            # the CPU backend cannot alias donated buffers and warns per
-            # launch; donation is the accelerator story, the warning is noise
-            warnings.filterwarnings(
-                "ignore", message="Some donated buffers were not usable")
-            outs.append(launch(_device_put_lanes(piece, mesh)))
+        outs.append(launch(_device_put_lanes(piece, mesh)))
     return outs
 
 
@@ -248,14 +224,14 @@ def run_static_grid(tables, node_of_pe, arrival, app_idx, *, policy: str,
     def launch(piece):
         tb, nodes = piece
         if faulted:
-            out, temps = _chunk_static_faults(
+            out, temps = _sweep_grid_faults(
                 tb, nodes, fdev, arrival, app_idx, policy=policy,
                 num_jobs=num_jobs, bins=bins, repeats=repeats,
                 scan_steps=scan_steps)
         else:
-            out, temps = _chunk_static(tb, nodes, arrival, app_idx,
-                                       policy=policy, num_jobs=num_jobs,
-                                       bins=bins, repeats=repeats)
+            out, temps = _sweep_grid(tb, nodes, arrival, app_idx,
+                                     policy=policy, num_jobs=num_jobs,
+                                     bins=bins, repeats=repeats)
         out = dict(out)
         out["_peak_temp_scan_c"] = temps
         return out
@@ -284,11 +260,11 @@ def run_dtpm_grid(tables, gov, arrival, app_idx, *, policy: str,
 
         def launch(tb):
             if faulted:
-                return _chunk_dtpm_design_faults(
+                return _sweep_grid_dtpm_faults(
                     tb, gov_dev, fdev, arrival, app_idx, policy=policy,
                     num_jobs=num_jobs, scan_steps=scan_steps)
-            return _chunk_dtpm_design(tb, gov_dev, arrival, app_idx,
-                                      policy=policy, num_jobs=num_jobs)
+            return _sweep_grid_dtpm(tb, gov_dev, arrival, app_idx,
+                                    policy=policy, num_jobs=num_jobs)
 
         return _concat_out(_stream(tables_h, D, chunk, mesh, launch), D,
                            axis=1 if faulted else 0)
@@ -296,11 +272,11 @@ def run_dtpm_grid(tables, gov, arrival, app_idx, *, policy: str,
 
     def launch(g):
         if faulted:
-            return _chunk_dtpm_policy_faults(
+            return _sweep_grid_dtpm_faults(
                 tables_dev, g, fdev, arrival, app_idx, policy=policy,
                 num_jobs=num_jobs, scan_steps=scan_steps)
-        return _chunk_dtpm_policy(tables_dev, g, arrival, app_idx,
-                                  policy=policy, num_jobs=num_jobs)
+        return _sweep_grid_dtpm(tables_dev, g, arrival, app_idx,
+                                policy=policy, num_jobs=num_jobs)
 
     return _concat_out(_stream(gov_h, G, chunk, mesh, launch), G,
                        axis=2 if faulted else 1)

@@ -35,7 +35,6 @@ oracle lane by lane.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import itertools
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -44,15 +43,13 @@ import numpy as np
 
 from ..core.dvfs import stack_policies
 from ..core.jobgen import JobTrace
-from ..core.simkernel_jax import _simulate_dtpm
-from ..dse.batch import (_simulate_grid, _simulate_grid_faults, pad_node_map,
-                         stack_tables, stack_traces)
+from ..dse.batch import pad_node_map, stack_tables, stack_traces
 from ..dse.space import DesignPoint
-from ..dse.thermal_jax import peak_temperature_grid
-from ..obs import metrics as _metrics
 from ..obs import telemetry as _obs_tel
 from . import faults as _faults
 from . import shardexec
+from .shardexec import (_sweep_grid, _sweep_grid_dtpm,
+                        _sweep_grid_dtpm_faults, _sweep_grid_faults)
 from .config import Scenario, TraceSpec
 from .errors import BackendCapabilityError, LaneAxisError, ScenarioError
 from .result import SweepResult
@@ -73,7 +70,7 @@ _TRACE_FIELDS = {f.name for f in dataclasses.fields(TraceSpec)}
 # The registered obs counter IS the module attribute — read it via
 # ``compile_count.value`` / the ``obs.metrics`` registry (DESIGN.md §11;
 # the deprecated ``compile_count[0]`` list alias is gone).
-compile_count = _metrics.counter("scenario.sweep.compile_count")
+compile_count = shardexec._compile_count
 
 
 def _canon(name: str) -> str:
@@ -127,68 +124,6 @@ def _lane_trace(scn: Scenario, names: Sequence[str],
     return scn.job_trace()
 
 
-@functools.partial(jax.jit, static_argnames=("policy", "num_jobs", "bins",
-                                             "repeats"))
-def _sweep_grid(tables, node_of_pe, arrival, app_idx, policy, num_jobs,
-                bins, repeats):
-    """Schedule simulation + thermal scan for (D, S) lanes, ONE program."""
-    compile_count.inc()  # lint: waive JX003 -- deliberate: counts compiles, python body runs per trace
-    out = _simulate_grid(tables, policy, num_jobs, arrival, app_idx)
-    temps = peak_temperature_grid(out, node_of_pe, tables.power_active,
-                                  tables.power_idle, bins=bins,
-                                  repeats=repeats)
-    return out, temps
-
-
-@functools.partial(jax.jit, static_argnames=("policy", "num_jobs"))
-def _sweep_grid_dtpm(tables, gov, arrival, app_idx, policy, num_jobs):
-    """Closed-loop DTPM lanes: (D designs, G policies, S traces) in ONE
-    program.  Peak temperature comes from the kernel's inline RC loop (the
-    one the throttle feedback integrates), so no post-hoc thermal scan."""
-    compile_count.inc()  # lint: waive JX003 -- deliberate: counts compiles, python body runs per trace
-    per_trace = jax.vmap(
-        lambda tb, g, a, i: _simulate_dtpm(tb, policy, num_jobs, a, i, g),
-        in_axes=(None, None, 0, 0))
-    per_policy = jax.vmap(per_trace, in_axes=(None, 0, None, None))
-    per_design = jax.vmap(per_policy, in_axes=(0, None, None, None))
-    return per_design(tables, gov, arrival, app_idx)
-
-
-@functools.partial(jax.jit, static_argnames=("policy", "num_jobs", "bins",
-                                             "repeats", "scan_steps"))
-def _sweep_grid_faults(tables, node_of_pe, fplans, arrival, app_idx, policy,
-                       num_jobs, bins, repeats, scan_steps):
-    """Fail-stop lanes (F fault plans, D designs, S traces), ONE program.
-
-    The fault axis is outermost so the design axis stays streamable by the
-    chunked/sharded executor; the thermal scan vmaps per fault lane over the
-    same (D, S) grid program the fault-free path uses (DESIGN.md §14)."""
-    compile_count.inc()  # lint: waive JX003 -- deliberate: counts compiles, python body runs per trace
-    out = _simulate_grid_faults(tables, policy, num_jobs, arrival, app_idx,
-                                fplans, scan_steps)
-    temps = jax.vmap(lambda o: peak_temperature_grid(
-        o, node_of_pe, tables.power_active, tables.power_idle, bins=bins,
-        repeats=repeats))(out)
-    return out, temps
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("policy", "num_jobs", "scan_steps"))
-def _sweep_grid_dtpm_faults(tables, gov, fplans, arrival, app_idx, policy,
-                            num_jobs, scan_steps):
-    """Fail-stop DTPM lanes: (F fault plans, D designs, G policies,
-    S traces) through the closed-loop kernel in ONE program."""
-    compile_count.inc()  # lint: waive JX003 -- deliberate: counts compiles, python body runs per trace
-    per_trace = jax.vmap(
-        lambda tb, g, a, i, fp: _simulate_dtpm(tb, policy, num_jobs, a, i, g,
-                                               fp, scan_steps=scan_steps),
-        in_axes=(None, None, 0, 0, None))
-    per_policy = jax.vmap(per_trace, in_axes=(None, 0, None, None, None))
-    per_design = jax.vmap(per_policy, in_axes=(0, None, None, None, None))
-    per_fault = jax.vmap(per_design, in_axes=(None, None, None, None, 0))
-    return per_fault(tables, gov, arrival, app_idx, fplans)
-
-
 def _design_lanes(base: Scenario, design_axes: List[str],
                   combos: List[Tuple], pad_pes: Optional[int],
                   host: bool = False):
@@ -234,8 +169,7 @@ def sweep(scenario: Scenario, axes: Dict[str, Sequence],
     a ``NamedSharding`` over ``repro.sharding.lane_mesh()`` (default
     ``None`` = auto — shard exactly when more than one device is present;
     ``False`` pins the single-device path); ``chunk=N`` streams the lanes
-    through ONE compiled program in fixed-shape N-lane chunks with donated
-    input buffers, bounding peak device memory at O(chunk) instead of
+    through ONE compiled program in fixed-shape N-lane chunks, bounding peak device memory at O(chunk) instead of
     O(grid).  Both are bit-for-bit equal to the unsharded sweep — lanes are
     independent, and uneven lane counts are padded with inert (dropped)
     lanes — and neither adds compiles per policy shape.

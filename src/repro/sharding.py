@@ -102,13 +102,10 @@ def shard(x: jax.Array, *axes: Optional[str]) -> jax.Array:
     if rules is None or mesh is None:
         return x
     m = mesh
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and am.shape and any(
-                t == jax.sharding.AxisType.Manual for t in am.axis_types):
-            m = am
-    except Exception:       # pragma: no cover — older jax
-        pass
+    am = jax.sharding.get_abstract_mesh()
+    if am is not None and am.shape and any(
+            t == jax.sharding.AxisType.Manual for t in am.axis_types):
+        m = am
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(m, logical_to_pspec(axes)))
 

@@ -11,7 +11,7 @@ import pytest
 from repro.core import build_tables, poisson_trace, thermal, \
     wifi_tx, get_application
 # kernels imported directly: the re-exports are deprecation shims
-from repro.core.simkernel_jax import simulate_jax
+from repro.core.simkernel_jax import fixed_order_sum, simulate_jax
 from repro.dse.batch import simulate_design_batch
 from repro.dse import (DesignPoint, DesignSpace, binned_power_trace,
                        build_design_batch, crowding_distance, evaluate,
@@ -102,6 +102,21 @@ def test_neighbors_stay_in_space():
 
 
 # ------------------------------------------------- batched kernel equivalence
+
+def test_fixed_order_sum_ignores_padding_and_lane_width():
+    """The result-path sum: trailing zero terms (inert padding) and vmapped
+    lanes of any width leave it bit-identical; it is a float32 sum."""
+    import jax
+    x = np.random.default_rng(0).random((13, 5)).astype(np.float32)
+    s = np.asarray(fixed_order_sum(x))
+    padded = np.concatenate([x, np.zeros((7, 5), np.float32)])
+    np.testing.assert_array_equal(np.asarray(fixed_order_sum(padded)), s)
+    for lanes in (1, 3, 64):
+        batched = jax.vmap(fixed_order_sum)(np.stack([x] * lanes))
+        np.testing.assert_array_equal(np.asarray(batched)[lanes - 1], s)
+    np.testing.assert_allclose(s, x.astype(np.float64).sum(axis=0),
+                               rtol=1e-6)
+
 
 @pytest.mark.parametrize("policy", ["met", "etf"])
 def test_padded_batch_matches_per_design(policy):

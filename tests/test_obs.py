@@ -179,6 +179,19 @@ def test_result_manifest_attached():
         assert "timestamp" in man and "device_platform" in man
 
 
+def test_manifest_propagates_device_errors(monkeypatch):
+    """A manifest names the device it ran on or fails — it never records a
+    placeholder platform when JAX cannot report one."""
+    import jax
+
+    def no_backend(*args, **kwargs):
+        raise RuntimeError("no backend")
+
+    monkeypatch.setattr(jax, "devices", no_backend)
+    with pytest.raises(RuntimeError, match="no backend"):
+        metrics.run_manifest()
+
+
 def test_sweep_telemetry_lanes_match_run():
     """Sweep timelines replay the grid outputs — every lane equals its
     single-scenario ``run(..., telemetry=True)``, without re-simulating."""

@@ -11,14 +11,18 @@ import os
 import subprocess
 import sys
 import textwrap
+import warnings
 
+import jax
 import numpy as np
 import pytest
+from jax.sharding import Mesh
 
 from repro.dse import DesignPoint
 from repro.obs import metrics
 from repro.scenario import Scenario, TraceSpec, sweep
 from repro.scenario import shardexec
+from repro.sharding import LANE_AXIS, lane_sharding
 
 SCN = Scenario(apps=("wifi_tx",), scheduler="etf", governor="design",
                trace=TraceSpec(rate_jobs_per_ms=25.0, num_jobs=16, seed=3))
@@ -130,6 +134,45 @@ def test_chunk_validation():
         sweep(SCN, axes={"seed": [0]}, backend="ref", chunk=2)
     with pytest.raises(ValueError, match="jax-backend lane options"):
         sweep(SCN, axes={"seed": [0]}, backend="ref", shard=True)
+
+
+def test_device_put_lanes_places_host_numpy_on_the_lane_sharding(
+        monkeypatch):
+    """A host chunk goes straight onto the lane sharding in one placement,
+    not first onto one device and then resharded."""
+    mesh = Mesh(np.asarray(jax.devices()[:1]), (LANE_AXIS,))
+    calls = []
+    real_put = jax.device_put
+
+    def spy(x, device=None, **kw):
+        calls.append((x, device))
+        return real_put(x, device, **kw)
+
+    monkeypatch.setattr(jax, "device_put", spy)
+    tree = {"a": np.arange(8.0).reshape(4, 2), "b": np.arange(4)}
+    out = shardexec._device_put_lanes(tree, mesh)
+    (placed, where), = calls
+    assert all(isinstance(x, np.ndarray)
+               for x in jax.tree_util.tree_leaves(placed))
+    assert where == lane_sharding(mesh)
+    assert out["a"].sharding == lane_sharding(mesh)
+    np.testing.assert_array_equal(np.asarray(out["a"]), tree["a"])
+
+
+def test_streaming_hides_no_warnings():
+    """The streamer passes every launch warning through (a donation the
+    backend cannot use is reported, not silenced), and the grid programs
+    donate nothing, so a chunked sweep lowers without that warning."""
+    def launch(piece):
+        warnings.warn("Some donated buffers were not usable: float32[4].")
+        return {"x": np.asarray(piece["a"])}
+
+    with pytest.warns(UserWarning, match="donated buffers"):
+        shardexec._stream({"a": np.arange(3.0)}, 3, None, None, launch)
+    scn = SCN.replace(**{"trace.num_jobs": 9})        # a fresh program shape
+    with warnings.catch_warnings():
+        warnings.filterwarnings("error", message=".*donated buffers")
+        sweep(scn, axes={"design": POINTS[:3], "seed": [0]}, chunk=2)
 
 
 def test_resolve_mesh_single_device():
