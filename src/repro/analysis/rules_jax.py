@@ -7,9 +7,11 @@
 * **JX002 host-numpy-in-jit** — ``np.*`` calls fed traced data inside jitted
   code pull the value to host per call (or fail to trace); use ``jnp``.
 * **JX003 impure-jit** — side effects in a jitted python body run once per
-  *compile*, not per call: printing, wall-clock reads, host RNG, ``global``
-  / ``self`` mutation and module-global mutation are almost always bugs (the
-  deliberate compile-counter exception carries a waiver).
+  *compile*, not per call: printing, wall-clock reads, host RNG, host
+  profiler spans (``repro.obs.metrics.span``,
+  ``jax.profiler.TraceAnnotation``), ``global`` / ``self`` mutation and
+  module-global mutation are almost always bugs (the deliberate
+  compile-counter exception carries a waiver).
 
 Whether a value is "traced" is approximated by taint: function parameters
 (minus every name seen in a ``static_argnames``) and anything assigned from
@@ -31,6 +33,7 @@ _LEAK_METHODS = {"item", "tolist"}
 _TIME_CALLS = {"time.time", "time.perf_counter", "time.monotonic",
                "time.process_time", "time.sleep", "time.monotonic_ns",
                "time.perf_counter_ns"}
+_SPAN_CALLS = {"repro.obs.metrics.span", "jax.profiler.TraceAnnotation"}
 _GLOBAL_MUTATORS = {"inc", "dec", "append", "add", "update", "extend",
                     "insert", "remove", "clear", "setdefault", "pop",
                     "reset"}
@@ -209,6 +212,11 @@ def _check_unit(unit: Unit, tainted: Set[str],
                 emit("JX003", node,
                      f"wall-clock read `{d}` inside jitted code executes "
                      f"once per compile, not per call")
+            elif d in _SPAN_CALLS:
+                emit("JX003", node,
+                     f"host span `{d}` inside jitted code records once per "
+                     f"compile, not per call; put it around the jitted "
+                     f"call on the host")
             elif d.startswith("numpy.random.") or d.startswith("random."):
                 emit("JX003", node,
                      f"host RNG `{d}` inside jitted code is baked in at "

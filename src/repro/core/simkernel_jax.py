@@ -39,6 +39,7 @@ from .power import active_power, idle_power
 from .resources import NOMINAL_FREQ, ResourceDB
 from . import thermal as _thermal
 from ..obs.metrics import counter as _obs_counter
+from ..obs.metrics import span as _obs_span
 
 BIG = jnp.float32(1e30)
 
@@ -644,16 +645,18 @@ def simulate_jax(tables: SimTables, policy: str, arrival: np.ndarray,
     program (DESIGN.md §14), bit-for-bit equal to the reference kernel's
     rollback semantics on comm-free traces.
     """
-    J = int(arrival.shape[0])
-    if faults is None:
-        return _simulate(tables, policy, J,
-                         jnp.asarray(arrival, jnp.float32),
-                         jnp.asarray(app_idx, jnp.int32))
-    return _simulate(tables, policy, J,
-                     jnp.asarray(arrival, jnp.float32),
-                     jnp.asarray(app_idx, jnp.int32),
-                     jnp.asarray(faults, jnp.float32),
-                     scan_steps=_fault_steps(J, tables.t_max, faults))
+    with _obs_span("repro.stage"):
+        J = int(arrival.shape[0])
+        arrival = jnp.asarray(arrival, jnp.float32)
+        app_idx = jnp.asarray(app_idx, jnp.int32)
+        if faults is not None:
+            steps = _fault_steps(J, tables.t_max, faults)
+            faults = jnp.asarray(faults, jnp.float32)
+    with _obs_span("repro.launch"):
+        if faults is None:
+            return _simulate(tables, policy, J, arrival, app_idx)
+        return _simulate(tables, policy, J, arrival, app_idx, faults,
+                         scan_steps=steps)
 
 
 def simulate_jax_dtpm(tables: SimTables, policy: str, arrival: np.ndarray,
@@ -672,18 +675,20 @@ def simulate_jax_dtpm(tables: SimTables, policy: str, arrival: np.ndarray,
     if not gov.dynamic:
         raise ValueError("static governors bake into the tables; use "
                          "simulate_jax (DESIGN.md §7)")
-    validate_policy_params(gov.sample_window_us, gov.up_threshold,
-                           gov.thermal_dt_s)
-    J = int(arrival.shape[0])
-    if faults is None:
-        return _simulate_dtpm(tables, policy, J,
-                              jnp.asarray(arrival, jnp.float32),
-                              jnp.asarray(app_idx, jnp.int32), gov)
-    return _simulate_dtpm(tables, policy, J,
-                          jnp.asarray(arrival, jnp.float32),
-                          jnp.asarray(app_idx, jnp.int32), gov,
-                          jnp.asarray(faults, jnp.float32),
-                          scan_steps=_fault_steps(J, tables.t_max, faults))
+    with _obs_span("repro.stage"):
+        validate_policy_params(gov.sample_window_us, gov.up_threshold,
+                               gov.thermal_dt_s)
+        J = int(arrival.shape[0])
+        arrival = jnp.asarray(arrival, jnp.float32)
+        app_idx = jnp.asarray(app_idx, jnp.int32)
+        if faults is not None:
+            steps = _fault_steps(J, tables.t_max, faults)
+            faults = jnp.asarray(faults, jnp.float32)
+    with _obs_span("repro.launch"):
+        if faults is None:
+            return _simulate_dtpm(tables, policy, J, arrival, app_idx, gov)
+        return _simulate_dtpm(tables, policy, J, arrival, app_idx, gov,
+                              faults, scan_steps=steps)
 
 
 def simulate_batch(tables: SimTables, policy: str, arrival: np.ndarray,

@@ -23,6 +23,7 @@ from ..core.dvfs import Governor
 from ..core.jobgen import JobTrace
 from ..core.simkernel_jax import SimTables, _simulate, build_tables
 from ..core.thermal import NODE_ACCEL, cluster_nodes
+from ..obs import metrics as _metrics
 from .space import DesignPoint
 
 
@@ -84,32 +85,40 @@ def build_design_batch(points: Sequence[DesignPoint],
     kernel gathers from, with each design's OPP ladder truncated at its
     per-cluster frequency caps — so Pareto search ranks dynamic policies
     under the design's static envelope, not just static caps.
+
+    Host spans (DESIGN.md §11): ``repro.tables.build`` covers ``to_db`` and
+    the per-design ``build_tables`` loop, ``repro.tables.stack`` the
+    stacking and the node map.
     """
     if not points:
         raise ValueError("empty design list")
-    dbs = [p.to_db() for p in points]
-    P = max(db.num_pes for db in dbs)
-    if pad_pes is not None:
-        if pad_pes < P:
-            raise ValueError(f"pad_pes={pad_pes} < widest design {P}")
-        P = pad_pes
-    if governor is not None:
-        if not governor.policy().dynamic:
-            # a uniform static governor would silently override the
-            # per-design frequency caps the sweep contract assumes
-            raise ValueError(
-                "build_design_batch bakes per-design frequency caps; pass "
-                "a dynamic (ondemand-family) governor to add OPP ladders, "
-                "or None for the static design-cap tables")
-        per_design = [
-            build_tables(db, apps, governor=governor, pad_pes=P,
-                         freq_caps=p.freq_caps())
-            for p, db in zip(points, dbs)]
-    else:
-        per_design = [build_tables(db, apps, governor=p.governor(), pad_pes=P)
-                      for p, db in zip(points, dbs)]
-    return DesignBatch(points=tuple(points), tables=stack_tables(per_design),
-                       node_of_pe=pad_node_map(dbs, P))
+    with _metrics.span("repro.tables.build"):
+        dbs = [p.to_db() for p in points]
+        P = max(db.num_pes for db in dbs)
+        if pad_pes is not None:
+            if pad_pes < P:
+                raise ValueError(f"pad_pes={pad_pes} < widest design {P}")
+            P = pad_pes
+        if governor is not None:
+            if not governor.policy().dynamic:
+                # a uniform static governor would silently override the
+                # per-design frequency caps the sweep contract assumes
+                raise ValueError(
+                    "build_design_batch bakes per-design frequency caps; "
+                    "pass a dynamic (ondemand-family) governor to add OPP "
+                    "ladders, or None for the static design-cap tables")
+            per_design = [
+                build_tables(db, apps, governor=governor, pad_pes=P,
+                             freq_caps=p.freq_caps())
+                for p, db in zip(points, dbs)]
+        else:
+            per_design = [build_tables(db, apps, governor=p.governor(),
+                                       pad_pes=P)
+                          for p, db in zip(points, dbs)]
+    with _metrics.span("repro.tables.stack"):
+        return DesignBatch(points=tuple(points),
+                           tables=stack_tables(per_design),
+                           node_of_pe=pad_node_map(dbs, P))
 
 
 def stack_traces(traces: Sequence[JobTrace]) -> Tuple[jnp.ndarray, jnp.ndarray]:

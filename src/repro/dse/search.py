@@ -133,7 +133,18 @@ def evaluate(points: Sequence[DesignPoint], apps: Sequence[Application],
     land on ``EvalResult.degraded_*``, and ``objectives()`` grows the
     degraded-latency column so the Pareto front trades peak performance
     against graceful degradation (DESIGN.md §14).
+
+    The whole call is the host span ``repro.evaluate`` (DESIGN.md §11).
     """
+    with _metrics.span("repro.evaluate"):
+        return _evaluate(points, apps, traces, policy, thermal_bins,
+                         thermal_repeats, pad_pes, batch, governor,
+                         governor_params, chunk, shard, faults)
+
+
+def _evaluate(points, apps, traces, policy, thermal_bins, thermal_repeats,
+              pad_pes, batch, governor, governor_params, chunk, shard,
+              faults) -> EvalResult:
     # lazy import: repro.scenario builds on repro.dse, not the reverse
     from ..scenario import Scenario, ThermalSpec
     from ..scenario.sweep import sweep
@@ -265,7 +276,6 @@ def pareto_search(space: DesignSpace, apps: Sequence[Application],
                                      pad_pes=pad_pes, **eval_kw) if halving
                   else evaluate(candidates, apps, traces, policy,
                                 pad_pes=pad_pes, **eval_kw))
-        _metrics.counter("dse.search.designs_evaluated").inc(ev.num_designs)
         archive = ev if archive is None else _concat(archive, ev)
         front = archive.front_mask()
         round_stats.append(dict(round=rnd, evaluated=ev.num_designs,

@@ -13,12 +13,17 @@ never per-step work.
 to every ``Result`` and every ``BENCH_*.json`` so perf artifacts are
 self-describing.
 
+:func:`span` names a stretch of host code on the profiler's clock
+(``jax.profiler.TraceAnnotation``), so a device trace shows what the host
+was doing while the device waited (DESIGN.md §11).
+
 This module is stdlib-only at import time (JAX is imported lazily inside
-``run_manifest``): the simulation kernels import it for their compile
-counters, so it must not import them back.
+``span`` and ``run_manifest``): the simulation kernels import it for their
+compile counters, so it must not import them back.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import platform as _platform
 import time
@@ -117,6 +122,23 @@ def timer(name: str) -> Timer:
     if t is None:
         t = _TIMERS[name] = Timer(name)
     return t
+
+
+@functools.lru_cache(maxsize=None)
+def _trace_annotation():
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
+
+
+def span(name: str):
+    """``with span("repro.<layer>[.<what>]"): ...`` — a host span on the
+    profiler's clock (``jax.profiler.TraceAnnotation``).  It records only
+    while a profiler trace is running and costs about half a microsecond
+    when none is (DESIGN.md §11).  Host code only: inside a jitted body it would record once
+    per compile, not per call (lint JX003).  Spans nest by time on the
+    calling thread; put them at stage boundaries, never per design or per
+    task in a loop."""
+    return _trace_annotation()(name)
 
 
 def snapshot() -> Dict[str, Dict[str, float]]:

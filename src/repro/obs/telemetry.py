@@ -35,6 +35,7 @@ from ..core import thermal as _thermal
 from ..core.dvfs import capped_levels
 from ..core.power import active_power, idle_power
 from ..core.resources import ResourceDB
+from .metrics import span
 
 TELEMETRY_SCHEMA = "repro.obs/telemetry/v1"
 
@@ -207,6 +208,7 @@ def jax_dtpm_telemetry(tables, gov, out: Dict, app_idx) -> Telemetry:
     onpe/onopp/makespan_us``).  The window axis is bucketed to the next power
     of two for jit-cache stability and truncated back to the real count.
     """
+    import jax
     import jax.numpy as jnp
     from ..core.simkernel_jax import _telemetry_scan_dtpm
 
@@ -215,19 +217,24 @@ def jax_dtpm_telemetry(tables, gov, out: Dict, app_idx) -> Telemetry:
     if W == 0:
         C = int(tables.opp_freq.shape[0])
         return _empty(window, C)
-    ys = _telemetry_scan_dtpm(tables, gov, jnp.asarray(app_idx, jnp.int32),
-                              out["scheduled"], out["start"], out["finish"],
-                              out["onpe"], out["onopp"],
-                              num_windows=_bucket_pow2(W))
-    freq_idx = np.asarray(ys["opp_idx"])[:W]
-    opp_freq = np.asarray(tables.opp_freq)                       # (C, K)
-    C = opp_freq.shape[0]
-    freq_ghz = opp_freq[np.arange(C)[None, :], freq_idx]         # (W, C)
-    return Telemetry(window, freq_idx.astype(np.int32),
-                     freq_ghz.astype(np.float32),
-                     np.asarray(ys["util"])[:W],
-                     np.asarray(ys["power_w"])[:W],
-                     np.asarray(ys["temps_c"])[:W])
+    with span("repro.launch"):
+        ys = _telemetry_scan_dtpm(tables, gov,
+                                  jnp.asarray(app_idx, jnp.int32),
+                                  out["scheduled"], out["start"],
+                                  out["finish"], out["onpe"], out["onopp"],
+                                  num_windows=_bucket_pow2(W))
+    with span("repro.wait"):
+        jax.block_until_ready(ys)
+    with span("repro.assemble"):
+        freq_idx = np.asarray(ys["opp_idx"])[:W]
+        opp_freq = np.asarray(tables.opp_freq)                   # (C, K)
+        C = opp_freq.shape[0]
+        freq_ghz = opp_freq[np.arange(C)[None, :], freq_idx]     # (W, C)
+        return Telemetry(window, freq_idx.astype(np.int32),
+                         freq_ghz.astype(np.float32),
+                         np.asarray(ys["util"])[:W],
+                         np.asarray(ys["power_w"])[:W],
+                         np.asarray(ys["temps_c"])[:W])
 
 
 def jax_static_telemetry(db: ResourceDB, governor, tables, out: Dict,
@@ -236,6 +243,7 @@ def jax_static_telemetry(db: ResourceDB, governor, tables, out: Dict,
     """Telemetry for one static-governor ``simulate_jax`` run: the window
     observables at the tables' fixed OPP; frequency columns are governor
     constants (see :func:`static_freq_columns`)."""
+    import jax
     import jax.numpy as jnp
     from ..core.simkernel_jax import _telemetry_scan_static
 
@@ -246,17 +254,22 @@ def jax_static_telemetry(db: ResourceDB, governor, tables, out: Dict,
     W = num_windows_for(float(out["makespan_us"]), window)
     if W == 0:
         return _empty(window, C)
-    ys = _telemetry_scan_static(tables, jnp.asarray(app_idx, jnp.int32),
-                                out["scheduled"], out["start"], out["finish"],
-                                out["onpe"], window,
-                                num_windows=_bucket_pow2(W), num_domains=C)
-    f_ghz, f_idx = static_freq_columns(db, governor, C)
-    return Telemetry(window,
-                     np.broadcast_to(f_idx, (W, C)).copy(),
-                     np.broadcast_to(f_ghz, (W, C)).copy(),
-                     np.asarray(ys["util"])[:W],
-                     np.asarray(ys["power_w"])[:W],
-                     np.asarray(ys["temps_c"])[:W])
+    with span("repro.launch"):
+        ys = _telemetry_scan_static(tables, jnp.asarray(app_idx, jnp.int32),
+                                    out["scheduled"], out["start"],
+                                    out["finish"], out["onpe"], window,
+                                    num_windows=_bucket_pow2(W),
+                                    num_domains=C)
+    with span("repro.wait"):
+        jax.block_until_ready(ys)
+    with span("repro.assemble"):
+        f_ghz, f_idx = static_freq_columns(db, governor, C)
+        return Telemetry(window,
+                         np.broadcast_to(f_idx, (W, C)).copy(),
+                         np.broadcast_to(f_ghz, (W, C)).copy(),
+                         np.asarray(ys["util"])[:W],
+                         np.asarray(ys["power_w"])[:W],
+                         np.asarray(ys["temps_c"])[:W])
 
 
 def _empty(window_us: float, C: int) -> Telemetry:

@@ -126,7 +126,15 @@ def run(scenario: Scenario, backend: str = "ref", *,
     backend replays the kernel's window carry as a separate jitted scan —
     the simulation program and its outputs are identical either way
     (asserted in tests/test_obs.py).
+
+    The whole call is the host span ``repro.run`` (DESIGN.md §11).
     """
+    with _metrics.span("repro.run"):
+        return _run(scenario, backend, trace_override, telemetry)
+
+
+def _run(scenario: Scenario, backend: str, trace_override,
+         telemetry: Optional[bool]) -> Result:
     want_tel = scenario.telemetry if telemetry is None else bool(telemetry)
 
     if backend == "ref":
@@ -148,19 +156,23 @@ def run(scenario: Scenario, backend: str = "ref", *,
         result = Result.from_ref(scenario, db, res, telemetry=tel)
 
     elif backend == "jax":
-        # no-op fault specs (empty / all-inf) normalise to plan=None here, so
-        # they take the exact fault-free program — same trace, same cache key
-        # (the §14 no-op contract, asserted via sweep.compile_count in tests).
-        plan = _faults.fault_plan(scenario.failures, scenario.design.num_pes)
-        if plan is not None and scenario.scheduler == "table":
-            raise BackendCapabilityError(
-                "fail-stop injection with the 'table' scheduler", "jax",
-                "backend='ref'",
-                detail="the offline ILP table pins tasks to PEs, so dead-PE "
-                       "fallback needs the runtime schedulers (met/etf)")
-        tables = tables_for(scenario)
-        trace = trace_override or scenario.job_trace()
-        pol = scenario.make_policy()
+        with _metrics.span("repro.stage"):
+            # no-op fault specs (empty / all-inf) normalise to plan=None
+            # here, so they take the exact fault-free program — same trace,
+            # same cache key (the §14 no-op contract, asserted via
+            # sweep.compile_count in tests).
+            plan = _faults.fault_plan(scenario.failures,
+                                      scenario.design.num_pes)
+            if plan is not None and scenario.scheduler == "table":
+                raise BackendCapabilityError(
+                    "fail-stop injection with the 'table' scheduler", "jax",
+                    "backend='ref'",
+                    detail="the offline ILP table pins tasks to PEs, so "
+                           "dead-PE fallback needs the runtime schedulers "
+                           "(met/etf)")
+            tables = tables_for(scenario)
+            trace = trace_override or scenario.job_trace()
+            pol = scenario.make_policy()
         if pol.dynamic:
             if plan is not None and want_tel:
                 raise BackendCapabilityError(
@@ -171,29 +183,41 @@ def run(scenario: Scenario, backend: str = "ref", *,
             out = _jaxk.simulate_jax_dtpm(tables, scenario.scheduler,
                                           trace.arrival_us, trace.app_index,
                                           pol, faults=plan)
+            with _metrics.span("repro.wait"):
+                jax.block_until_ready(out)
             tel = (_obs_tel.jax_dtpm_telemetry(tables, pol, out,
                                                trace.app_index)
                    if want_tel else None)
-            result = Result.from_jax(scenario, out, scenario.design.num_pes,
-                                     float(out["peak_temp_c"]), telemetry=tel)
+            with _metrics.span("repro.assemble"):
+                result = Result.from_jax(scenario, out,
+                                         scenario.design.num_pes,
+                                         float(out["peak_temp_c"]),
+                                         telemetry=tel)
         else:
             out = _jaxk.simulate_jax(tables, scenario.scheduler,
                                      trace.arrival_us, trace.app_index,
                                      faults=plan)
-            peak = _peak_temp_single(
-                out["start"], out["finish"], out["onpe"], out["scheduled"],
-                _cached_nodes(scenario.design),
-                tables.power_active, tables.power_idle, out["makespan_us"],
-                bins=scenario.thermal.bins, repeats=scenario.thermal.repeats)
+            with _metrics.span("repro.launch"):
+                peak = _peak_temp_single(
+                    out["start"], out["finish"], out["onpe"],
+                    out["scheduled"], _cached_nodes(scenario.design),
+                    tables.power_active, tables.power_idle,
+                    out["makespan_us"], bins=scenario.thermal.bins,
+                    repeats=scenario.thermal.repeats)
+            with _metrics.span("repro.wait"):
+                jax.block_until_ready((out, peak))
             tel = (_obs_tel.jax_static_telemetry(
                        scenario.soc(), scenario.make_governor(), tables, out,
                        trace.app_index)
                    if want_tel else None)
-            result = Result.from_jax(scenario, out, scenario.design.num_pes,
-                                     float(peak), telemetry=tel)
+            with _metrics.span("repro.assemble"):
+                result = Result.from_jax(scenario, out,
+                                         scenario.design.num_pes,
+                                         float(peak), telemetry=tel)
     else:
         raise ScenarioError(f"unknown backend {backend!r}; have {BACKENDS}")
 
-    result.manifest = _metrics.run_manifest(scenario=scenario,
-                                            backend=backend)
+    with _metrics.span("repro.assemble"):
+        result.manifest = _metrics.run_manifest(scenario=scenario,
+                                                backend=backend)
     return result

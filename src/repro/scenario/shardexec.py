@@ -28,7 +28,10 @@ one trace per (policy shape, chunk width).
 
 Observability: ``scenario.shard.devices`` (lane-mesh width of the most
 recent launch), ``scenario.shard.pad_lanes`` (inert lanes added) and
-``scenario.sweep.chunks`` (chunks streamed) in the ``obs.metrics`` registry.
+``scenario.sweep.chunks`` (chunks streamed) in the ``obs.metrics`` registry;
+each chunk's slice, pad and placement is a ``repro.stage`` span, its
+launch a ``repro.launch`` span, and the concatenation a ``repro.wait`` then
+``repro.assemble`` span (DESIGN.md §11).
 """
 from __future__ import annotations
 
@@ -182,24 +185,31 @@ def _stream(lane_tree, lanes: int, chunk: Optional[int], mesh,
     shard_devices.inc(quantum)
     outs = []
     for lo in range(0, lanes, width):
-        hi = min(lo + width, lanes)
-        piece = _slice_lanes(lane_tree, lo, hi)
-        if hi - lo < width:
-            shard_pad_lanes.inc(width - (hi - lo))
-            piece = pad_lane_axis(piece, hi - lo, width)
-        sweep_chunks.inc()
-        outs.append(launch(_device_put_lanes(piece, mesh)))
+        with _metrics.span("repro.stage"):
+            hi = min(lo + width, lanes)
+            piece = _slice_lanes(lane_tree, lo, hi)
+            if hi - lo < width:
+                shard_pad_lanes.inc(width - (hi - lo))
+                piece = pad_lane_axis(piece, hi - lo, width)
+            sweep_chunks.inc()
+            piece = _device_put_lanes(piece, mesh)
+        with _metrics.span("repro.launch"):
+            outs.append(launch(piece))
     return outs
 
 
 def _concat_out(chunks: list, lanes: int, axis: int = 0) -> Dict:
     """Concatenate per-chunk output dicts on the lane axis and drop the pad
     lanes (host-side: chunk outputs leave the device as they arrive)."""
-    keys = chunks[0].keys()
-    out = {k: np.concatenate([np.asarray(c[k]) for c in chunks], axis=axis)
-           for k in keys}
-    sl = (slice(None),) * axis + (slice(0, lanes),)
-    return {k: v[sl] for k, v in out.items()}
+    with _metrics.span("repro.wait"):
+        jax.block_until_ready(chunks)
+    with _metrics.span("repro.assemble"):
+        keys = chunks[0].keys()
+        out = {k: np.concatenate([np.asarray(c[k]) for c in chunks],
+                                 axis=axis)
+               for k in keys}
+        sl = (slice(None),) * axis + (slice(0, lanes),)
+        return {k: v[sl] for k, v in out.items()}
 
 
 def run_static_grid(tables, node_of_pe, arrival, app_idx, *, policy: str,
@@ -216,10 +226,11 @@ def run_static_grid(tables, node_of_pe, arrival, app_idx, *, policy: str,
     leading (F,) fault-lane axis, and the design axis (still the streamed
     one) moves to position 1 — the fault axis is outermost precisely so
     streaming stays a design-axis slice (DESIGN.md §14)."""
-    lanes = int(np.asarray(tables.exec_us).shape[0])
-    lane_tree = (host_tree(tables), host_tree(node_of_pe))
-    faulted = fplans is not None
-    fdev = jnp.asarray(fplans, jnp.float32) if faulted else None
+    with _metrics.span("repro.stage"):
+        lanes = int(np.asarray(tables.exec_us).shape[0])
+        lane_tree = (host_tree(tables), host_tree(node_of_pe))
+        faulted = fplans is not None
+        fdev = jnp.asarray(fplans, jnp.float32) if faulted else None
 
     def launch(piece):
         tb, nodes = piece
@@ -250,13 +261,15 @@ def run_dtpm_grid(tables, gov, arrival, app_idx, *, policy: str,
     the SimTables leaves (DESIGN.md §10).  ``fplans``/``scan_steps`` switch
     to the fail-stop grid: outputs gain a leading (F,) fault-lane axis and
     the streamed axis shifts one position right (DESIGN.md §14)."""
-    D = int(np.asarray(tables.exec_us).shape[0])
-    G = int(np.asarray(gov.up_threshold).shape[0])
-    tables_h, gov_h = host_tree(tables), host_tree(gov)
-    faulted = fplans is not None
-    fdev = jnp.asarray(fplans, jnp.float32) if faulted else None
+    with _metrics.span("repro.stage"):
+        D = int(np.asarray(tables.exec_us).shape[0])
+        G = int(np.asarray(gov.up_threshold).shape[0])
+        tables_h, gov_h = host_tree(tables), host_tree(gov)
+        faulted = fplans is not None
+        fdev = jnp.asarray(fplans, jnp.float32) if faulted else None
     if D >= G:                               # stream designs, reuse policies
-        gov_dev = jax.tree_util.tree_map(jnp.asarray, gov_h)
+        with _metrics.span("repro.stage"):
+            gov_dev = jax.tree_util.tree_map(jnp.asarray, gov_h)
 
         def launch(tb):
             if faulted:
@@ -268,7 +281,8 @@ def run_dtpm_grid(tables, gov, arrival, app_idx, *, policy: str,
 
         return _concat_out(_stream(tables_h, D, chunk, mesh, launch), D,
                            axis=1 if faulted else 0)
-    tables_dev = jax.tree_util.tree_map(jnp.asarray, tables_h)
+    with _metrics.span("repro.stage"):
+        tables_dev = jax.tree_util.tree_map(jnp.asarray, tables_h)
 
     def launch(g):
         if faulted:

@@ -45,6 +45,7 @@ from ..core.dvfs import stack_policies
 from ..core.jobgen import JobTrace
 from ..dse.batch import pad_node_map, stack_tables, stack_traces
 from ..dse.space import DesignPoint
+from ..obs import metrics as _metrics
 from ..obs import telemetry as _obs_tel
 from . import faults as _faults
 from . import shardexec
@@ -173,7 +174,17 @@ def sweep(scenario: Scenario, axes: Dict[str, Sequence],
     O(grid).  Both are bit-for-bit equal to the unsharded sweep — lanes are
     independent, and uneven lane counts are padded with inert (dropped)
     lanes — and neither adds compiles per policy shape.
+
+    The whole call is the host span ``repro.sweep`` (DESIGN.md §11).
     """
+    with _metrics.span("repro.sweep"):
+        return _sweep(scenario, axes, backend, pad_pes, design_batch,
+                      telemetry, chunk, shard)
+
+
+def _sweep(scenario: Scenario, axes: Dict[str, Sequence], backend: str,
+           pad_pes: Optional[int], design_batch, telemetry: Optional[bool],
+           chunk: Optional[int], shard: Optional[bool]) -> SweepResult:
     if not axes:
         raise ValueError("axes must name at least one swept dimension")
     if chunk is not None and (not isinstance(chunk, int) or chunk < 1):
@@ -256,17 +267,18 @@ def sweep(scenario: Scenario, axes: Dict[str, Sequence],
         *(values[n] for n in trace_axes))) or [()]
 
     # workloads: one stacked (S, J) pair shared by every design lane
-    t_scns = [_apply_axes(scenario, trace_axes, c) for c in trace_combos]
-    traces = [_lane_trace(s, trace_axes, c)
-              for s, c in zip(t_scns, trace_combos)]
-    job_counts = {t.num_jobs for t in traces}
-    if len(job_counts) > 1:
-        raise LaneAxisError(
-            f"the jax backend needs equal job counts per lane to stack one "
-            f"(S, J) workload tensor, got {sorted(job_counts)}; sweep the "
-            f"'jobs' axis with backend='ref' instead")
-    arrival, app_idx = stack_traces(traces)
-    num_jobs = int(arrival.shape[1])
+    with _metrics.span("repro.stage"):
+        t_scns = [_apply_axes(scenario, trace_axes, c) for c in trace_combos]
+        traces = [_lane_trace(s, trace_axes, c)
+                  for s, c in zip(t_scns, trace_combos)]
+        job_counts = {t.num_jobs for t in traces}
+        if len(job_counts) > 1:
+            raise LaneAxisError(
+                f"the jax backend needs equal job counts per lane to stack "
+                f"one (S, J) workload tensor, got {sorted(job_counts)}; "
+                f"sweep the 'jobs' axis with backend='ref' instead")
+        arrival, app_idx = stack_traces(traces)
+        num_jobs = int(arrival.shape[1])
 
     # design-lane base: dynamic tables carry the OPP ladders, so the (first)
     # dynamic governor must be applied before tables are built; every dynamic
@@ -316,33 +328,37 @@ def sweep(scenario: Scenario, axes: Dict[str, Sequence],
             detail="the offline ILP table pins tasks to PEs, so dead-PE "
                    "fallback needs the runtime schedulers (met/etf)")
     rebuild_per_combo = design_batch is None and any_table
-    if design_batch is None and not rebuild_per_combo:
-        tables, node_of_pe = _design_lanes(lane_base, design_axes,
-                                           design_combos, pad_pes,
-                                           host=lane_exec)
+    with _metrics.span("repro.stage"):
+        if design_batch is None and not rebuild_per_combo:
+            tables, node_of_pe = _design_lanes(lane_base, design_axes,
+                                               design_combos, pad_pes,
+                                               host=lane_exec)
 
-    gov_stack = stack_policies(policies) if dynamic else None
+        gov_stack = stack_policies(policies) if dynamic else None
 
-    # stacked (F, P) fault plans: pe_ids validate against the narrowest
-    # design lane; plans are emitted at the padded PE width.  All-noop lanes
-    # leave plans=None — the sweep then runs the exact fault-free program
-    # (zero extra compiles) and tiles its results over the fault axis.
-    plans, scan_steps = None, None
-    if have_faults:
-        min_pes = min(_apply_axes(lane_base, design_axes, c).design.num_pes
-                      for c in design_combos)
-        plans, max_f = _faults.stack_fault_plans(
-            fault_sets, min_pes, width=int(tables.num_pes))
-        scan_steps = _faults.fault_scan_steps(num_jobs, int(tables.t_max),
-                                              max_f)
+        # stacked (F, P) fault plans: pe_ids validate against the narrowest
+        # design lane; plans are emitted at the padded PE width.  All-noop
+        # lanes leave plans=None — the sweep then runs the exact fault-free
+        # program (zero extra compiles) and tiles its results over the
+        # fault axis.
+        plans, scan_steps = None, None
+        if have_faults:
+            min_pes = min(
+                _apply_axes(lane_base, design_axes, c).design.num_pes
+                for c in design_combos)
+            plans, max_f = _faults.stack_fault_plans(
+                fault_sets, min_pes, width=int(tables.num_pes))
+            scan_steps = _faults.fault_scan_steps(
+                num_jobs, int(tables.t_max), max_f)
 
     per_static = []
     for sc in static_combos:
         s_scn = _apply_axes(lane_base, static_axes, sc)
         if rebuild_per_combo:
-            tables, node_of_pe = _design_lanes(s_scn, design_axes,
-                                               design_combos, pad_pes,
-                                               host=lane_exec)
+            with _metrics.span("repro.stage"):
+                tables, node_of_pe = _design_lanes(s_scn, design_axes,
+                                                   design_combos, pad_pes,
+                                                   host=lane_exec)
         if dynamic:
             if plans is not None:
                 if lane_exec:
@@ -352,11 +368,11 @@ def sweep(scenario: Scenario, axes: Dict[str, Sequence],
                         chunk=chunk, mesh=mesh, fplans=plans,
                         scan_steps=scan_steps)
                 else:
-                    out = _sweep_grid_dtpm_faults(tables, gov_stack, plans,
-                                                  arrival, app_idx,
-                                                  policy=s_scn.scheduler,
-                                                  num_jobs=num_jobs,
-                                                  scan_steps=scan_steps)
+                    with _metrics.span("repro.launch"):
+                        out = _sweep_grid_dtpm_faults(
+                            tables, gov_stack, plans, arrival, app_idx,
+                            policy=s_scn.scheduler, num_jobs=num_jobs,
+                            scan_steps=scan_steps)
             elif lane_exec:
                 out = shardexec.run_dtpm_grid(tables, gov_stack, arrival,
                                               app_idx,
@@ -364,9 +380,10 @@ def sweep(scenario: Scenario, axes: Dict[str, Sequence],
                                               num_jobs=num_jobs,
                                               chunk=chunk, mesh=mesh)
             else:
-                out = _sweep_grid_dtpm(tables, gov_stack, arrival, app_idx,
-                                       policy=s_scn.scheduler,
-                                       num_jobs=num_jobs)
+                with _metrics.span("repro.launch"):
+                    out = _sweep_grid_dtpm(tables, gov_stack, arrival,
+                                           app_idx, policy=s_scn.scheduler,
+                                           num_jobs=num_jobs)
             temps = out["peak_temp_c"]
         else:
             if plans is not None:
@@ -379,12 +396,13 @@ def sweep(scenario: Scenario, axes: Dict[str, Sequence],
                         chunk=chunk, mesh=mesh, fplans=plans,
                         scan_steps=scan_steps)
                 else:
-                    out, temps = _sweep_grid_faults(
-                        tables, node_of_pe, plans, arrival, app_idx,
-                        policy=s_scn.scheduler, num_jobs=num_jobs,
-                        bins=s_scn.thermal.bins,
-                        repeats=s_scn.thermal.repeats,
-                        scan_steps=scan_steps)
+                    with _metrics.span("repro.launch"):
+                        out, temps = _sweep_grid_faults(
+                            tables, node_of_pe, plans, arrival, app_idx,
+                            policy=s_scn.scheduler, num_jobs=num_jobs,
+                            bins=s_scn.thermal.bins,
+                            repeats=s_scn.thermal.repeats,
+                            scan_steps=scan_steps)
             elif lane_exec:
                 out, temps = shardexec.run_static_grid(
                     tables, node_of_pe, arrival, app_idx,
@@ -392,22 +410,27 @@ def sweep(scenario: Scenario, axes: Dict[str, Sequence],
                     bins=s_scn.thermal.bins, repeats=s_scn.thermal.repeats,
                     chunk=chunk, mesh=mesh)
             else:
-                out, temps = _sweep_grid(tables, node_of_pe, arrival, app_idx,
-                                         policy=s_scn.scheduler,
-                                         num_jobs=num_jobs,
-                                         bins=s_scn.thermal.bins,
-                                         repeats=s_scn.thermal.repeats)
-        if plans is not None and not fault_axes:
-            # base-scenario faults, no fault axis: drop the F=1 lane axis so
-            # the grid keeps its fault-free shape
-            out = {k: v[0] for k, v in out.items()}
-            temps = temps[0]
-        entry = dict(
-            avg_latency_us=np.asarray(out["avg_job_latency_us"], np.float64),
-            makespan_us=np.asarray(out["makespan_us"], np.float64),
-            energy_j=np.asarray(out["energy_j"], np.float64),
-            peak_temp_c=np.asarray(temps, np.float64),
-            busy_per_pe_us=np.asarray(out["busy_per_pe_us"], np.float64))
+                with _metrics.span("repro.launch"):
+                    out, temps = _sweep_grid(tables, node_of_pe, arrival,
+                                             app_idx, policy=s_scn.scheduler,
+                                             num_jobs=num_jobs,
+                                             bins=s_scn.thermal.bins,
+                                             repeats=s_scn.thermal.repeats)
+        with _metrics.span("repro.wait"):
+            jax.block_until_ready((out, temps))
+        with _metrics.span("repro.assemble"):
+            if plans is not None and not fault_axes:
+                # base-scenario faults, no fault axis: drop the F=1 lane axis
+                # so the grid keeps its fault-free shape
+                out = {k: v[0] for k, v in out.items()}
+                temps = temps[0]
+            entry = dict(
+                avg_latency_us=np.asarray(out["avg_job_latency_us"],
+                                          np.float64),
+                makespan_us=np.asarray(out["makespan_us"], np.float64),
+                energy_j=np.asarray(out["energy_j"], np.float64),
+                peak_temp_c=np.asarray(temps, np.float64),
+                busy_per_pe_us=np.asarray(out["busy_per_pe_us"], np.float64))
         if want_tel:
             entry["telemetry"] = _telemetry_grid(
                 s_scn, design_axes, design_combos, policies, tables,
@@ -418,8 +441,9 @@ def sweep(scenario: Scenario, axes: Dict[str, Sequence],
             # every fault lane is a no-op: the fault-free program ran once
             # (the §14 no-op contract — zero extra compiles) and its results
             # tile verbatim across the fault axis
-            entry = {k: np.repeat(v[None], len(fault_sets), axis=0)
-                     for k, v in entry.items()}
+            with _metrics.span("repro.assemble"):
+                entry = {k: np.repeat(v[None], len(fault_sets), axis=0)
+                         for k, v in entry.items()}
         per_static.append(entry)
 
     # assemble: (static..., faults..., design..., policy..., trace..., extra)
@@ -443,15 +467,17 @@ def sweep(scenario: Scenario, axes: Dict[str, Sequence],
         k = len(internal)
         return np.transpose(arr, axes=perm + list(range(k, arr.ndim)))
 
-    makespan = _assemble("makespan_us")
-    return SweepResult(
-        base=scenario, backend="jax", axes=values,
-        avg_latency_us=_assemble("avg_latency_us"),
-        throughput_jobs_per_ms=num_jobs / np.maximum(makespan, 1e-9) * 1e3,
-        makespan_us=makespan, energy_j=_assemble("energy_j"),
-        peak_temp_c=_assemble("peak_temp_c"),
-        busy_per_pe_us=_assemble("busy_per_pe_us"),
-        telemetry=_assemble("telemetry") if want_tel else None)
+    with _metrics.span("repro.assemble"):
+        makespan = _assemble("makespan_us")
+        return SweepResult(
+            base=scenario, backend="jax", axes=values,
+            avg_latency_us=_assemble("avg_latency_us"),
+            throughput_jobs_per_ms=num_jobs / np.maximum(makespan, 1e-9)
+            * 1e3,
+            makespan_us=makespan, energy_j=_assemble("energy_j"),
+            peak_temp_c=_assemble("peak_temp_c"),
+            busy_per_pe_us=_assemble("busy_per_pe_us"),
+            telemetry=_assemble("telemetry") if want_tel else None)
 
 
 def _telemetry_grid(s_scn: Scenario, design_axes: List[str],

@@ -76,6 +76,14 @@ def test_jx003_good_host_effects_and_waiver():
     assert lines([f for f in rep.findings if f.waived], "JX003") == [18]
 
 
+def test_jx003_host_span_in_jitted_body():
+    """A profiler span inside a jitted body records once per compile; the
+    same span around the jitted call on the host stays clean."""
+    rep = lint("jx003_span.py", select=["JX003"])
+    assert lines(rep.active, "JX003") == [11, 17, 23]
+    assert all("span" in f.message for f in rep.active)
+
+
 # ---------------------------------------------------------------- PT001
 
 def test_pt001_bad_exact_sites():

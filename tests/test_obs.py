@@ -339,3 +339,171 @@ def test_report_cli_trace_validate_render(tmp_path, capsys):
     bad.write_text(json.dumps({"traceEvents": [
         {"name": "t", "ph": "B", "pid": 0, "tid": 0, "ts": 0.0}]}))
     assert report.main(["--validate", str(bad)]) == 1
+
+
+# ------------------------------------------------ host spans (profiler)
+
+OUTER = ("repro.run", "repro.sweep", "repro.evaluate")
+
+
+def _profiled(fn, tmp_path):
+    """``fn()`` under the JAX profiler: its result and the host plane's
+    ``repro.*`` spans as ``(name, start_ns, end_ns)``, by start."""
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    spans = [(e.name, e.start_ns, e.end_ns)
+             for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("repro.")]
+    return out, sorted(spans, key=lambda s: (s[1], -s[2]))
+
+
+def _leaves(spans):
+    """The leaf spans in time order, after checking that no two of them
+    overlap (leaves never nest inside each other)."""
+    leaves = [s for s in spans if s[0] not in OUTER]
+    for a, b in zip(leaves, leaves[1:]):
+        assert a[2] <= b[1], (a, b)
+    return leaves
+
+
+def _inside(inner, outer):
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def _assert_equal_outputs(a, b, fields):
+    for f in fields:
+        np.testing.assert_array_equal(np.asarray(getattr(a, f)),
+                                      np.asarray(getattr(b, f)), err_msg=f)
+
+
+RUN_FIELDS = ("avg_latency_us", "throughput_jobs_per_ms", "makespan_us",
+              "energy_j", "avg_power_w", "peak_temp_c", "utilization")
+
+
+def test_span_is_a_trace_annotation_and_metrics_stays_stdlib():
+    """``span`` is JAX's profiler annotation, and ``repro.obs.metrics``
+    imports JAX only when the first span is made."""
+    import os
+    import subprocess
+    import sys
+    from jax.profiler import TraceAnnotation
+    assert isinstance(metrics.span("repro.stage"), TraceAnnotation)
+    code = ("import sys, repro.obs.metrics as m; "
+            "assert 'jax' not in sys.modules; m.span('repro.x'); "
+            "assert 'jax' in sys.modules")
+    subprocess.run([sys.executable, "-c", code], check=True,
+                   env={**os.environ, "JAX_PLATFORMS": "cpu"})
+
+
+@pytest.mark.parametrize("governor,telemetry,leaves", [
+    ("ondemand", False,
+     ["stage", "stage", "launch", "wait", "assemble", "assemble"]),
+    ("performance", False,
+     ["stage", "stage", "launch", "launch", "wait", "assemble",
+      "assemble"]),
+    # the telemetry replay scan is a launch of its own, between the run's
+    # wait and its assembly
+    ("ondemand", True,
+     ["stage", "stage", "launch", "wait", "launch", "wait", "assemble",
+      "assemble", "assemble"]),
+])
+def test_run_spans(tmp_path, governor, telemetry, leaves):
+    """``run(backend="jax")``: one ``repro.run`` span holds every leaf, in
+    the order stage → launch → wait → assemble; the outputs are bit for bit
+    those of the same run with the profiler off."""
+    scn = SCN.replace(governor=governor)
+    off = run(scn, backend="jax", telemetry=telemetry)
+    on, spans = _profiled(
+        lambda: run(scn, backend="jax", telemetry=telemetry), tmp_path)
+    outer = [s for s in spans if s[0] in OUTER]
+    assert [s[0] for s in outer] == ["repro.run"]
+    got = _leaves(spans)
+    assert [n for n, _, _ in got] == [f"repro.{n}" for n in leaves]
+    assert all(_inside(s, outer[0]) for s in got)
+    _assert_equal_outputs(off, on, RUN_FIELDS)
+    for key in ("scheduled", "start", "finish", "onpe"):
+        np.testing.assert_array_equal(np.asarray(off.raw[key]),
+                                      np.asarray(on.raw[key]))
+    if telemetry:
+        np.testing.assert_array_equal(off.telemetry.temps_c,
+                                      on.telemetry.temps_c)
+
+
+SWEEP_FIELDS = ("avg_latency_us", "throughput_jobs_per_ms", "makespan_us",
+                "energy_j", "peak_temp_c", "busy_per_pe_us")
+
+
+@pytest.mark.parametrize("chunk,leaves", [
+    # traces staged, lanes staged, then per scheduler launch, wait and
+    # assembly, then the grid's assembly
+    (None, ["stage", "stage"] + ["launch", "wait", "assemble"] * 2
+     + ["assemble"]),
+    # streamed in 1-lane chunks: the lanes go to the host once, then each
+    # chunk is staged and launched, and the chunks are waited for and
+    # concatenated before the sweep's own (no-op) wait and assembly
+    (1, ["stage", "stage"]
+     + (["stage"] + ["stage", "launch"] * 2 + ["wait", "assemble", "wait",
+                                              "assemble"]) * 2
+     + ["assemble"]),
+])
+def test_sweep_spans(tmp_path, chunk, leaves):
+    """A met/etf × 2-design sweep: every leaf inside ``repro.sweep``, in
+    the order stage → launch → wait → assemble; outputs bit for bit equal
+    with the profiler on and off."""
+    axes = {"scheduler": ["met", "etf"], "design.num_fft": [2, 4]}
+    off = sweep(SCN, axes=axes, chunk=chunk, shard=False)
+    on, spans = _profiled(
+        lambda: sweep(SCN, axes=axes, chunk=chunk, shard=False), tmp_path)
+    outer = [s for s in spans if s[0] in OUTER]
+    assert [s[0] for s in outer] == ["repro.sweep"]
+    got = _leaves(spans)
+    assert [n for n, _, _ in got] == [f"repro.{n}" for n in leaves]
+    assert all(_inside(s, outer[0]) for s in got)
+    _assert_equal_outputs(off, on, SWEEP_FIELDS)
+
+
+def test_design_batch_and_evaluate_spans(tmp_path):
+    """``build_design_batch`` is ``repro.tables.build`` then
+    ``repro.tables.stack``; ``evaluate(batch=)`` is ``repro.evaluate``
+    holding ``repro.sweep`` and its leaves; outputs bit for bit equal with
+    the profiler on and off."""
+    import dataclasses
+    from repro.core.applications import get_application
+    from repro.dse import DesignSpace, build_design_batch, evaluate
+
+    points = DesignSpace().sample_lhs(3, seed=1)
+    apps = [get_application("wifi_tx")]
+    traces = [SCN.replace(trace=dataclasses.replace(SCN.trace, seed=s))
+              .job_trace() for s in (1, 2)]
+
+    def call():
+        batch = build_design_batch(points, apps, pad_pes=16)
+        return evaluate(points, apps, traces, batch=batch)
+
+    off = call()
+    on, spans = _profiled(call, tmp_path)
+    names = [n for n, _, _ in spans]
+    assert names.count("repro.evaluate") == names.count("repro.sweep") == 1
+    ev = spans[names.index("repro.evaluate")]
+    sw = spans[names.index("repro.sweep")]
+    assert _inside(sw, ev)
+    got = _leaves(spans)
+    assert [n for n, _, _ in got] == [
+        "repro.tables.build", "repro.tables.stack", "repro.stage",
+        "repro.stage", "repro.launch", "repro.wait", "repro.assemble",
+        "repro.assemble"]
+    assert not any(_inside(s, ev) for s in got[:2])
+    assert all(_inside(s, sw) for s in got[2:])
+    _assert_equal_outputs(off, on, ("avg_latency_us", "energy_j",
+                                    "peak_temp_c", "latency_per_trace_us"))
