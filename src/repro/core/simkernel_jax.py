@@ -57,7 +57,8 @@ MIN_DOMAINS = 3
 
 
 # --------------------------------------------------------------------------
-# Static tables (device-resident constants per (db, apps, governor) triple)
+# Static tables (per (db, apps, governor) triple: built on the host, placed
+# on the device as constants)
 # --------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
@@ -103,7 +104,25 @@ def build_tables(db: ResourceDB, apps: Sequence[Application],
                  pad_tasks: Optional[int] = None,
                  pad_pes: Optional[int] = None,
                  freq_caps: Optional[Mapping[str, float]] = None) -> SimTables:
-    """Build device-resident simulation tables for one SoC design.
+    """Build device-resident simulation tables for one SoC design: the
+    :func:`build_tables_host` tables placed with one ``jax.device_put``."""
+    return jax.device_put(build_tables_host(
+        db, apps, governor=governor, table=table, pad_tasks=pad_tasks,
+        pad_pes=pad_pes, freq_caps=freq_caps))
+
+
+def build_tables_host(db: ResourceDB, apps: Sequence[Application],
+                      governor: Optional[Governor] = None,
+                      table: Optional[Dict[Tuple[str, int], int]] = None,
+                      pad_tasks: Optional[int] = None,
+                      pad_pes: Optional[int] = None,
+                      freq_caps: Optional[Mapping[str, float]] = None
+                      ) -> SimTables:
+    """Build one SoC design's simulation tables with numpy leaves.
+
+    Nothing touches the device: callers that stack many designs
+    (``repro.dse.batch``) place the stacked batch once instead of every
+    design's leaves one by one.
 
     ``pad_tasks`` / ``pad_pes`` pad the task and PE axes to a fixed size so
     tables from *different* designs stack into one (D, …) batch (see
@@ -187,22 +206,17 @@ def build_tables(db: ResourceDB, apps: Sequence[Application],
         pe_domain[j] = pe.cluster
         pe_is_cpu[j] = 1.0 if pe.is_cpu else 0.0
 
-    opp_kw: Dict[str, jnp.ndarray] = {}
+    opp_kw: Dict[str, np.ndarray] = {}
     if dynamic:
         opp_kw = _build_opp_tables(db, apps, A, T, P, C, freq_caps)
 
     return SimTables(
-        exec_us=jnp.asarray(exec_us),
-        pred=jnp.asarray(pred), ebytes=jnp.asarray(ebytes),
-        valid=jnp.asarray(valid),
-        comm_mult=jnp.asarray(comm_mult),
-        comm_startup=jnp.float32(db.comm.startup_us),
-        comm_inv_bw=jnp.float32(1.0 / db.comm.bw_bytes_per_us),
-        power_active=jnp.asarray(p_act), power_idle=jnp.asarray(p_idle),
-        table_pe=jnp.asarray(table_pe),
-        node_of_pe=jnp.asarray(node_of_pe),
-        pe_domain=jnp.asarray(pe_domain),
-        pe_is_cpu=jnp.asarray(pe_is_cpu),
+        exec_us=exec_us, pred=pred, ebytes=ebytes, valid=valid,
+        comm_mult=comm_mult,
+        comm_startup=np.asarray(db.comm.startup_us, np.float32),
+        comm_inv_bw=np.asarray(1.0 / db.comm.bw_bytes_per_us, np.float32),
+        power_active=p_act, power_idle=p_idle, table_pe=table_pe,
+        node_of_pe=node_of_pe, pe_domain=pe_domain, pe_is_cpu=pe_is_cpu,
         t_max=T, num_pes=P, **opp_kw)
 
 
@@ -257,12 +271,9 @@ def _build_opp_tables(db: ResourceDB, apps: Sequence[Application],
                 else:
                     exec_opp[ai, t, j, :] = np.float32(base)
 
-    return dict(exec_opp=jnp.asarray(exec_opp),
-                power_active_opp=jnp.asarray(p_act_opp),
-                opp_freq=jnp.asarray(opp_freq),
-                num_opp=jnp.asarray(num_opp),
-                domain_node=jnp.asarray(domain_node),
-                domain_cpu=jnp.asarray(domain_cpu))
+    return dict(exec_opp=exec_opp, power_active_opp=p_act_opp,
+                opp_freq=opp_freq, num_opp=num_opp, domain_node=domain_node,
+                domain_cpu=domain_cpu)
 
 
 # --------------------------------------------------------------------------

@@ -1,12 +1,14 @@
 """Stack per-design simulation tables into one (D, …) tensor program.
 
 Designs differ in PE count, so every per-design :class:`SimTables` is built
-padded to the fleet-wide maximum (``build_tables(pad_pes=…)``) and the padded
-tables are stacked leaf-wise into a single pytree whose data fields carry a
-leading design axis.  Padding is inert by construction (BIG latency, zero
-power — see DESIGN.md §5), so the batched kernel needs **no masking logic**:
-``jax.vmap`` over the design axis × the trace axis runs designs × seeds ×
-injection rates in one ``jit``.
+padded to the fleet-wide maximum (``build_tables_host(pad_pes=…)``) and the
+padded tables are stacked leaf-wise into a single pytree whose data fields
+carry a leading design axis.  :func:`build_design_batch` builds and stacks
+on the host and places the stacked batch on the device once, one transfer
+per leaf whatever the number of designs.  Padding is inert by construction
+(BIG latency, zero power — see DESIGN.md §5), so the batched kernel needs
+**no masking logic**: ``jax.vmap`` over the design axis × the trace axis
+runs designs × seeds × injection rates in one ``jit``.
 """
 from __future__ import annotations
 
@@ -21,10 +23,14 @@ import numpy as np
 from ..core.applications import Application
 from ..core.dvfs import Governor
 from ..core.jobgen import JobTrace
-from ..core.simkernel_jax import SimTables, _simulate, build_tables
+from ..core.simkernel_jax import SimTables, _simulate, build_tables_host
 from ..core.thermal import NODE_ACCEL, cluster_nodes
 from ..obs import metrics as _metrics
 from .space import DesignPoint
+
+# host->device transfers build_design_batch makes, one per leaf placed: a
+# batch's leaf count, whatever its number of designs
+_PLACEMENTS = _metrics.counter("dse.tables.placements")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,7 +59,8 @@ def stack_tables(tables: Sequence[SimTables], host: bool = False) -> SimTables:
 
     ``host=True`` stacks into numpy leaves instead of device arrays — the
     form the chunked/sharded executor (``scenario.shardexec``) streams from,
-    so a grid larger than device memory is never device-resident at once.
+    so a grid larger than device memory is never device-resident at once,
+    and the form :func:`build_design_batch` places in one transfer.
     """
     shapes = {(t.t_max, t.num_pes) for t in tables}
     if len(shapes) != 1:
@@ -67,10 +74,14 @@ def stack_tables(tables: Sequence[SimTables], host: bool = False) -> SimTables:
 def pad_node_map(dbs, pad_pes: int) -> jnp.ndarray:
     """(D, P) thermal node per PE slot; padded slots are inert (zero-power)
     and binned to the accel node by convention."""
+    return jnp.asarray(_node_map_host(dbs, pad_pes))
+
+
+def _node_map_host(dbs, pad_pes: int) -> np.ndarray:
     nodes = np.full((len(dbs), pad_pes), NODE_ACCEL, dtype=np.int32)
     for i, db in enumerate(dbs):
         nodes[i, :db.num_pes] = cluster_nodes(db)
-    return jnp.asarray(nodes)
+    return nodes
 
 
 def build_design_batch(points: Sequence[DesignPoint],
@@ -86,9 +97,12 @@ def build_design_batch(points: Sequence[DesignPoint],
     per-cluster frequency caps — so Pareto search ranks dynamic policies
     under the design's static envelope, not just static caps.
 
-    Host spans (DESIGN.md §11): ``repro.tables.build`` covers ``to_db`` and
-    the per-design ``build_tables`` loop, ``repro.tables.stack`` the
-    stacking and the node map.
+    Every design's tables are built and stacked on the host, then the
+    stacked tables and node map are placed with one ``jax.device_put``
+    (counted by ``dse.tables.placements``, one per leaf).  Host spans
+    (DESIGN.md §11): ``repro.tables.build`` covers ``to_db`` and the
+    per-design ``build_tables_host`` loop, ``repro.tables.stack`` the host
+    stack, the node map and the placement.
     """
     if not points:
         raise ValueError("empty design list")
@@ -108,17 +122,22 @@ def build_design_batch(points: Sequence[DesignPoint],
                     "pass a dynamic (ondemand-family) governor to add OPP "
                     "ladders, or None for the static design-cap tables")
             per_design = [
-                build_tables(db, apps, governor=governor, pad_pes=P,
-                             freq_caps=p.freq_caps())
+                build_tables_host(db, apps, governor=governor, pad_pes=P,
+                                  freq_caps=p.freq_caps())
                 for p, db in zip(points, dbs)]
         else:
-            per_design = [build_tables(db, apps, governor=p.governor(),
-                                       pad_pes=P)
+            per_design = [build_tables_host(db, apps, governor=p.governor(),
+                                            pad_pes=P)
                           for p, db in zip(points, dbs)]
     with _metrics.span("repro.tables.stack"):
-        return DesignBatch(points=tuple(points),
-                           tables=stack_tables(per_design),
-                           node_of_pe=pad_node_map(dbs, P))
+        # uncommitted, unsharded placement, as jnp.stack would give: the
+        # batched programs find the jit cache entries they always had
+        tables, node_of_pe = jax.device_put(
+            (stack_tables(per_design, host=True), _node_map_host(dbs, P)))
+        _PLACEMENTS.inc(
+            len(jax.tree_util.tree_leaves((tables, node_of_pe))))
+        return DesignBatch(points=tuple(points), tables=tables,
+                           node_of_pe=node_of_pe)
 
 
 def stack_traces(traces: Sequence[JobTrace]) -> Tuple[jnp.ndarray, jnp.ndarray]:

@@ -62,14 +62,14 @@ def _cached_tables(key: Scenario, pad_pes: Optional[int]) -> SimTables:
 
 @functools.lru_cache(maxsize=256)
 def _cached_tables_host(key: Scenario, pad_pes: Optional[int]) -> SimTables:
-    """Host-resident (numpy-leaf) twin of :func:`_cached_tables`: built
-    fresh (not via the device cache) so only one design's device arrays are
-    ever live during construction — the chunked sweep's streaming source."""
+    """Host-resident (numpy-leaf) twin of :func:`_cached_tables`, built by
+    the host builder so no device array is made — the chunked sweep's
+    streaming source."""
     db = key.soc()
-    tb = _jaxk.build_tables(db, key.applications(),
-                            governor=key.make_governor(),
-                            table=key.schedule_table(), pad_pes=pad_pes)
-    return jax.tree_util.tree_map(np.asarray, tb)
+    return _jaxk.build_tables_host(db, key.applications(),
+                                   governor=key.make_governor(),
+                                   table=key.schedule_table(),
+                                   pad_pes=pad_pes)
 
 
 def tables_for(scn: Scenario, pad_pes: Optional[int] = None,

@@ -5,20 +5,25 @@ determinism, padded-batch vs per-design kernel equivalence (the batching
 correctness contract), and the JAX RC thermal model against the analytical
 steady state / the numpy reference integrator.
 """
+import jax
 import numpy as np
 import pytest
 
-from repro.core import build_tables, poisson_trace, thermal, \
-    wifi_tx, get_application
+from repro.core import build_tables, poisson_trace, solve_optimal_table, \
+    thermal, wifi_tx, get_application
+from repro.core.dvfs import get_governor
 # kernels imported directly: the re-exports are deprecation shims
-from repro.core.simkernel_jax import fixed_order_sum, simulate_jax
+from repro.core.simkernel_jax import build_tables_host, fixed_order_sum, \
+    simulate_jax
 from repro.dse.batch import simulate_design_batch
-from repro.dse import (DesignPoint, DesignSpace, binned_power_trace,
-                       build_design_batch, crowding_distance, evaluate,
-                       non_dominated_sort, pareto_mask, pareto_search,
-                       peak_temperature_grid,
-                       stack_traces, successive_halving, transient_trace)
+from repro.dse import (DesignBatch, DesignPoint, DesignSpace,
+                       binned_power_trace, build_design_batch,
+                       crowding_distance, evaluate, non_dominated_sort,
+                       pad_node_map, pareto_mask, pareto_search,
+                       peak_temperature_grid, stack_tables, stack_traces,
+                       successive_halving, transient_trace)
 from repro.dse import thermal_jax
+from repro.obs import metrics as _metrics
 
 APPS = ["wifi_tx", "wifi_rx"]
 
@@ -106,7 +111,6 @@ def test_neighbors_stay_in_space():
 def test_fixed_order_sum_ignores_padding_and_lane_width():
     """The result-path sum: trailing zero terms (inert padding) and vmapped
     lanes of any width leave it bit-identical; it is a float32 sum."""
-    import jax
     x = np.random.default_rng(0).random((13, 5)).astype(np.float32)
     s = np.asarray(fixed_order_sum(x))
     padded = np.concatenate([x, np.zeros((7, 5), np.float32)])
@@ -157,6 +161,118 @@ def test_build_tables_pad_validation():
         build_tables(db, [wifi_tx()], pad_pes=db.num_pes - 1)
     with pytest.raises(ValueError):
         build_tables(db, [wifi_tx()], pad_tasks=2)
+
+
+# ------------------------------------------------------- host table build
+
+_BATCH_POINTS = [DesignPoint(4, 4, 2, 4, 0), DesignPoint(1, 2, 0, 1, 0),
+                 DesignPoint(0, 4, 1, 2, 1, big_freq_ghz=1.4),
+                 DesignPoint(2, 0, 2, 0, 0, cross_cluster_penalty=4.0)]
+
+
+def _device_path_batch(points, apps, pad_pes=None, governor=None):
+    """The batch as built when every design's tables went to the device
+    first: per-design device ``build_tables``, device ``stack_tables``."""
+    dbs = [p.to_db() for p in points]
+    P = pad_pes or max(db.num_pes for db in dbs)
+    per_design = [
+        build_tables(db, apps, governor=governor or p.governor(), pad_pes=P,
+                     freq_caps=p.freq_caps() if governor else None)
+        for p, db in zip(points, dbs)]
+    return DesignBatch(points=tuple(points), tables=stack_tables(per_design),
+                       node_of_pe=pad_node_map(dbs, P))
+
+
+def _assert_same_leaves(got, want):
+    """Same tree, and every leaf the same dtype, shape and bits."""
+    assert (jax.tree_util.tree_structure(got)
+            == jax.tree_util.tree_structure(want))
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want)):
+        name = jax.tree_util.keystr(path)
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("governor", [None, "throttle"])
+def test_design_batch_matches_device_path(governor):
+    """Host build + host stack + one placement gives the tables and node
+    map the per-design device path gave, static caps and OPP ladders."""
+    gov = get_governor(governor) if governor else None
+    got = build_design_batch(_BATCH_POINTS, _apps(), pad_pes=16,
+                             governor=gov)
+    want = _device_path_batch(_BATCH_POINTS, _apps(), pad_pes=16,
+                              governor=gov)
+    assert got.dynamic == (governor is not None)
+    _assert_same_leaves((got.tables, got.node_of_pe),
+                        (want.tables, want.node_of_pe))
+
+
+def test_design_batch_leaves_are_device_arrays():
+    """Device arrays, uncommitted as jnp.stack leaves them: a committed
+    argument would be a new jit cache entry for the batched programs."""
+    batch = build_design_batch(_BATCH_POINTS, _apps(),
+                               governor=get_governor("throttle"))
+    leaves = jax.tree_util.tree_leaves((batch.tables, batch.node_of_pe))
+    assert len(leaves) == 20
+    assert all(isinstance(x, jax.Array) and not x.committed for x in leaves)
+
+
+def test_design_batch_placements_do_not_grow_with_designs():
+    """One host->device transfer per leaf, whatever the number of designs."""
+    placements = _metrics.counter("dse.tables.placements")
+    points = DesignSpace().sample_lhs(16, seed=4)
+    counts = []
+    for d in (2, 16):
+        before = placements.value
+        batch = build_design_batch(points[:d], _apps(), pad_pes=20)
+        counts.append(placements.value - before)
+    assert counts[0] == counts[1] == len(jax.tree_util.tree_leaves(
+        (batch.tables, batch.node_of_pe)))
+
+
+def test_evaluate_on_host_built_batch_matches_device_path():
+    """evaluate(batch=...) on the host-built batch gives the device-built
+    batch's outputs bit for bit, from the same compiled program."""
+    from repro.scenario.sweep import compile_count
+    pts = DesignSpace().sample_lhs(6, seed=9)
+    apps, traces = _apps(), _traces(2)
+    want = evaluate(pts, apps, traces, pad_pes=20,
+                    batch=_device_path_batch(pts, apps, pad_pes=20))
+    compiles = compile_count.value
+    got = evaluate(pts, apps, traces, pad_pes=20,
+                   batch=build_design_batch(pts, apps, pad_pes=20))
+    assert compile_count.value == compiles
+    for field in ("latency_per_trace_us", "energy_per_trace_j",
+                  "temp_per_trace_c"):
+        np.testing.assert_array_equal(getattr(got, field),
+                                      getattr(want, field), err_msg=field)
+
+
+@pytest.mark.parametrize("case", ["pad_pes", "table"])
+def test_host_builder_matches_build_tables(case):
+    """build_tables is the host builder's tables placed: same leaves."""
+    db = DesignPoint(2, 2, 1, 1, 0).to_db()
+    app = wifi_tx()
+    kw = (dict(pad_pes=db.num_pes + 3) if case == "pad_pes"
+          else dict(table=solve_optimal_table(db, app)))
+    host = build_tables_host(db, [app], **kw)
+    assert all(isinstance(x, np.ndarray)
+               for x in jax.tree_util.tree_leaves(host))
+    _assert_same_leaves(host, build_tables(db, [app], **kw))
+
+
+def test_cached_host_tables_match_device_tables():
+    """The chunked sweep's host tables are the device tables read back."""
+    from repro.scenario import Scenario
+    from repro.scenario.run import tables_for
+    scn = Scenario(scheduler="table")
+    host = tables_for(scn, pad_pes=16, host=True)
+    device = tables_for(scn, pad_pes=16)
+    assert all(isinstance(x, np.ndarray)
+               for x in jax.tree_util.tree_leaves(host))
+    _assert_same_leaves(host, jax.tree_util.tree_map(np.asarray, device))
 
 
 # ------------------------------------------------------------------ thermal
