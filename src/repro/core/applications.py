@@ -1,4 +1,5 @@
-"""DAG application models — the five reference applications of the paper.
+"""DAG application models — the five reference applications of the paper,
+and pulse-Doppler at the width of one CPI.
 
 An application is a directed acyclic graph of named tasks (paper Fig. 2 shows
 WiFi-TX).  Each edge carries a payload size (bytes) used by the analytical
@@ -8,7 +9,8 @@ interconnect model.  Task latencies live in the resource database
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Sequence, Tuple
+import functools
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -51,10 +53,66 @@ class Application:
                 m[t.task_id, p] = self.tasks[p].out_bytes
         return m
 
+    def successors(self) -> List[Tuple[int, ...]]:
+        """task_ids of each task's children, ascending."""
+        out: List[List[int]] = [[] for _ in self.tasks]
+        for t in self.tasks:
+            for p in t.predecessors:
+                out[p].append(t.task_id)
+        return [tuple(s) for s in out]
+
+    @property
+    def num_edges(self) -> int:
+        return sum(len(t.predecessors) for t in self.tasks)
+
+    @property
+    def max_in_degree(self) -> int:
+        return max((len(t.predecessors) for t in self.tasks), default=0)
+
+    @property
+    def max_out_degree(self) -> int:
+        return max((len(s) for s in self.successors()), default=0)
+
+    @property
+    def depth(self) -> int:
+        """Edges on the longest path: how many hops a rollback can travel
+        down the DAG."""
+        level = [0] * self.num_tasks
+        for t in self.tasks:                      # topological order
+            level[t.task_id] = max((level[p] + 1 for p in t.predecessors),
+                                   default=0)
+        return max(level, default=0)
+
+    def pred_lists(self, width: Optional[int] = None
+                   ) -> Tuple[np.ndarray, np.ndarray]:
+        """(T, K) i32 predecessor ids, −1-padded, and (T, K) f32 bytes on
+        each of those edges (0 in the padding); K = ``width`` or the
+        largest in-degree."""
+        k = self.max_in_degree if width is None else width
+        idx = np.full((self.num_tasks, k), -1, dtype=np.int32)
+        nbytes = np.zeros((self.num_tasks, k), dtype=np.float32)
+        for t in self.tasks:
+            for i, p in enumerate(t.predecessors):
+                idx[t.task_id, i] = p
+                nbytes[t.task_id, i] = self.tasks[p].out_bytes
+        return idx, nbytes
+
+    def succ_lists(self, width: Optional[int] = None) -> np.ndarray:
+        """(T, K) i32 successor ids, −1-padded; K = ``width`` or the largest
+        out-degree."""
+        succ = self.successors()
+        k = self.max_out_degree if width is None else width
+        idx = np.full((self.num_tasks, k), -1, dtype=np.int32)
+        for t, s in enumerate(succ):
+            idx[t, :len(s)] = s
+        return idx
+
     def validate(self) -> None:
         for t in self.tasks:
             assert all(p < t.task_id for p in t.predecessors), \
                 f"{self.name}: tasks must be topologically ordered"
+            assert len(set(t.predecessors)) == len(t.predecessors), \
+                f"{self.name}: task {t.task_id} repeats a predecessor"
 
 
 def _chain(name: str, task_names: Sequence[str], out_bytes: float = 1024.0) -> Application:
@@ -141,12 +199,50 @@ def pulse_doppler() -> Application:
     return app
 
 
+@functools.lru_cache(maxsize=8)
+def pulse_doppler_cpi(pulses: int = 128, doppler_bins: int = 64
+                      ) -> Application:
+    """Pulse-Doppler processing of one coherent processing interval (CPI).
+
+    ``pd_stack``, then per pulse a pulse-compression chain ``fft →
+    conj_multiply → inverse_fft`` (as in :func:`range_detection`), then the
+    corner turn: ``doppler_bins`` ``doppler_fft`` tasks, each with every
+    pulse's ``inverse_fft`` as a predecessor.  Tasks are numbered stage by
+    stage: 1 + 3·pulses + doppler_bins of them, pulses·doppler_bins
+    corner-turn edges.  The defaults (128 pulses, 64 bins: 449 tasks) are
+    DS3's published task count for its pulse-Doppler application
+    (arXiv:2003.09016); the split into stages is inferred.  Other widths
+    carry their size in the name (``pulse_doppler_cpi_8x4``), so schedule
+    tables never mix them up.
+    """
+    P, B = int(pulses), int(doppler_bins)
+    if P < 1 or B < 1:
+        raise ValueError(f"need pulses >= 1 and doppler_bins >= 1, got "
+                         f"{P}, {B}")
+    fft, conj, ifft = 1, 1 + P, 1 + 2 * P
+    tasks: List[Task] = [Task("pd_stack", 0, (), 4096)]
+    tasks += [Task("fft", fft + p, (0,), 4096) for p in range(P)]
+    tasks += [Task("conj_multiply", conj + p, (fft + p,), 4096)
+              for p in range(P)]
+    tasks += [Task("inverse_fft", ifft + p, (conj + p,), 4096)
+              for p in range(P)]
+    corner = tuple(range(ifft, ifft + P))
+    tasks += [Task("doppler_fft", ifft + P + b, corner, 4096)
+              for b in range(B)]
+    name = ("pulse_doppler_cpi" if (P, B) == (128, 64)
+            else f"pulse_doppler_cpi_{P}x{B}")
+    app = Application(name, tuple(tasks))
+    app.validate()
+    return app
+
+
 REFERENCE_APPS = {
     "wifi_tx": wifi_tx,
     "wifi_rx": wifi_rx,
     "single_carrier": single_carrier,
     "range_detection": range_detection,
     "pulse_doppler": pulse_doppler,
+    "pulse_doppler_cpi": pulse_doppler_cpi,
 }
 
 

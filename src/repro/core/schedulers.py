@@ -221,6 +221,12 @@ def solve_optimal_table(db: ResourceDB, app: Application,
             rec(i + 1, assign, finish, pe_free, pe_load, states)
             assign.pop(); finish.pop(); pe_free[j] = old_free; pe_load[j] = old_load
 
-    rec(0, [], [], [0.0] * n, [0.0] * n, [0])
+    states = [0]
+    rec(0, [], [], [0.0] * n, [0.0] * n, states)
+    if states[0] > max_states:
+        raise ValueError(
+            f"{app.name}: the exact table search ran out of its budget of "
+            f"max_states={max_states} before proving an optimum ({T} tasks "
+            f"on {n} PEs)")
     assert best["assign"] is not None, "optimal table search failed"
     return {(app.name, t): int(best["assign"][t]) for t in range(T)}

@@ -49,6 +49,21 @@ BIG = jnp.float32(1e30)
 _COMPILES_STATIC = _obs_counter("kernel.jax.simulate.compile_count")
 _COMPILES_DTPM = _obs_counter("kernel.jax.simulate_dtpm.compile_count")
 _COMPILES_TELEMETRY = _obs_counter("obs.telemetry.scan.compile_count")
+# the DAG the most recently built tables hold: its edges (summed over the
+# apps) and its largest in-degree — the corner turn's width (K_in)
+_EDGES = _obs_counter("sim.tables.edges")
+_MAX_IN_DEGREE = _obs_counter("sim.tables.max_in_degree")
+# epoch-scan steps launched, summed over lanes (J·T per lane, or the
+# fail-stop bound): the sequential work a call asks of the device
+_SCAN_STEPS = _obs_counter("sim.scan.steps")
+
+
+def count_scan_steps(lanes: int, num_jobs: int, t_max: int,
+                     scan_steps: Optional[int] = None) -> None:
+    """Add one launch's scan steps (``lanes`` × its static scan length) to
+    ``sim.scan.steps``."""
+    _SCAN_STEPS.inc(int(lanes) * (num_jobs * t_max if scan_steps is None
+                                  else int(scan_steps)))
 
 # Frequency domains: one per SoC cluster; make_soc uses 0=big, 1=LITTLE,
 # 2=accelerator fabric.  Padded PE slots map to the last (accel) domain,
@@ -64,8 +79,9 @@ MIN_DOMAINS = 3
 @dataclasses.dataclass(frozen=True)
 class SimTables:
     exec_us: jnp.ndarray        # (A, T, P) f32 — DVFS-scaled latency, BIG=unsupported
-    pred: jnp.ndarray           # (A, T, T) bool
-    ebytes: jnp.ndarray         # (A, T, T) f32 (bytes flowing t' -> t)
+    pred_idx: jnp.ndarray       # (A, T, K_in) i32 predecessor ids, -1 = none
+    pred_bytes: jnp.ndarray     # (A, T, K_in) f32 bytes on each pred edge
+    succ_idx: jnp.ndarray       # (A, T, K_out) i32 successor ids, -1 = none
     valid: jnp.ndarray          # (A, T) bool
     comm_mult: jnp.ndarray      # (P, P) f32 in {0,1,penalty}
     comm_startup: jnp.ndarray   # () f32
@@ -85,16 +101,18 @@ class SimTables:
     domain_cpu: Optional[jnp.ndarray] = None        # (C,) f32 CPU PEs per domain
     t_max: int = 0
     num_pes: int = 0
+    depth: int = 0              # edges on the longest path of any app's DAG
 
 
 jax.tree_util.register_dataclass(
     SimTables,
-    data_fields=["exec_us", "pred", "ebytes", "valid", "comm_mult",
+    data_fields=["exec_us", "pred_idx", "pred_bytes", "succ_idx", "valid",
+                 "comm_mult",
                  "comm_startup", "comm_inv_bw", "power_active", "power_idle",
                  "table_pe", "node_of_pe", "pe_domain", "pe_is_cpu",
                  "exec_opp", "power_active_opp", "opp_freq", "num_opp",
                  "domain_node", "domain_cpu"],
-    meta_fields=["t_max", "num_pes"],
+    meta_fields=["t_max", "num_pes", "depth"],
 )
 
 
@@ -159,9 +177,13 @@ def build_tables_host(db: ResourceDB, apps: Sequence[Application],
         if pe.is_cpu and pe.cluster not in freq:
             freq[pe.cluster] = governor.initial_freq(pe.pe_type)
 
+    # dependency lists, padded to the widest in- and out-degree (>= 1)
+    k_in = max(1, max(a.max_in_degree for a in apps))
+    k_out = max(1, max(a.max_out_degree for a in apps))
     exec_us = np.full((A, T, P), 1e30, dtype=np.float32)
-    pred = np.zeros((A, T, T), dtype=bool)
-    ebytes = np.zeros((A, T, T), dtype=np.float32)
+    pred_idx = np.full((A, T, k_in), -1, dtype=np.int32)
+    pred_bytes = np.zeros((A, T, k_in), dtype=np.float32)
+    succ_idx = np.full((A, T, k_out), -1, dtype=np.int32)
     valid = np.zeros((A, T), dtype=bool)
     table_pe = np.full((A, T), -1, dtype=np.int32)
 
@@ -177,8 +199,11 @@ def build_tables_host(db: ResourceDB, apps: Sequence[Application],
                     exec_us[ai, t, j] = np.float32(np.float32(base) * np.float32(scale))
             if table is not None:
                 table_pe[ai, t] = table.get((app.name, t), -1)
-        pred[ai, :app.num_tasks, :app.num_tasks] = app.pred_matrix()
-        ebytes[ai, :app.num_tasks, :app.num_tasks] = app.edge_bytes_matrix()
+        n = app.num_tasks
+        pred_idx[ai, :n], pred_bytes[ai, :n] = app.pred_lists(k_in)
+        succ_idx[ai, :n] = app.succ_lists(k_out)
+    _EDGES.set(sum(a.num_edges for a in apps))
+    _MAX_IN_DEGREE.set(max(a.max_in_degree for a in apps))
 
     comm_mult = np.zeros((P, P), dtype=np.float32)
     for s in range(db.num_pes):
@@ -211,13 +236,13 @@ def build_tables_host(db: ResourceDB, apps: Sequence[Application],
         opp_kw = _build_opp_tables(db, apps, A, T, P, C, freq_caps)
 
     return SimTables(
-        exec_us=exec_us, pred=pred, ebytes=ebytes, valid=valid,
-        comm_mult=comm_mult,
+        exec_us=exec_us, pred_idx=pred_idx, pred_bytes=pred_bytes,
+        succ_idx=succ_idx, valid=valid, comm_mult=comm_mult,
         comm_startup=np.asarray(db.comm.startup_us, np.float32),
         comm_inv_bw=np.asarray(1.0 / db.comm.bw_bytes_per_us, np.float32),
         power_active=p_act, power_idle=p_idle, table_pe=table_pe,
         node_of_pe=node_of_pe, pe_domain=pe_domain, pe_is_cpu=pe_is_cpu,
-        t_max=T, num_pes=P, **opp_kw)
+        t_max=T, num_pes=P, depth=max(a.depth for a in apps), **opp_kw)
 
 
 def _build_opp_tables(db: ResourceDB, apps: Sequence[Application],
@@ -392,8 +417,6 @@ def _epoch_scan(tables: SimTables, policy: str, num_jobs: int,
         raise ValueError("the faulted scan needs a static scan_steps bound "
                          "(see repro.scenario.faults.fault_scan_steps)")
 
-    pred_j = tables.pred[app_idx]          # (J, T, T)
-    ebytes_j = tables.ebytes[app_idx]      # (J, T, T)
     valid_j = tables.valid[app_idx]        # (J, T)
     table_j = tables.table_pe[app_idx]     # (J, T)
     if not dtpm:
@@ -403,12 +426,18 @@ def _epoch_scan(tables: SimTables, policy: str, num_jobs: int,
     # re-commits + skip epochs a caller-supplied fault budget adds
     total = J * T if scan_steps is None else scan_steps
 
+    # dependencies as fixed-width lists: the carry keeps each task's count
+    # of uncommitted preds and the max finish of its committed ones, both
+    # updated at commit through the committed task's successor list
     state = dict(
         scheduled=~valid_j,                              # invalid = pre-done
         finish=jnp.zeros((J, T), jnp.float32),
         start=jnp.zeros((J, T), jnp.float32),
         onpe=jnp.zeros((J, T), jnp.int32),
         pe_free=jnp.zeros((P,), jnp.float32),
+        open=jnp.sum(tables.pred_idx[app_idx] >= 0, axis=-1,
+                     dtype=jnp.int32),                   # (J, T) preds left
+        pred_fin=jnp.full((J, T), -BIG, jnp.float32),    # max pred finish
     )
     if faulted:
         state.update(
@@ -433,6 +462,7 @@ def _epoch_scan(tables: SimTables, policy: str, num_jobs: int,
 
     flat_order = (jnp.arange(J, dtype=jnp.int32)[:, None] * T
                   + jnp.arange(T, dtype=jnp.int32)[None, :])      # (J, T)
+    rows = jnp.arange(J, dtype=jnp.int32)[:, None, None]
 
     def advance_window(st, carry):
         """Advance one sampling window; the telemetry aux is dropped here
@@ -440,26 +470,38 @@ def _epoch_scan(tables: SimTables, policy: str, num_jobs: int,
         return _window_step(tables, valid_j, window, up, cap, Am1_rc, B_rc,
                             st, carry)[0]
 
+    def child_of(mask):
+        """(J, T): tasks with a predecessor in ``mask``, scattered through
+        the successor lists (the -1 padding lands in a dropped column)."""
+        succ = tables.succ_idx[app_idx]                            # (J,T,K)
+        tgt = jnp.where(mask[..., None] & (succ >= 0), succ, T)
+        return jnp.zeros((J, T + 1), bool).at[rows, tgt].set(True)[:, :T]
+
     def apply_faults(st, fire):
         """Fail-stop rollback (the in-scan twin of the reference kernel's
         ``apply_failure``): invalidate unfinished tasks on the PEs firing
         now plus their committed-descendant closure, reset their records,
-        recompute the queue drain times from the surviving schedule, and
-        floor direct victims at the fail time (descendants and tasks whose
-        pred was lost re-ready off their preds' fresh finish times)."""
+        recompute the queue drain times from the surviving schedule and
+        the open-pred counts and pred finishes from the surviving preds,
+        and floor direct victims at the fail time (descendants and tasks
+        whose pred was lost re-ready off their preds' fresh finish times).
+        The closure walks the successor lists ``tables.depth`` times: no
+        descendant is further than the longest path."""
         committed = st["scheduled"] & valid_j
         onpe, fin = st["onpe"], st["finish"]
         ftime = faults[onpe]                                       # (J, T)
         inv = committed & fire[onpe] & (fin > ftime)
-        closure = lambda _, acc: acc | (
-            committed & jnp.any(pred_j & acc[:, None, :], axis=-1))
-        inv = jax.lax.fori_loop(0, T, closure, inv)
-        any_pred_inv = jnp.any(pred_j & inv[:, None, :], axis=-1)  # (J, T)
+        closure = lambda _, acc: acc | (committed & child_of(acc))
+        inv = jax.lax.fori_loop(0, tables.depth, closure, inv)
+        any_pred_inv = child_of(inv)                               # (J, T)
         roots = inv & ~any_pred_inv                # all preds still committed
         sched2 = st["scheduled"] & ~inv
         fin2 = jnp.where(inv, 0.0, fin)
         recomputed = jnp.zeros((P,), jnp.float32).at[onpe].max(
             jnp.where(sched2 & valid_j, fin2, 0.0))
+        pidx = tables.pred_idx[app_idx]                            # (J,T,K)
+        src = jnp.maximum(pidx, 0)
+        done = (pidx >= 0) & sched2[rows, src]
         new = dict(
             st,
             scheduled=sched2,
@@ -467,6 +509,9 @@ def _epoch_scan(tables: SimTables, policy: str, num_jobs: int,
             start=jnp.where(inv, 0.0, st["start"]),
             onpe=jnp.where(inv, 0, onpe),
             pe_free=jnp.where(jnp.any(inv), recomputed, st["pe_free"]),
+            open=jnp.sum((pidx >= 0) & ~done, axis=-1, dtype=jnp.int32),
+            pred_fin=jnp.max(jnp.where(done, fin2[rows, src], -BIG),
+                             axis=-1),
             fired=st["fired"] | fire,
             floor=jnp.where(roots, ftime,
                             jnp.where(any_pred_inv, 0.0, st["floor"])),
@@ -476,14 +521,12 @@ def _epoch_scan(tables: SimTables, policy: str, num_jobs: int,
         return new
 
     def body(st, _):
-        scheduled, finish = st["scheduled"], st["finish"]
+        scheduled = st["scheduled"]
         # 1. eligibility: job tasks whose preds are all committed
-        preds_open = jnp.any(pred_j & ~scheduled[:, None, :], axis=-1)   # (J, T)
-        eligible = (~scheduled) & (~preds_open)
+        eligible = (~scheduled) & (st["open"] == 0)                     # (J, T)
         # 2. epoch time (no comm): max(arrival, max pred finish); rolled-back
         # direct fault victims additionally wait out the fail time (floor)
-        pf = jnp.where(pred_j, finish[:, None, :], -BIG)                  # (J,T,T)
-        ready = jnp.maximum(arrival[:, None], jnp.max(pf, axis=-1))      # (J, T)
+        ready = jnp.maximum(arrival[:, None], st["pred_fin"])            # (J, T)
         if faulted:
             ready = jnp.maximum(ready, st["floor"])
         ready = jnp.where(eligible, ready, BIG)
@@ -492,6 +535,7 @@ def _epoch_scan(tables: SimTables, policy: str, num_jobs: int,
         tie = eligible & (ready <= rmin)
         pick = jnp.min(jnp.where(tie, flat_order, jnp.int32(2**30)))
         j, t = pick // T, pick % T
+        a = app_idx[j]
         any_left = rmin < BIG * 0.5
 
         # 3a. fail-stop events this epoch crosses fire before anything else
@@ -502,7 +546,7 @@ def _epoch_scan(tables: SimTables, policy: str, num_jobs: int,
             fire = (~st["fired"]) & (faults <= rmin) & any_left
             st = jax.lax.cond(jnp.any(fire), apply_faults,
                               lambda s, _f: s, st, fire)
-            skip = jnp.any(pred_j[j, t] & ~st["scheduled"][j])
+            skip = st["open"][j, t] > 0
             do_commit = any_left & ~skip
         else:
             do_commit = any_left
@@ -518,16 +562,20 @@ def _epoch_scan(tables: SimTables, policy: str, num_jobs: int,
             st = dict(st, opp_idx=opp_idx, next_w=next_w, temps=temps,
                       peak_t=peak)
             opp_of_pe = opp_idx[tables.pe_domain]                     # (P,)
-            ex = tables.exec_opp[app_idx[j], t][jnp.arange(P), opp_of_pe]
+            ex = tables.exec_opp[a, t][jnp.arange(P), opp_of_pe]
         else:
             ex = exec_j[j, t]                                         # (P,)
 
-        # 4. per-PE data-ready with comm from producer PEs
-        onpe_row = st["onpe"][j]                                        # (T,)
-        mult = tables.comm_mult[onpe_row]                               # (T, P)
-        base = tables.comm_startup + ebytes_j[j, t] * tables.comm_inv_bw  # (T,)
-        comm = mult * base[:, None]                                     # (T, P)
-        pf_row = jnp.where(pred_j[j, t], st["finish"][j], -BIG)         # (T,)
+        # 4. per-PE data-ready with comm from the producer PEs of the
+        # task's K_in predecessors, selected from row j by a (K, T) match
+        # (no gather; the -1 padding matches no task: finish -BIG, PE 0)
+        sel = tables.pred_idx[a, t][:, None] == jnp.arange(T)           # (K, T)
+        src_pe = jnp.max(jnp.where(sel, st["onpe"][j], 0), axis=1)      # (K,)
+        mult = tables.comm_mult[src_pe]                                 # (K, P)
+        base = (tables.comm_startup
+                + tables.pred_bytes[a, t] * tables.comm_inv_bw)         # (K,)
+        comm = mult * base[:, None]                                     # (K, P)
+        pf_row = jnp.max(jnp.where(sel, st["finish"][j], -BIG), axis=1)  # (K,)
         data_ready = jnp.maximum(
             rmin, jnp.max(pf_row[:, None] + comm, axis=0))              # (P,)
         start_c = jnp.maximum(data_ready, st["pe_free"])                # (P,)
@@ -550,24 +598,31 @@ def _epoch_scan(tables: SimTables, policy: str, num_jobs: int,
         else:
             raise ValueError(f"unknown policy {policy!r}")
 
-        # 6. commit (no-op when nothing eligible — padding iterations)
+        # 6. commit (no-op when nothing eligible — padding iterations) as
+        # masked elementwise updates, no branch: the picked task's cell and
+        # its successors' cells in row j (the successors lose one open pred
+        # and see this finish; the -1 padding matches no column)
         s0 = jnp.maximum(data_ready[pe], st["pe_free"][pe])
         f0 = s0 + ex[pe]
-
-        def commit(st):
-            new = dict(
-                st,
-                scheduled=st["scheduled"].at[j, t].set(True),
-                finish=st["finish"].at[j, t].set(f0),
-                start=st["start"].at[j, t].set(s0),
-                onpe=st["onpe"].at[j, t].set(pe),
-                pe_free=st["pe_free"].at[pe].set(f0),
-            )
-            if dtpm:
-                new["onopp"] = st["onopp"].at[j, t].set(opp_of_pe[pe])
-            return new
-
-        return jax.lax.cond(do_commit, commit, lambda s: s, st), None
+        hit = (flat_order == pick) & do_commit                          # (J, T)
+        sidx = tables.succ_idx[a, t]                                    # (K,)
+        kid = ((jnp.arange(J) == j)[:, None] & do_commit
+               & jnp.any(sidx[:, None] == jnp.arange(T), axis=0))       # (J, T)
+        new = dict(
+            st,
+            scheduled=st["scheduled"] | hit,
+            finish=jnp.where(hit, f0, st["finish"]),
+            start=jnp.where(hit, s0, st["start"]),
+            onpe=jnp.where(hit, pe, st["onpe"]),
+            pe_free=jnp.where((jnp.arange(P) == pe) & do_commit, f0,
+                              st["pe_free"]),
+            open=st["open"] - kid.astype(jnp.int32),
+            pred_fin=jnp.where(kid, jnp.maximum(st["pred_fin"], f0),
+                               st["pred_fin"]),
+        )
+        if dtpm:
+            new["onopp"] = jnp.where(hit, opp_of_pe[pe], st["onopp"])
+        return new, None
 
     st, _ = jax.lax.scan(body, state, None, length=total)
 
@@ -660,9 +715,11 @@ def simulate_jax(tables: SimTables, policy: str, arrival: np.ndarray,
         J = int(arrival.shape[0])
         arrival = jnp.asarray(arrival, jnp.float32)
         app_idx = jnp.asarray(app_idx, jnp.int32)
+        steps = None
         if faults is not None:
             steps = _fault_steps(J, tables.t_max, faults)
             faults = jnp.asarray(faults, jnp.float32)
+        count_scan_steps(1, J, tables.t_max, steps)
     with _obs_span("repro.launch"):
         if faults is None:
             return _simulate(tables, policy, J, arrival, app_idx)
@@ -692,9 +749,11 @@ def simulate_jax_dtpm(tables: SimTables, policy: str, arrival: np.ndarray,
         J = int(arrival.shape[0])
         arrival = jnp.asarray(arrival, jnp.float32)
         app_idx = jnp.asarray(app_idx, jnp.int32)
+        steps = None
         if faults is not None:
             steps = _fault_steps(J, tables.t_max, faults)
             faults = jnp.asarray(faults, jnp.float32)
+        count_scan_steps(1, J, tables.t_max, steps)
     with _obs_span("repro.launch"):
         if faults is None:
             return _simulate_dtpm(tables, policy, J, arrival, app_idx, gov)

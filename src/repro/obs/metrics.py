@@ -33,7 +33,7 @@ MANIFEST_SCHEMA = "repro.obs/manifest/v1"
 
 
 class Counter:
-    """A named monotonic counter (``.value`` / ``.inc()`` / ``.reset()``).
+    """A named counter (``.value`` / ``.inc()`` / ``.set()`` / ``.reset()``).
 
     The legacy one-element-list protocol (``c[0]``), deprecated when the
     registry replaced the ``compile_count = [0]`` hack and kept for one
@@ -55,6 +55,12 @@ class Counter:
 
     def reset(self) -> None:
         self._value = 0
+
+    def set(self, n: int) -> int:
+        """Hold ``n`` (a counter that reports the latest state, not a
+        total)."""
+        self._value = int(n)
+        return self._value
 
     def __int__(self) -> int:
         return self._value

@@ -189,6 +189,12 @@ class Scenario:
                 f"|{self.scheduler}|{self.governor}")
 
 
+# the widest DAG scheduler="table" accepts: the exact branch and bound is
+# exponential in the task count, and its state budget runs out near 15
+# tasks on the Table-2 SoC
+TABLE_MAX_TASKS = 12
+
+
 @functools.lru_cache(maxsize=64)
 def _solve_table_cached(design: DesignPoint,
                         apps: Tuple[Union[str, Application], ...]):
@@ -196,6 +202,11 @@ def _solve_table_cached(design: DesignPoint,
     table: Dict[Tuple[str, int], int] = {}
     for app in (a if isinstance(a, Application) else get_application(a)
                 for a in apps):
+        if app.num_tasks > TABLE_MAX_TASKS:
+            raise ValueError(
+                f"scheduler='table' solves each DAG exactly, which reaches "
+                f"DAGs of at most TABLE_MAX_TASKS={TABLE_MAX_TASKS} tasks; "
+                f"{app.name} has {app.num_tasks}. Use 'met' or 'etf'.")
         table.update(solve_optimal_table(db, app))
     return table
 

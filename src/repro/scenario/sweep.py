@@ -43,6 +43,7 @@ import numpy as np
 
 from ..core.dvfs import stack_policies
 from ..core.jobgen import JobTrace
+from ..core.simkernel_jax import count_scan_steps
 from ..dse.batch import pad_node_map, stack_tables, stack_traces
 from ..dse.space import DesignPoint
 from ..obs import metrics as _metrics
@@ -351,6 +352,10 @@ def _sweep(scenario: Scenario, axes: Dict[str, Sequence], backend: str,
             scan_steps = _faults.fault_scan_steps(
                 num_jobs, int(tables.t_max), max_f)
 
+    # lanes of one static combo: faults × designs × policies × traces
+    lanes = ((len(fault_sets) if plans is not None else 1)
+             * len(design_combos) * (len(policies) if dynamic else 1)
+             * len(traces))
     per_static = []
     for sc in static_combos:
         s_scn = _apply_axes(lane_base, static_axes, sc)
@@ -359,6 +364,7 @@ def _sweep(scenario: Scenario, axes: Dict[str, Sequence], backend: str,
                 tables, node_of_pe = _design_lanes(s_scn, design_axes,
                                                    design_combos, pad_pes,
                                                    host=lane_exec)
+        count_scan_steps(lanes, num_jobs, int(tables.t_max), scan_steps)
         if dynamic:
             if plans is not None:
                 if lane_exec:

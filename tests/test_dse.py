@@ -215,7 +215,7 @@ def test_design_batch_leaves_are_device_arrays():
     batch = build_design_batch(_BATCH_POINTS, _apps(),
                                governor=get_governor("throttle"))
     leaves = jax.tree_util.tree_leaves((batch.tables, batch.node_of_pe))
-    assert len(leaves) == 20
+    assert len(leaves) == 21
     assert all(isinstance(x, jax.Array) and not x.committed for x in leaves)
 
 

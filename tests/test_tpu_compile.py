@@ -133,6 +133,49 @@ def test_dse_grid_shards_over_four_chips_without_collectives(topo):
     assert not [c for c in COLLECTIVES if c in text]
 
 
+def test_cpi_grid_compiles_for_v5e(one_chip):
+    """pd_cpi_grid's program at its real size: 6 lanes of 16 CPIs of the
+    449-task pulse-Doppler DAG, dependency lists of width 128."""
+    scn = Scenario(apps=("pulse_doppler_cpi",),
+                   trace=TraceSpec(rate_jobs_per_ms=0.72, num_jobs=16))
+    tb = jax.tree_util.tree_map(lambda x: np.asarray(x)[None],
+                                tables_for(scn, host=True))
+    assert tb.pred_idx.shape == (1, 1, 449, 128)
+    arrival, app_idx = stack_traces([scn.with_seed(s).job_trace()
+                                     for s in range(6)])
+    compiled = shardexec._sweep_grid.lower(
+        _specs(tb, one_chip),
+        _specs(np.zeros((1, tb.num_pes), np.int32), one_chip),
+        _specs(arrival, one_chip), _specs(app_idx, one_chip),
+        policy="etf", num_jobs=16, bins=32, repeats=3).compile()
+    # most of it is the binned thermal scan's (lanes, J*T, bins, P) terms
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_table2_space_shards_over_four_chips(topo):
+    """dse_grid_4chip's program: all 360 Table-2 sub-SoCs x 4 traces with
+    the design lanes split 90 per chip, and no collective."""
+    mesh = Mesh(np.asarray(topo.devices), (LANE_AXIS,))
+    lanes, replicated = (NamedSharding(mesh, PartitionSpec(LANE_AXIS)),
+                         NamedSharding(mesh, PartitionSpec()))
+    base = Scenario(apps=("wifi_tx",), governor="design",
+                    trace=TraceSpec(rate_jobs_per_ms=20.0, num_jobs=32))
+    space = DesignSpace(num_big=tuple(range(5)), num_little=tuple(range(5)),
+                        num_scr=(0, 1, 2), num_fft=tuple(range(5)),
+                        num_vit=(0,), big_freq_ghz=(2.0,),
+                        little_freq_ghz=(1.4,))
+    points = space.grid()
+    assert len(points) == 360
+    batch = build_design_batch(points, base.applications(), pad_pes=14)
+    arrival, app_idx = stack_traces([base.with_seed(s).job_trace()
+                                     for s in range(4)])
+    compiled = shardexec._sweep_grid.lower(
+        _specs(batch.tables, lanes), _specs(batch.node_of_pe, lanes),
+        _specs(arrival, replicated), _specs(app_idx, replicated),
+        policy="etf", num_jobs=32, bins=32, repeats=3).compile()
+    assert not [c for c in COLLECTIVES if c in compiled.as_text()]
+
+
 @pytest.mark.parametrize("governor", ["performance", "ondemand"])
 def test_lane_grid_programs_hold_no_contraction(governor):
     """The grid programs sum with masked reduces, never a contraction: at
