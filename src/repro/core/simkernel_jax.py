@@ -36,7 +36,7 @@ from .dvfs import (Governor, GovernorPolicy, MAX_OPP_LEVELS,
                    PerformanceGovernor, ondemand_index, padded_ladder,
                    throttle_index, validate_policy_params)
 from .power import active_power, idle_power
-from .resources import NOMINAL_FREQ, ResourceDB
+from .resources import INF, NOMINAL_FREQ, ResourceDB
 from . import thermal as _thermal
 from ..obs.metrics import counter as _obs_counter
 from ..obs.metrics import span as _obs_span
@@ -136,11 +136,8 @@ def build_tables_host(db: ResourceDB, apps: Sequence[Application],
                       pad_pes: Optional[int] = None,
                       freq_caps: Optional[Mapping[str, float]] = None
                       ) -> SimTables:
-    """Build one SoC design's simulation tables with numpy leaves.
-
-    Nothing touches the device: callers that stack many designs
-    (``repro.dse.batch``) place the stacked batch once instead of every
-    design's leaves one by one.
+    """Build one SoC design's simulation tables with numpy leaves: the
+    one-design case of :func:`build_tables_batch_host`.
 
     ``pad_tasks`` / ``pad_pes`` pad the task and PE axes to a fixed size so
     tables from *different* designs stack into one (D, …) batch (see
@@ -155,150 +152,210 @@ def build_tables_host(db: ResourceDB, apps: Sequence[Application],
     ladder — the design's hardware envelope; it defaults to the governor's
     own ``freq_caps`` (attached by ``Scenario.make_governor`` from the
     design point), keeping ref and jax on the same capped OPP set.
+    ``table`` ((app name, task) → PE slot) fills ``table_pe`` for the
+    table scheduler.
     """
     governor = governor or PerformanceGovernor()
-    dynamic = governor.policy().dynamic
     if freq_caps is None:
         freq_caps = getattr(governor, "freq_caps", None)
-    A = len(apps)
+    batch, _ = build_tables_batch_host([db], apps, [governor], [freq_caps],
+                                       pad_tasks=pad_tasks, pad_pes=pad_pes)
+    tables = jax.tree_util.tree_map(lambda x: x[0, ...], batch)
+    if table is not None:
+        for ai, app in enumerate(apps):
+            for t in range(app.num_tasks):
+                tables.table_pe[ai, t] = table.get((app.name, t), -1)
+    return tables
+
+
+def build_tables_batch_host(
+        dbs: Sequence[ResourceDB], apps: Sequence[Application],
+        governors: Sequence[Governor],
+        freq_caps: Optional[Sequence[Optional[Mapping[str, float]]]] = None,
+        pad_tasks: Optional[int] = None, pad_pes: Optional[int] = None
+        ) -> Tuple[SimTables, int]:
+    """Simulation tables of D designs with numpy leaves of a leading (D, …)
+    axis, and the number of distinct rows filled.
+
+    Design ``d`` runs ``governors[d]``; ``freq_caps[d]`` truncates its OPP
+    ladders under a dynamic governor (default: the governor's own
+    ``freq_caps``).  Every design pads to the widest one (or ``pad_pes``).
+
+    No per-PE value depends on the design as such: a slot's latency and
+    power rows depend on its PE type and cluster frequency (and, under a
+    dynamic governor, its capped OPP ladder), given the profiles.  So the
+    designs' slots are first keyed into (D, P) index arrays; one row is
+    filled per distinct key — keyed by profile *content*, as every design
+    holds its own copy — and the (D, …) leaves are numpy gathers of those
+    rows, plus a trailing inert row for padded slots.  Each leaf equals
+    the stack of the designs' one-at-a-time tables bit for bit: a row is
+    the same scalar arithmetic, f32(base) · f32(nominal / f), whichever
+    slot it lands in.  Dependency lists depend only on ``apps`` and are
+    built once.
+    """
+    if freq_caps is None:
+        freq_caps = [getattr(g, "freq_caps", None) for g in governors]
+    kinds = {g.policy().dynamic for g in governors}
+    if len(kinds) != 1:
+        raise ValueError("designs mix static and dynamic governors")
+    dynamic = kinds.pop()
+    D, A, K = len(dbs), len(apps), MAX_OPP_LEVELS
     T = max(a.num_tasks for a in apps)
-    P = db.num_pes
+    P = max(db.num_pes for db in dbs)
     if pad_tasks is not None:
         if pad_tasks < T:
             raise ValueError(f"pad_tasks={pad_tasks} < max tasks {T}")
         T = pad_tasks
     if pad_pes is not None:
         if pad_pes < P:
-            raise ValueError(f"pad_pes={pad_pes} < num_pes {P}")
+            raise ValueError(f"pad_pes={pad_pes} < widest design's {P} PEs")
         P = pad_pes
 
-    freq = {}
-    for pe in db.pes:
-        if pe.is_cpu and pe.cluster not in freq:
-            freq[pe.cluster] = governor.initial_freq(pe.pe_type)
+    # (D, P) slot attributes: row key index (len(rows) = the inert padding
+    # row, patched in below), cluster; per design its domain count and each
+    # domain's last CPU slot's key (the one the per-slot loop left there)
+    profiles: List[Mapping] = []
+    keys: Dict[tuple, int] = {}
+    rows: List[tuple] = []                  # (profiles, pe, f, ladder)
+    slot = np.full((D, P), -1, dtype=np.int64)
+    cluster = np.full((D, P), -1, dtype=np.int64)
+    domains = np.empty(D, dtype=np.int64)
+    dom_rows: List[Dict[int, int]] = []
+    dom_cpus: List[Dict[int, int]] = []
+    for d, (db, gov, caps) in enumerate(zip(dbs, governors, freq_caps)):
+        g = next((i for i, prof in enumerate(profiles)
+                  if prof == db.profiles), None)
+        if g is None:
+            g = len(profiles)
+            profiles.append(db.profiles)
+        freq: Dict[int, float] = {}
+        last: Dict[int, int] = {}
+        cpus: Dict[int, int] = {}
+        for j, pe in enumerate(db.pes):
+            f, ladder = 0.0, None
+            if pe.is_cpu:
+                f = freq.get(pe.cluster)
+                if f is None:
+                    f = freq[pe.cluster] = gov.initial_freq(pe.pe_type)
+                if dynamic:
+                    _, row, n = padded_ladder(pe.pe_type, caps)
+                    ladder = (tuple(row), n)
+            key = (g, pe.pe_type, f, ladder)
+            i = keys.get(key)
+            if i is None:
+                i = keys[key] = len(rows)
+                rows.append((profiles[g], pe, f, ladder))
+            slot[d, j] = i
+            cluster[d, j] = pe.cluster
+            if pe.is_cpu:
+                last[pe.cluster] = i
+                cpus[pe.cluster] = cpus.get(pe.cluster, 0) + 1
+        domains[d] = max(MIN_DOMAINS, max(pe.cluster for pe in db.pes) + 1)
+        dom_rows.append(last)
+        dom_cpus.append(cpus)
+    pad = len(rows)
+    real = slot >= 0
+    slot[~real] = pad
+
+    # one row per key; row ``pad`` stays inert (BIG latency, zero power)
+    exec_rows = np.full((pad + 1, A, T), 1e30, dtype=np.float32)
+    p_act_rows = np.zeros(pad + 1, dtype=np.float32)
+    p_idle_rows = np.zeros(pad + 1, dtype=np.float32)
+    node_rows = np.full(pad + 1, _thermal.NODE_ACCEL, dtype=np.int32)
+    cpu_rows = np.zeros(pad + 1, dtype=np.float32)
+    if dynamic:
+        exec_opp_rows = np.full((pad + 1, A, T, K), 1e30, dtype=np.float32)
+        p_opp_rows = np.zeros((pad + 1, K), dtype=np.float32)
+        ladder_rows = np.zeros((pad + 1, K), dtype=np.float32)
+        num_opp_rows = np.ones(pad + 1, dtype=np.int32)
+    for i, (prof, pe, f, ladder) in enumerate(rows):
+        scale = NOMINAL_FREQ[pe.pe_type] / f if pe.is_cpu else 1.0
+        if dynamic:
+            if pe.is_cpu:
+                ladder_rows[i] = ladder[0]
+                num_opp_rows[i] = ladder[1]
+                p_opp_rows[i] = [active_power(pe, fk) for fk in ladder[0]]
+                opp_scale = np.array(
+                    [np.float32(NOMINAL_FREQ[pe.pe_type] / fk)
+                     for fk in ladder[0]], dtype=np.float32)
+            else:
+                p_opp_rows[i] = active_power(pe, 0.0)
+                opp_scale = np.ones(K, dtype=np.float32)
+        for ai, app in enumerate(apps):
+            base = np.array([prof.get(t, {}).get(pe.pe_type, INF)
+                             for t in app.task_names], dtype=np.float32)
+            ok = np.isfinite(base)
+            n = app.num_tasks
+            exec_rows[i, ai, :n][ok] = base[ok] * np.float32(scale)
+            if dynamic:
+                exec_opp_rows[i, ai, :n][ok] = base[ok, None] * opp_scale
+        p_act_rows[i] = active_power(pe, f)
+        p_idle_rows[i] = idle_power(pe)
+        node_rows[i] = _thermal.pe_node(pe.pe_type)
+        cpu_rows[i] = 1.0 if pe.is_cpu else 0.0
 
     # dependency lists, padded to the widest in- and out-degree (>= 1)
     k_in = max(1, max(a.max_in_degree for a in apps))
     k_out = max(1, max(a.max_out_degree for a in apps))
-    exec_us = np.full((A, T, P), 1e30, dtype=np.float32)
     pred_idx = np.full((A, T, k_in), -1, dtype=np.int32)
     pred_bytes = np.zeros((A, T, k_in), dtype=np.float32)
     succ_idx = np.full((A, T, k_out), -1, dtype=np.int32)
     valid = np.zeros((A, T), dtype=bool)
-    table_pe = np.full((A, T), -1, dtype=np.int32)
-
     for ai, app in enumerate(apps):
-        lat = db.latency_matrix(app.task_names)      # (t, P), inf unsupported
-        for t in range(app.num_tasks):
-            valid[ai, t] = True
-            for j, pe in enumerate(db.pes):
-                base = lat[t, j]
-                if np.isfinite(base):
-                    scale = (NOMINAL_FREQ[pe.pe_type] / freq[pe.cluster]
-                             if pe.is_cpu else 1.0)
-                    exec_us[ai, t, j] = np.float32(np.float32(base) * np.float32(scale))
-            if table is not None:
-                table_pe[ai, t] = table.get((app.name, t), -1)
         n = app.num_tasks
+        valid[ai, :n] = True
         pred_idx[ai, :n], pred_bytes[ai, :n] = app.pred_lists(k_in)
         succ_idx[ai, :n] = app.succ_lists(k_out)
     _EDGES.set(sum(a.num_edges for a in apps))
     _MAX_IN_DEGREE.set(max(a.max_in_degree for a in apps))
 
-    comm_mult = np.zeros((P, P), dtype=np.float32)
-    for s in range(db.num_pes):
-        for d in range(db.num_pes):
-            if s == d:
-                continue
-            comm_mult[s, d] = (db.comm.cross_cluster_penalty
-                               if db.pes[s].cluster != db.pes[d].cluster else 1.0)
+    def per_design(x):
+        return np.broadcast_to(x, (D,) + x.shape).copy()
 
-    p_act = np.zeros(P, dtype=np.float32)
-    p_idle = np.zeros(P, dtype=np.float32)
-    for j, pe in enumerate(db.pes):
-        f = freq.get(pe.cluster, 0.0) if pe.is_cpu else 0.0
-        p_act[j] = active_power(pe, f)
-        p_idle[j] = idle_power(pe)
-
-    # frequency-domain / thermal-node maps (padded slots are inert: zero
-    # power, non-CPU, binned to the accel node/domain by convention)
-    C = max(MIN_DOMAINS, max(pe.cluster for pe in db.pes) + 1)
-    node_of_pe = np.full(P, _thermal.NODE_ACCEL, dtype=np.int32)
-    node_of_pe[:db.num_pes] = _thermal.cluster_nodes(db)
-    pe_domain = np.full(P, C - 1, dtype=np.int32)
-    pe_is_cpu = np.zeros(P, dtype=np.float32)
-    for j, pe in enumerate(db.pes):
-        pe_domain[j] = pe.cluster
-        pe_is_cpu[j] = 1.0 if pe.is_cpu else 0.0
+    # real slots talk at 1 within a cluster, at the design's penalty across
+    # clusters, and not at all to themselves or to padding
+    penalty = np.array([db.comm.cross_cluster_penalty for db in dbs],
+                       dtype=np.float32)
+    comm_mult = np.where(cluster[:, :, None] != cluster[:, None, :],
+                         penalty[:, None, None], np.float32(1.0))
+    comm_mult[~(real[:, :, None] & real[:, None, :])] = 0.0
+    comm_mult[:, np.arange(P), np.arange(P)] = 0.0
 
     opp_kw: Dict[str, np.ndarray] = {}
     if dynamic:
-        opp_kw = _build_opp_tables(db, apps, A, T, P, C, freq_caps)
+        if len(set(domains.tolist())) != 1:
+            raise ValueError("designs differ in frequency-domain count")
+        C = int(domains[0])
+        dom = np.full((D, C), pad, dtype=np.int64)
+        domain_cpu = np.zeros((D, C), dtype=np.float32)
+        for d, (last, cpus) in enumerate(zip(dom_rows, dom_cpus)):
+            for c, i in last.items():
+                dom[d, c] = i
+                domain_cpu[d, c] = cpus[c]
+        opp_kw = dict(
+            exec_opp=np.ascontiguousarray(
+                exec_opp_rows[slot].transpose(0, 2, 3, 1, 4)),
+            power_active_opp=p_opp_rows[slot], opp_freq=ladder_rows[dom],
+            num_opp=num_opp_rows[dom], domain_node=node_rows[dom],
+            domain_cpu=domain_cpu)
 
-    return SimTables(
-        exec_us=exec_us, pred_idx=pred_idx, pred_bytes=pred_bytes,
-        succ_idx=succ_idx, valid=valid, comm_mult=comm_mult,
-        comm_startup=np.asarray(db.comm.startup_us, np.float32),
-        comm_inv_bw=np.asarray(1.0 / db.comm.bw_bytes_per_us, np.float32),
-        power_active=p_act, power_idle=p_idle, table_pe=table_pe,
-        node_of_pe=node_of_pe, pe_domain=pe_domain, pe_is_cpu=pe_is_cpu,
+    tables = SimTables(
+        exec_us=np.ascontiguousarray(exec_rows[slot].transpose(0, 2, 3, 1)),
+        pred_idx=per_design(pred_idx), pred_bytes=per_design(pred_bytes),
+        succ_idx=per_design(succ_idx), valid=per_design(valid),
+        comm_mult=comm_mult,
+        comm_startup=np.array([db.comm.startup_us for db in dbs],
+                              dtype=np.float32),
+        comm_inv_bw=np.array([1.0 / db.comm.bw_bytes_per_us for db in dbs],
+                             dtype=np.float32),
+        power_active=p_act_rows[slot], power_idle=p_idle_rows[slot],
+        table_pe=np.full((D, A, T), -1, dtype=np.int32),
+        node_of_pe=node_rows[slot],
+        pe_domain=np.where(real, cluster, domains[:, None] - 1
+                           ).astype(np.int32),
+        pe_is_cpu=cpu_rows[slot],
         t_max=T, num_pes=P, depth=max(a.depth for a in apps), **opp_kw)
-
-
-def _build_opp_tables(db: ResourceDB, apps: Sequence[Application],
-                      A: int, T: int, P: int, C: int,
-                      freq_caps: Optional[Mapping[str, float]]) -> Dict:
-    """The (…, K) OPP-indexed tables the DTPM kernel gathers from.
-
-    Level ladders are ascending and top-padded by repeating the highest real
-    level; ``num_opp`` bounds the real counts (truncated under ``freq_caps``,
-    but never below one level).  ``exec_opp`` quantises exactly like the
-    reference path — f32(base) · f32(nominal/f) — so the two kernels see
-    bit-identical latencies at every OPP.
-    """
-    K = MAX_OPP_LEVELS
-    exec_opp = np.full((A, T, P, K), 1e30, dtype=np.float32)
-    p_act_opp = np.zeros((P, K), dtype=np.float32)
-    opp_freq = np.zeros((C, K), dtype=np.float32)
-    num_opp = np.ones(C, dtype=np.int32)
-    domain_node = np.full(C, _thermal.NODE_ACCEL, dtype=np.int32)
-    domain_cpu = np.zeros(C, dtype=np.float32)
-    nodes = _thermal.cluster_nodes(db)
-    ladders = {pe.pe_type: padded_ladder(pe.pe_type, freq_caps)
-               for pe in db.pes if pe.is_cpu}
-
-    for j, pe in enumerate(db.pes):
-        if pe.is_cpu:
-            _, row, n = ladders[pe.pe_type]
-            c = pe.cluster
-            num_opp[c] = n
-            domain_node[c] = nodes[j]
-            domain_cpu[c] += 1.0
-            for k in range(K):
-                opp_freq[c, k] = row[k]
-                p_act_opp[j, k] = active_power(pe, row[k])
-        else:
-            p_act_opp[j, :] = active_power(pe, 0.0)
-
-    for ai, app in enumerate(apps):
-        lat = db.latency_matrix(app.task_names)
-        for t in range(app.num_tasks):
-            for j, pe in enumerate(db.pes):
-                base = lat[t, j]
-                if not np.isfinite(base):
-                    continue
-                if pe.is_cpu:
-                    _, row, _ = ladders[pe.pe_type]
-                    for k in range(K):
-                        scale = np.float32(NOMINAL_FREQ[pe.pe_type] / row[k])
-                        exec_opp[ai, t, j, k] = np.float32(
-                            np.float32(base) * scale)
-                else:
-                    exec_opp[ai, t, j, :] = np.float32(base)
-
-    return dict(exec_opp=exec_opp, power_active_opp=p_act_opp,
-                opp_freq=opp_freq, num_opp=num_opp, domain_node=domain_node,
-                domain_cpu=domain_cpu)
+    return tables, pad
 
 
 # --------------------------------------------------------------------------

@@ -26,22 +26,18 @@ R_BOARD_AMB = 1.5                                            # K/W
 C_BOARD = 20.0                                               # J/K
 
 
-def cluster_nodes(db) -> np.ndarray:
-    """Map each PE of a ``ResourceDB`` to its thermal node index.
-
-    big CPUs -> NODE_BIG, LITTLE CPUs -> NODE_LITTLE, accelerators share the
-    NODE_ACCEL fabric node.
-    """
+def pe_node(pe_type: str) -> int:
+    """The thermal node a PE of ``pe_type`` heats: big CPUs NODE_BIG,
+    LITTLE CPUs NODE_LITTLE, accelerators the shared NODE_ACCEL fabric."""
     from .resources import CPU_BIG, CPU_LITTLE
-    out = np.empty(db.num_pes, dtype=np.int64)
-    for j, pe in enumerate(db.pes):
-        if pe.pe_type == CPU_BIG:
-            out[j] = NODE_BIG
-        elif pe.pe_type == CPU_LITTLE:
-            out[j] = NODE_LITTLE
-        else:
-            out[j] = NODE_ACCEL
-    return out
+    return {CPU_BIG: NODE_BIG, CPU_LITTLE: NODE_LITTLE}.get(pe_type,
+                                                            NODE_ACCEL)
+
+
+def cluster_nodes(db) -> np.ndarray:
+    """Map each PE of a ``ResourceDB`` to its thermal node index
+    (:func:`pe_node`)."""
+    return np.array([pe_node(pe.pe_type) for pe in db.pes], dtype=np.int64)
 
 
 def node_power_split(db, energy_per_pe_j: np.ndarray,

@@ -1,14 +1,13 @@
-"""Stack per-design simulation tables into one (D, …) tensor program.
+"""Build D designs' simulation tables as one (D, …) tensor program.
 
-Designs differ in PE count, so every per-design :class:`SimTables` is built
-padded to the fleet-wide maximum (``build_tables_host(pad_pes=…)``) and the
-padded tables are stacked leaf-wise into a single pytree whose data fields
-carry a leading design axis.  :func:`build_design_batch` builds and stacks
-on the host and places the stacked batch on the device once, one transfer
-per leaf whatever the number of designs.  Padding is inert by construction
-(BIG latency, zero power — see DESIGN.md §5), so the batched kernel needs
-**no masking logic**: ``jax.vmap`` over the design axis × the trace axis
-runs designs × seeds × injection rates in one ``jit``.
+Designs differ in PE count, so every design is padded to the fleet-wide
+maximum.  :func:`build_design_batch` fills the batch on the host in one
+pass (``build_tables_batch_host``: one row per distinct PE type and
+frequency, gathered over the designs) and places it on the device once,
+one transfer per leaf whatever the number of designs.  Padding is inert by
+construction (BIG latency, zero power — see DESIGN.md §5), so the batched
+kernel needs **no masking logic**: ``jax.vmap`` over the design axis × the
+trace axis runs designs × seeds × injection rates in one ``jit``.
 """
 from __future__ import annotations
 
@@ -23,7 +22,8 @@ import numpy as np
 from ..core.applications import Application
 from ..core.dvfs import Governor
 from ..core.jobgen import JobTrace
-from ..core.simkernel_jax import SimTables, _simulate, build_tables_host
+from ..core.simkernel_jax import (SimTables, _simulate,
+                                  build_tables_batch_host)
 from ..core.thermal import NODE_ACCEL, cluster_nodes
 from ..obs import metrics as _metrics
 from .space import DesignPoint
@@ -31,6 +31,9 @@ from .space import DesignPoint
 # host->device transfers build_design_batch makes, one per leaf placed: a
 # batch's leaf count, whatever its number of designs
 _PLACEMENTS = _metrics.counter("dse.tables.placements")
+# distinct (PE type, frequency) rows the latest batch filled, summed over
+# profile groups: what its designs share, whatever their number
+_ROWS_FILLED = _metrics.counter("dse.tables.rows_filled")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,8 +62,7 @@ def stack_tables(tables: Sequence[SimTables], host: bool = False) -> SimTables:
 
     ``host=True`` stacks into numpy leaves instead of device arrays — the
     form the chunked/sharded executor (``scenario.shardexec``) streams from,
-    so a grid larger than device memory is never device-resident at once,
-    and the form :func:`build_design_batch` places in one transfer.
+    so a grid larger than device memory is never device-resident at once.
     """
     shapes = {(t.t_max, t.num_pes) for t in tables}
     if len(shapes) != 1:
@@ -97,43 +99,37 @@ def build_design_batch(points: Sequence[DesignPoint],
     per-cluster frequency caps — so Pareto search ranks dynamic policies
     under the design's static envelope, not just static caps.
 
-    Every design's tables are built and stacked on the host, then the
-    stacked tables and node map are placed with one ``jax.device_put``
-    (counted by ``dse.tables.placements``, one per leaf).  Host spans
-    (DESIGN.md §11): ``repro.tables.build`` covers ``to_db`` and the
-    per-design ``build_tables_host`` loop, ``repro.tables.stack`` the host
-    stack, the node map and the placement.
+    The whole batch is filled on the host by ``build_tables_batch_host``
+    (one row per distinct PE type and frequency, counted by
+    ``dse.tables.rows_filled``, gathered over the designs), then the tables
+    and node map are placed with one ``jax.device_put`` (counted by
+    ``dse.tables.placements``, one per leaf).  Host spans (DESIGN.md §11):
+    ``repro.tables.build`` covers ``to_db`` and the fill,
+    ``repro.tables.stack`` the placement.
     """
     if not points:
         raise ValueError("empty design list")
+    if governor is None:
+        governors, caps = [p.governor() for p in points], None
+    elif governor.policy().dynamic:
+        governors = [governor] * len(points)
+        caps = [p.freq_caps() for p in points]
+    else:
+        # a uniform static governor would silently override the per-design
+        # frequency caps the sweep contract assumes
+        raise ValueError(
+            "build_design_batch bakes per-design frequency caps; pass a "
+            "dynamic (ondemand-family) governor to add OPP ladders, or None "
+            "for the static design-cap tables")
     with _metrics.span("repro.tables.build"):
-        dbs = [p.to_db() for p in points]
-        P = max(db.num_pes for db in dbs)
-        if pad_pes is not None:
-            if pad_pes < P:
-                raise ValueError(f"pad_pes={pad_pes} < widest design {P}")
-            P = pad_pes
-        if governor is not None:
-            if not governor.policy().dynamic:
-                # a uniform static governor would silently override the
-                # per-design frequency caps the sweep contract assumes
-                raise ValueError(
-                    "build_design_batch bakes per-design frequency caps; "
-                    "pass a dynamic (ondemand-family) governor to add OPP "
-                    "ladders, or None for the static design-cap tables")
-            per_design = [
-                build_tables_host(db, apps, governor=governor, pad_pes=P,
-                                  freq_caps=p.freq_caps())
-                for p, db in zip(points, dbs)]
-        else:
-            per_design = [build_tables_host(db, apps, governor=p.governor(),
-                                            pad_pes=P)
-                          for p, db in zip(points, dbs)]
+        tables, rows = build_tables_batch_host(
+            [p.to_db() for p in points], apps, governors, caps,
+            pad_pes=pad_pes)
+        _ROWS_FILLED.set(rows)
     with _metrics.span("repro.tables.stack"):
         # uncommitted, unsharded placement, as jnp.stack would give: the
         # batched programs find the jit cache entries they always had
-        tables, node_of_pe = jax.device_put(
-            (stack_tables(per_design, host=True), _node_map_host(dbs, P)))
+        tables, node_of_pe = jax.device_put((tables, tables.node_of_pe))
         _PLACEMENTS.inc(
             len(jax.tree_util.tree_leaves((tables, node_of_pe))))
         return DesignBatch(points=tuple(points), tables=tables,

@@ -263,6 +263,190 @@ def test_host_builder_matches_build_tables(case):
     _assert_same_leaves(host, build_tables(db, [app], **kw))
 
 
+# The per-design fill as it was before the batched one: every design's
+# tables one scalar at a time, stacked.  The batched fill must equal it.
+
+def _loop_fill_host(db, apps, governor, pad_pes, freq_caps=None):
+    from repro.core.dvfs import MAX_OPP_LEVELS, padded_ladder
+    from repro.core.power import active_power, idle_power
+    from repro.core.resources import NOMINAL_FREQ
+    from repro.core.simkernel_jax import MIN_DOMAINS, SimTables
+    dynamic = governor.policy().dynamic
+    if freq_caps is None:
+        freq_caps = getattr(governor, "freq_caps", None)
+    A, P = len(apps), pad_pes or db.num_pes
+    T = max(a.num_tasks for a in apps)
+    freq = {}
+    for pe in db.pes:
+        if pe.is_cpu and pe.cluster not in freq:
+            freq[pe.cluster] = governor.initial_freq(pe.pe_type)
+    k_in = max(1, max(a.max_in_degree for a in apps))
+    k_out = max(1, max(a.max_out_degree for a in apps))
+    exec_us = np.full((A, T, P), 1e30, dtype=np.float32)
+    pred_idx = np.full((A, T, k_in), -1, dtype=np.int32)
+    pred_bytes = np.zeros((A, T, k_in), dtype=np.float32)
+    succ_idx = np.full((A, T, k_out), -1, dtype=np.int32)
+    valid = np.zeros((A, T), dtype=bool)
+    for ai, app in enumerate(apps):
+        lat = db.latency_matrix(app.task_names)
+        for t in range(app.num_tasks):
+            valid[ai, t] = True
+            for j, pe in enumerate(db.pes):
+                base = lat[t, j]
+                if np.isfinite(base):
+                    scale = (NOMINAL_FREQ[pe.pe_type] / freq[pe.cluster]
+                             if pe.is_cpu else 1.0)
+                    exec_us[ai, t, j] = np.float32(
+                        np.float32(base) * np.float32(scale))
+        n = app.num_tasks
+        pred_idx[ai, :n], pred_bytes[ai, :n] = app.pred_lists(k_in)
+        succ_idx[ai, :n] = app.succ_lists(k_out)
+    comm_mult = np.zeros((P, P), dtype=np.float32)
+    for s in range(db.num_pes):
+        for d in range(db.num_pes):
+            if s != d:
+                comm_mult[s, d] = (
+                    db.comm.cross_cluster_penalty
+                    if db.pes[s].cluster != db.pes[d].cluster else 1.0)
+    p_act = np.zeros(P, dtype=np.float32)
+    p_idle = np.zeros(P, dtype=np.float32)
+    for j, pe in enumerate(db.pes):
+        p_act[j] = active_power(pe, freq.get(pe.cluster, 0.0)
+                                if pe.is_cpu else 0.0)
+        p_idle[j] = idle_power(pe)
+    C = max(MIN_DOMAINS, max(pe.cluster for pe in db.pes) + 1)
+    nodes = thermal.cluster_nodes(db)
+    node_of_pe = np.full(P, thermal.NODE_ACCEL, dtype=np.int32)
+    node_of_pe[:db.num_pes] = nodes
+    pe_domain = np.full(P, C - 1, dtype=np.int32)
+    pe_is_cpu = np.zeros(P, dtype=np.float32)
+    for j, pe in enumerate(db.pes):
+        pe_domain[j] = pe.cluster
+        pe_is_cpu[j] = 1.0 if pe.is_cpu else 0.0
+    opp = {}
+    if dynamic:
+        K = MAX_OPP_LEVELS
+        exec_opp = np.full((A, T, P, K), 1e30, dtype=np.float32)
+        p_act_opp = np.zeros((P, K), dtype=np.float32)
+        opp_freq = np.zeros((C, K), dtype=np.float32)
+        num_opp = np.ones(C, dtype=np.int32)
+        domain_node = np.full(C, thermal.NODE_ACCEL, dtype=np.int32)
+        domain_cpu = np.zeros(C, dtype=np.float32)
+        ladders = {pe.pe_type: padded_ladder(pe.pe_type, freq_caps)
+                   for pe in db.pes if pe.is_cpu}
+        for j, pe in enumerate(db.pes):
+            if pe.is_cpu:
+                _, row, n = ladders[pe.pe_type]
+                num_opp[pe.cluster] = n
+                domain_node[pe.cluster] = nodes[j]
+                domain_cpu[pe.cluster] += 1.0
+                for k in range(K):
+                    opp_freq[pe.cluster, k] = row[k]
+                    p_act_opp[j, k] = active_power(pe, row[k])
+            else:
+                p_act_opp[j, :] = active_power(pe, 0.0)
+        for ai, app in enumerate(apps):
+            lat = db.latency_matrix(app.task_names)
+            for t in range(app.num_tasks):
+                for j, pe in enumerate(db.pes):
+                    base = lat[t, j]
+                    if not np.isfinite(base):
+                        continue
+                    if pe.is_cpu:
+                        row = ladders[pe.pe_type][1]
+                        for k in range(K):
+                            scale = np.float32(
+                                NOMINAL_FREQ[pe.pe_type] / row[k])
+                            exec_opp[ai, t, j, k] = np.float32(
+                                np.float32(base) * scale)
+                    else:
+                        exec_opp[ai, t, j, :] = np.float32(base)
+        opp = dict(exec_opp=exec_opp, power_active_opp=p_act_opp,
+                   opp_freq=opp_freq, num_opp=num_opp,
+                   domain_node=domain_node, domain_cpu=domain_cpu)
+    return SimTables(
+        exec_us=exec_us, pred_idx=pred_idx, pred_bytes=pred_bytes,
+        succ_idx=succ_idx, valid=valid, comm_mult=comm_mult,
+        comm_startup=np.asarray(db.comm.startup_us, np.float32),
+        comm_inv_bw=np.asarray(1.0 / db.comm.bw_bytes_per_us, np.float32),
+        power_active=p_act, power_idle=p_idle,
+        table_pe=np.full((A, T), -1, dtype=np.int32),
+        node_of_pe=node_of_pe, pe_domain=pe_domain, pe_is_cpu=pe_is_cpu,
+        t_max=T, num_pes=P, depth=max(a.depth for a in apps), **opp)
+
+
+def _loop_fill_batch(points, apps, pad_pes, governor=None):
+    dbs = [p.to_db() for p in points]
+    return stack_tables([
+        _loop_fill_host(db, apps, governor or p.governor(), pad_pes,
+                        p.freq_caps() if governor else None)
+        for p, db in zip(points, dbs)], host=True)
+
+
+# every sub-SoC of the Table-2 SoC with a CPU: 360 designs
+_TABLE2_SPACE = DesignSpace(num_big=(0, 1, 2, 3, 4),
+                            num_little=(0, 1, 2, 3, 4), num_scr=(0, 1, 2),
+                            num_fft=(0, 1, 2, 3, 4), num_vit=(0,),
+                            big_freq_ghz=(2.0,), little_freq_ghz=(1.4,),
+                            cross_cluster_penalty=(2.0,))
+
+
+@pytest.mark.parametrize("case", ["table2_grid", "lhs_ondemand",
+                                  "lhs_throttle", "mixed_freq"])
+def test_batched_fill_matches_per_design_fill(case):
+    """The batched host fill is the per-design fill stacked, leaf for
+    leaf and bit for bit, metas included."""
+    gov, apps, pad = None, [wifi_tx()], 14
+    if case == "table2_grid":
+        points = _TABLE2_SPACE.grid()
+        assert len(points) == 360
+    elif case == "mixed_freq":
+        # two big frequencies and two LITTLE ones: rows shared per pair
+        points = DesignSpace().sample_lhs(24, seed=5)
+        assert len({p.big_freq_ghz for p in points}) == 2
+        apps, pad = _apps(), 20
+    else:
+        points = DesignSpace().sample_lhs(16, seed=3)
+        gov, apps, pad = get_governor(case.split("_")[1]), _apps(), 20
+    got = build_design_batch(points, apps, pad_pes=pad, governor=gov)
+    want = _loop_fill_batch(points, apps, pad, governor=gov)
+    assert (got.tables.t_max, got.tables.num_pes, got.tables.depth) == \
+        (want.t_max, want.num_pes, want.depth)
+    _assert_same_leaves(got.tables, want)
+    np.testing.assert_array_equal(np.asarray(got.node_of_pe),
+                                  want.node_of_pe)
+
+
+@pytest.mark.parametrize("pad_pes", [None, 17])
+@pytest.mark.parametrize("governor", ["performance", "throttle"])
+def test_one_design_fill_matches_per_design_fill(governor, pad_pes):
+    """build_tables_host is the batched fill's one-design case: its
+    numpy leaves (0-d scalars included) equal the per-design fill's."""
+    db = DesignPoint(2, 3, 1, 2, 1, little_freq_ghz=1.0).to_db()
+    gov = get_governor(governor)
+    got = build_tables_host(db, _apps(), governor=gov, pad_pes=pad_pes)
+    assert all(isinstance(x, np.ndarray)
+               for x in jax.tree_util.tree_leaves(got))
+    _assert_same_leaves(got, _loop_fill_host(db, _apps(), gov, pad_pes))
+    assert got.num_pes == (pad_pes or db.num_pes)
+
+
+def test_rows_filled_counts_distinct_pe_rows():
+    """One row per distinct (PE type, frequency) among the real slots,
+    the same for 2 designs as for 64 drawn from the same space."""
+    rows = _metrics.counter("dse.tables.rows_filled")
+    sample = _TABLE2_SPACE.sample_lhs(64, seed=11)
+    pairs = [DesignPoint(4, 4, 2, 4, 0), DesignPoint(1, 0, 1, 1, 0)]
+    counts = []
+    for points in (pairs, sample):
+        build_design_batch(points, [wifi_tx()], pad_pes=14)
+        keys = {(pe.pe_type, p.freq_caps().get(pe.pe_type))
+                for p in points for pe in p.to_db().pes}
+        assert rows.value == len(keys)
+        counts.append(rows.value)
+    assert counts == [4, 4]
+
+
 def test_cached_host_tables_match_device_tables():
     """The chunked sweep's host tables are the device tables read back."""
     from repro.scenario import Scenario
