@@ -30,7 +30,6 @@ from .schedulers import (ETFScheduler, METScheduler, SchedContext, Scheduler,
                          TableScheduler, available_schedulers, get_scheduler,
                          register_scheduler, solve_optimal_table)
 from .simkernel_jax import SimTables, build_tables
-from .simkernel_jax import simulate_batch as _simulate_batch_impl
 from .simkernel_jax import simulate_jax as _simulate_jax_impl
 from .simkernel_ref import SimResult, TaskRecord
 from .simkernel_ref import simulate as _simulate_impl
@@ -43,9 +42,6 @@ simulate = _deprecated_entry_point(
 simulate_jax = _deprecated_entry_point(
     _simulate_jax_impl,
     "repro.scenario.run(Scenario(...), backend='jax')")
-simulate_batch = _deprecated_entry_point(
-    _simulate_batch_impl,
-    "repro.scenario.sweep(Scenario(...), axes={'trace': ...})")
 
 
 __all__ = [n for n in dir() if not n.startswith("_")]

@@ -818,14 +818,6 @@ def simulate_jax_dtpm(tables: SimTables, policy: str, arrival: np.ndarray,
                               faults, scan_steps=steps)
 
 
-def simulate_batch(tables: SimTables, policy: str, arrival: np.ndarray,
-                   app_idx: np.ndarray):
-    """Batched simulation: ``arrival``/(B, J), ``app_idx``/(B, J) — one design
-    point per row (seed × rate × mix).  Runs as ONE vmapped tensor program."""
-    fn = jax.vmap(lambda a, i: _simulate(tables, policy, int(arrival.shape[1]), a, i))
-    return fn(jnp.asarray(arrival, jnp.float32), jnp.asarray(app_idx, jnp.int32))
-
-
 # --------------------------------------------------------------------------
 # Telemetry scans — per-window (W, C) timelines from a realised schedule
 # --------------------------------------------------------------------------

@@ -1,4 +1,4 @@
-"""Build D designs' simulation tables as one (D, …) tensor program.
+"""Build D designs' simulation tables as one (D, …) stack of tensors.
 
 Designs differ in PE count, so every design is padded to the fleet-wide
 maximum.  :func:`build_design_batch` fills the batch on the host in one
@@ -6,14 +6,14 @@ pass (``build_tables_batch_host``: one row per distinct PE type and
 frequency, gathered over the designs) and places it on the device once,
 one transfer per leaf whatever the number of designs.  Padding is inert by
 construction (BIG latency, zero power — see DESIGN.md §5), so the batched
-kernel needs **no masking logic**: ``jax.vmap`` over the design axis × the
-trace axis runs designs × seeds × injection rates in one ``jit``.
+kernel needs **no masking logic**: the sweep's one grid program
+(``scenario.shardexec._sweep_grid``) vmaps the design axis × the trace axis
+and runs designs × seeds × injection rates in one ``jit``.
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -22,8 +22,7 @@ import numpy as np
 from ..core.applications import Application
 from ..core.dvfs import Governor
 from ..core.jobgen import JobTrace
-from ..core.simkernel_jax import (SimTables, _simulate,
-                                  build_tables_batch_host)
+from ..core.simkernel_jax import SimTables, build_tables_batch_host
 from ..core.thermal import NODE_ACCEL, cluster_nodes
 from ..obs import metrics as _metrics
 from .space import DesignPoint
@@ -144,52 +143,3 @@ def stack_traces(traces: Sequence[JobTrace]) -> Tuple[jnp.ndarray, jnp.ndarray]:
     arr = jnp.asarray(np.stack([t.arrival_us for t in traces]), jnp.float32)
     idx = jnp.asarray(np.stack([t.app_index for t in traces]), jnp.int32)
     return arr, idx
-
-
-@functools.partial(jax.jit, static_argnames=("policy", "num_jobs"))
-def _simulate_grid(tables: SimTables, policy: str, num_jobs: int,
-                   arrival: jnp.ndarray, app_idx: jnp.ndarray):
-    """(D designs) × (S traces) simulations as one tensor program."""
-    per_trace = jax.vmap(
-        lambda tb, a, i: _simulate(tb, policy, num_jobs, a, i),
-        in_axes=(None, 0, 0))                      # map traces, share design
-    per_design = jax.vmap(per_trace, in_axes=(0, None, None))
-    return per_design(tables, arrival, app_idx)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("policy", "num_jobs", "scan_steps"))
-def _simulate_grid_faults(tables: SimTables, policy: str, num_jobs: int,
-                          arrival: jnp.ndarray, app_idx: jnp.ndarray,
-                          fplans: jnp.ndarray, scan_steps: int):
-    """(F fault plans) × (D designs) × (S traces) fail-stop simulations.
-
-    ``fplans``: (F, P) f32 per-PE fail times (``+inf`` = never fails, see
-    ``repro.scenario.faults``); ``scan_steps`` is the static epoch budget
-    covering the widest lane's rollbacks (DESIGN.md §14).  The fault axis is
-    outermost so the design axis stays streamable (``scenario.shardexec``).
-    """
-    per_trace = jax.vmap(
-        lambda tb, a, i, fp: _simulate(tb, policy, num_jobs, a, i, fp,
-                                       scan_steps=scan_steps),
-        in_axes=(None, 0, 0, None))
-    per_design = jax.vmap(per_trace, in_axes=(0, None, None, None))
-    per_fault = jax.vmap(per_design, in_axes=(None, None, None, 0))
-    return per_fault(tables, arrival, app_idx, fplans)
-
-
-def simulate_design_batch(batch: DesignBatch, policy: str,
-                          arrival: jnp.ndarray, app_idx: jnp.ndarray) -> Dict:
-    """Run all designs × traces in one jitted call.
-
-    ``arrival``/``app_idx``: (S, J) as from :func:`stack_traces`.  Every entry
-    of the returned dict gains leading (D, S) axes over ``simulate_jax``'s
-    output — e.g. ``avg_job_latency_us`` is (D, S), ``busy_per_pe_us`` is
-    (D, S, P).
-    """
-    arrival = jnp.asarray(arrival, jnp.float32)
-    app_idx = jnp.asarray(app_idx, jnp.int32)
-    if arrival.ndim != 2:
-        raise ValueError("arrival must be (num_traces, num_jobs)")
-    return _simulate_grid(batch.tables, policy, int(arrival.shape[1]),
-                          arrival, app_idx)

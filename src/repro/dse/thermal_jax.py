@@ -135,8 +135,9 @@ def peak_temperature_grid(sim_out: Dict, node_of_pe: jnp.ndarray,
                           bins: int = 32, repeats: int = 3) -> jnp.ndarray:
     """(D, S) peak temperatures from batched simulation output.
 
-    ``sim_out`` is the dict from ``simulate_design_batch`` (leading (D, S)
-    axes); ``node_of_pe``/``power_active``/``power_idle`` are (D, P).
+    ``sim_out`` is the static kernel's output dict with leading (D, S)
+    axes, as the sweep's grid program (``scenario.shardexec._sweep_grid``)
+    produces it; ``node_of_pe``/``power_active``/``power_idle`` are (D, P).
     """
     def one(start, finish, onpe, scheduled, makespan, nodes, p_act, p_idle):
         trace, dt = binned_power_trace(start, finish, onpe, scheduled,

@@ -1,11 +1,17 @@
-"""The sweep's grid programs and their sharded + chunked lane execution
-(DESIGN.md §13).
+"""The sweep's one grid program and its one lane executor (DESIGN.md §13).
 
-The compiled grid programs are embarrassingly parallel along their
-leading *lane* axis — the stacked design axis of ``SimTables`` (static
-sweeps) or, when the policy grid is the wide one, the stacked
-:class:`~repro.core.dvfs.GovernorPolicy` axis (dynamic DTPM sweeps).  This
-module scales that axis two ways, composably:
+``_sweep_grid`` is the only compiled grid program.  Its lanes are the
+cross-product of up to four axes, nested from one ordered list, outermost
+first: fault plans (F), designs (the stacked ``SimTables`` axis, D),
+dynamic :class:`~repro.core.dvfs.GovernorPolicy` stacks (G) and traces
+(S).  An absent axis (``fplans=None``, ``gov=None``) is simply not mapped.
+``gov=None`` runs the static kernel plus the binned thermal scan; a policy
+stack runs the closed-loop DTPM kernel, whose inline RC loop gives the peak
+temperature.
+
+``run_grid`` is the only executor.  With no chunk and no lane mesh it
+launches the program once on the trees as they are.  Otherwise it scales
+the wider of the design and policy axes two ways, composably:
 
 * **lane sharding** — the per-chunk lane tensors are placed with a
   ``NamedSharding`` over the 1-D lane mesh (``repro.sharding.lane_mesh``,
@@ -15,16 +21,17 @@ module scales that axis two ways, composably:
   results are bit-for-bit equal to the single-device sweep.
 * **chunked streaming** — lanes stream through ONE compiled program in
   fixed-shape chunks (``sweep(..., chunk=N)``): the stacked lane tensors
-  stay host-resident (numpy leaves) and only one chunk is device-resident
-  at a time, so peak device memory is O(chunk), not O(grid).
+  stay host-resident (numpy leaves, see :func:`stages_on_host`) and only one
+  chunk is device-resident at a time, so peak device memory is O(chunk),
+  not O(grid).
 
-Both paths pad the lane count up to the chunk/device quantum by repeating
-lane 0.  Unlike ``dse.batch``'s *in-kernel* inert padding (BIG latency,
-zero power), pad lanes here are ordinary simulations whose outputs are
-sliced off before assembly — inert by construction because lanes never
-interact.  Chunk shapes are pinned (every chunk padded to the same width,
-``pad_pes``-style), so chunking and uneven lane counts never add compiles:
-one trace per (policy shape, chunk width).
+Both pad the lane count up to the chunk/device quantum by repeating lane 0.
+Unlike ``dse.batch``'s *in-kernel* inert padding (BIG latency, zero power),
+pad lanes here are ordinary simulations whose outputs are sliced off before
+assembly — inert by construction because lanes never interact.  Chunk
+shapes are pinned (every chunk padded to the same width, ``pad_pes``-style),
+so chunking and uneven lane counts never add compiles: one trace per
+(policy shape, chunk width).
 
 Observability: ``scenario.shard.devices`` (lane-mesh width of the most
 recent launch), ``scenario.shard.pad_lanes`` (inert lanes added) and
@@ -36,15 +43,14 @@ launch a ``repro.launch`` span, and the concatenation a ``repro.wait`` then
 from __future__ import annotations
 
 import functools
-from typing import Dict, Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..dse.batch import _simulate_grid, _simulate_grid_faults
+from ..core.simkernel_jax import _simulate, _simulate_dtpm
 from ..dse.thermal_jax import peak_temperature_grid
-from ..core.simkernel_jax import _simulate_dtpm
 from ..obs import metrics as _metrics
 from ..sharding import lane_count, lane_mesh, lane_sharding
 
@@ -52,7 +58,7 @@ from ..sharding import lane_count, lane_mesh, lane_sharding
 shard_devices = _metrics.counter("scenario.shard.devices")
 # cumulative inert pad lanes added for chunk/device-count divisibility
 shard_pad_lanes = _metrics.counter("scenario.shard.pad_lanes")
-# cumulative fixed-shape chunks streamed through the grid programs
+# cumulative fixed-shape chunks streamed through the grid program
 sweep_chunks = _metrics.counter("scenario.sweep.chunks")
 # the sweep's one-program-per-policy-shape trace counter (re-exported as
 # ``sweep.compile_count``; looked up here, not in the jitted bodies, so the
@@ -102,77 +108,67 @@ def _slice_lanes(tree, lo: int, hi: int, axis: int = 0):
 
 
 # --------------------------------------------------------------------------
-# The grid programs — ONE trace per (policy shape, lane width).  The plain
-# sweep launches them on whole device-resident grids, the streamer below on
-# host-staged (and optionally sharded) chunks.  Nothing is donated: the
-# outputs share no shape with the lane inputs, so no buffer could alias.
+# The grid program — ONE trace per (policy shape, lane width).  run_grid
+# launches it on whole device-resident grids or on host-staged (and
+# optionally sharded) chunks.  Nothing is donated: the outputs share no
+# shape with the lane inputs, so no buffer could alias.
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("policy", "num_jobs", "bins",
-                                             "repeats"))
-def _sweep_grid(tables, node_of_pe, arrival, app_idx, policy, num_jobs,
-                bins, repeats):
-    """Schedule simulation + thermal scan for (D, S) lanes, ONE program."""
-    _compile_count.inc()  # lint: waive JX003 -- deliberate: counts compiles, python body runs per trace
-    out = _simulate_grid(tables, policy, num_jobs, arrival, app_idx)
-    temps = peak_temperature_grid(out, node_of_pe, tables.power_active,
-                                  tables.power_idle, bins=bins,
-                                  repeats=repeats)
-    return out, temps
-
-
-@functools.partial(jax.jit, static_argnames=("policy", "num_jobs"))
-def _sweep_grid_dtpm(tables, gov, arrival, app_idx, policy, num_jobs):
-    """Closed-loop DTPM lanes: (D designs, G policies, S traces) in ONE
-    program.  Peak temperature comes from the kernel's inline RC loop (the
-    one the throttle feedback integrates), so no post-hoc thermal scan."""
-    _compile_count.inc()  # lint: waive JX003 -- deliberate: counts compiles, python body runs per trace
-    per_trace = jax.vmap(
-        lambda tb, g, a, i: _simulate_dtpm(tb, policy, num_jobs, a, i, g),
-        in_axes=(None, None, 0, 0))
-    per_policy = jax.vmap(per_trace, in_axes=(None, 0, None, None))
-    per_design = jax.vmap(per_policy, in_axes=(0, None, None, None))
-    return per_design(tables, gov, arrival, app_idx)
+# the lane axes, outermost first, each with the positions in
+# (tables, gov, fplans, arrival, app_idx) of the arguments that carry it;
+# the fault axis is outermost so the design axis stays streamable
+_LANE_AXES = ((2,), (0,), (1,), (3, 4))        # fault, design, policy, trace
 
 
 @functools.partial(jax.jit, static_argnames=("policy", "num_jobs", "bins",
                                              "repeats", "scan_steps"))
-def _sweep_grid_faults(tables, node_of_pe, fplans, arrival, app_idx, policy,
-                       num_jobs, bins, repeats, scan_steps):
-    """Fail-stop lanes (F fault plans, D designs, S traces), ONE program.
+def _sweep_grid(tables, node_of_pe, gov, fplans, arrival, app_idx, *,
+                policy, num_jobs, bins, repeats, scan_steps):
+    """(F faults, D designs, G policies, S traces) lanes in ONE program.
 
-    The fault axis is outermost so the design axis stays streamable by the
-    chunked/sharded executor; the thermal scan vmaps per fault lane over the
-    same (D, S) grid program the fault-free path uses (DESIGN.md §14)."""
+    ``gov=None`` drops the policy axis and runs the static kernel, whose
+    peak temperatures come from the binned thermal scan over each lane's
+    schedule; a stacked policy runs the DTPM kernel and takes its inline
+    RC peak.  ``fplans=None`` drops the fault axis (``scan_steps`` is then
+    unused).  Returns ``(out, temps)``, both with the leading lane axes
+    present in that order."""
     _compile_count.inc()  # lint: waive JX003 -- deliberate: counts compiles, python body runs per trace
-    out = _simulate_grid_faults(tables, policy, num_jobs, arrival, app_idx,
-                                fplans, scan_steps)
-    temps = jax.vmap(lambda o: peak_temperature_grid(
-        o, node_of_pe, tables.power_active, tables.power_idle, bins=bins,
-        repeats=repeats))(out)
-    return out, temps
 
+    def lane(tb, g, fp, a, i):
+        kernel = _simulate if g is None else _simulate_dtpm
+        head = (tb, policy, num_jobs, a, i) + (() if g is None else (g,))
+        if fp is None:
+            return kernel(*head)
+        return kernel(*head, fp, scan_steps=scan_steps)
 
-@functools.partial(jax.jit,
-                   static_argnames=("policy", "num_jobs", "scan_steps"))
-def _sweep_grid_dtpm_faults(tables, gov, fplans, arrival, app_idx, policy,
-                            num_jobs, scan_steps):
-    """Fail-stop DTPM lanes: (F fault plans, D designs, G policies,
-    S traces) through the closed-loop kernel in ONE program."""
-    _compile_count.inc()  # lint: waive JX003 -- deliberate: counts compiles, python body runs per trace
-    per_trace = jax.vmap(
-        lambda tb, g, a, i, fp: _simulate_dtpm(tb, policy, num_jobs, a, i, g,
-                                               fp, scan_steps=scan_steps),
-        in_axes=(None, None, 0, 0, None))
-    per_policy = jax.vmap(per_trace, in_axes=(None, 0, None, None, None))
-    per_design = jax.vmap(per_policy, in_axes=(0, None, None, None, None))
-    per_fault = jax.vmap(per_design, in_axes=(None, None, None, None, 0))
-    return per_fault(tables, gov, arrival, app_idx, fplans)
+    args = (tables, gov, fplans, arrival, app_idx)
+    grid = lane
+    for carriers in reversed([c for c in _LANE_AXES
+                              if args[c[0]] is not None]):
+        grid = jax.vmap(grid, in_axes=tuple(0 if k in carriers else None
+                                            for k in range(len(args))))
+    out = grid(*args)
+    if gov is not None:
+        return out, out.pop("peak_temp_c")
+
+    def thermal(o):
+        return peak_temperature_grid(o, node_of_pe, tables.power_active,
+                                     tables.power_idle, bins=bins,
+                                     repeats=repeats)
+
+    return out, thermal(out) if fplans is None else jax.vmap(thermal)(out)
 
 
 # --------------------------------------------------------------------------
-# The streamer
+# The executor
 # --------------------------------------------------------------------------
+
+def stages_on_host(chunk: Optional[int], mesh) -> bool:
+    """Whether a sweep stacks its lane tensors on the host: the streamer
+    slices chunks from numpy leaves, while the direct launch takes the
+    device-resident trees as they are."""
+    return chunk is not None or mesh is not None
+
 
 def _stream(lane_tree, lanes: int, chunk: Optional[int], mesh,
             launch) -> list:
@@ -198,102 +194,53 @@ def _stream(lane_tree, lanes: int, chunk: Optional[int], mesh,
     return outs
 
 
-def _concat_out(chunks: list, lanes: int, axis: int = 0) -> Dict:
-    """Concatenate per-chunk output dicts on the lane axis and drop the pad
+def _concat_out(chunks: list, lanes: int, axis: int):
+    """Concatenate per-chunk output trees on the lane axis and drop the pad
     lanes (host-side: chunk outputs leave the device as they arrive)."""
     with _metrics.span("repro.wait"):
         jax.block_until_ready(chunks)
     with _metrics.span("repro.assemble"):
-        keys = chunks[0].keys()
-        out = {k: np.concatenate([np.asarray(c[k]) for c in chunks],
-                                 axis=axis)
-               for k in keys}
         sl = (slice(None),) * axis + (slice(0, lanes),)
-        return {k: v[sl] for k, v in out.items()}
+        return jax.tree_util.tree_map(
+            lambda *xs: np.concatenate([np.asarray(x) for x in xs],
+                                       axis=axis)[sl], *chunks)
 
 
-def run_static_grid(tables, node_of_pe, arrival, app_idx, *, policy: str,
-                    num_jobs: int, bins: int, repeats: int,
-                    chunk: Optional[int] = None, mesh=None,
-                    fplans=None,
-                    scan_steps: Optional[int] = None
-                    ) -> Tuple[Dict, np.ndarray]:
-    """The sharded/chunked twin of ``sweep._sweep_grid``: (D, S) lanes with
-    the design axis streamed/sharded; returns host-resident outputs with
-    exactly D lanes (bit-for-bit equal to the unsharded grid).
+def run_grid(tables, node_of_pe, gov, fplans, arrival, app_idx, *,
+             policy: str, num_jobs: int, bins: int, repeats: int,
+             scan_steps: Optional[int] = None, chunk: Optional[int] = None,
+             mesh=None):
+    """Run ``_sweep_grid`` over its lanes; returns ``(out, temps)``.
 
-    ``fplans``/``scan_steps`` switch to the fail-stop grid: outputs gain a
-    leading (F,) fault-lane axis, and the design axis (still the streamed
-    one) moves to position 1 — the fault axis is outermost precisely so
-    streaming stays a design-axis slice (DESIGN.md §14)."""
+    With no ``chunk`` and no ``mesh`` the program is launched once on the
+    trees as given.  Otherwise the wider of the design (D) and policy (G)
+    axes streams through :func:`_stream` from host numpy (the other is
+    placed whole), and the host-resident outputs hold exactly the real
+    lanes — bit-for-bit equal to the direct launch (DESIGN.md §10, §13)."""
+    kw = dict(policy=policy, num_jobs=num_jobs, bins=bins, repeats=repeats,
+              scan_steps=scan_steps)
+    if not stages_on_host(chunk, mesh):
+        with _metrics.span("repro.launch"):
+            return _sweep_grid(tables, node_of_pe, gov, fplans, arrival,
+                               app_idx, **kw)
     with _metrics.span("repro.stage"):
-        lanes = int(np.asarray(tables.exec_us).shape[0])
-        lane_tree = (host_tree(tables), host_tree(node_of_pe))
-        faulted = fplans is not None
-        fdev = jnp.asarray(fplans, jnp.float32) if faulted else None
+        D = tables.exec_us.shape[0]
+        G = 0 if gov is None else gov.up_threshold.shape[0]
+        designs = (host_tree(tables), host_tree(node_of_pe))
+        fdev = None if fplans is None else jnp.asarray(fplans, jnp.float32)
+        by_design = D >= G
+        lane_tree, lanes = (designs, D) if by_design else (host_tree(gov), G)
+        whole = jax.tree_util.tree_map(
+            jnp.asarray, host_tree(gov) if by_design else designs)
 
     def launch(piece):
-        tb, nodes = piece
-        if faulted:
-            out, temps = _sweep_grid_faults(
-                tb, nodes, fdev, arrival, app_idx, policy=policy,
-                num_jobs=num_jobs, bins=bins, repeats=repeats,
-                scan_steps=scan_steps)
-        else:
-            out, temps = _sweep_grid(tb, nodes, arrival, app_idx,
-                                     policy=policy, num_jobs=num_jobs,
-                                     bins=bins, repeats=repeats)
-        out = dict(out)
-        out["_peak_temp_scan_c"] = temps
-        return out
+        (tb, nodes), g = (piece, whole) if by_design else (whole, piece)
+        return _sweep_grid(tb, nodes, g, fdev, arrival, app_idx, **kw)
 
-    out = _concat_out(_stream(lane_tree, lanes, chunk, mesh, launch), lanes,
-                      axis=1 if faulted else 0)
-    return out, out.pop("_peak_temp_scan_c")
-
-
-def run_dtpm_grid(tables, gov, arrival, app_idx, *, policy: str,
-                  num_jobs: int, chunk: Optional[int] = None, mesh=None,
-                  fplans=None, scan_steps: Optional[int] = None) -> Dict:
-    """The sharded/chunked twin of ``sweep._sweep_grid_dtpm``: (D, G, S)
-    lanes, streaming/sharding whichever of the design (D) and policy (G)
-    axes is wider — the GovernorPolicy leaves are as much a lane stack as
-    the SimTables leaves (DESIGN.md §10).  ``fplans``/``scan_steps`` switch
-    to the fail-stop grid: outputs gain a leading (F,) fault-lane axis and
-    the streamed axis shifts one position right (DESIGN.md §14)."""
-    with _metrics.span("repro.stage"):
-        D = int(np.asarray(tables.exec_us).shape[0])
-        G = int(np.asarray(gov.up_threshold).shape[0])
-        tables_h, gov_h = host_tree(tables), host_tree(gov)
-        faulted = fplans is not None
-        fdev = jnp.asarray(fplans, jnp.float32) if faulted else None
-    if D >= G:                               # stream designs, reuse policies
-        with _metrics.span("repro.stage"):
-            gov_dev = jax.tree_util.tree_map(jnp.asarray, gov_h)
-
-        def launch(tb):
-            if faulted:
-                return _sweep_grid_dtpm_faults(
-                    tb, gov_dev, fdev, arrival, app_idx, policy=policy,
-                    num_jobs=num_jobs, scan_steps=scan_steps)
-            return _sweep_grid_dtpm(tb, gov_dev, arrival, app_idx,
-                                    policy=policy, num_jobs=num_jobs)
-
-        return _concat_out(_stream(tables_h, D, chunk, mesh, launch), D,
-                           axis=1 if faulted else 0)
-    with _metrics.span("repro.stage"):
-        tables_dev = jax.tree_util.tree_map(jnp.asarray, tables_h)
-
-    def launch(g):
-        if faulted:
-            return _sweep_grid_dtpm_faults(
-                tables_dev, g, fdev, arrival, app_idx, policy=policy,
-                num_jobs=num_jobs, scan_steps=scan_steps)
-        return _sweep_grid_dtpm(tables_dev, g, arrival, app_idx,
-                                policy=policy, num_jobs=num_jobs)
-
-    return _concat_out(_stream(gov_h, G, chunk, mesh, launch), G,
-                       axis=2 if faulted else 1)
+    # the streamed axis sits after the fault axis, the policy axis after D
+    axis = (fplans is not None) + (not by_design)
+    return _concat_out(_stream(lane_tree, lanes, chunk, mesh, launch), lanes,
+                       axis=axis)
 
 
 def resolve_mesh(shard: Optional[bool], devices=None):

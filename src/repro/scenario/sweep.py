@@ -24,11 +24,14 @@ factorise into four kinds (see DESIGN.md §9–10):
 * **static** (``scheduler``): a compile-time branch of the kernel — swept in
   an outer python loop, one compiled program per value.
 
-For one scheduler the whole (designs × policies × traces) cross-product runs
-as ONE vmapped/jitted tensor program per *policy shape* (static / dynamic) —
-and every lane is bit-for-bit equal to a per-point ``run(..., backend="jax")``
-(padding is inert; a vmap lane equals a single call; the RC stepper's
-spectral e^{A·dt} keeps the thermal math batch-width independent).
+For one scheduler the whole (faults × designs × policies × traces)
+cross-product is one call of ``shardexec.run_grid``, which launches the one
+grid program ``shardexec._sweep_grid`` — one trace per *policy shape*
+(static / dynamic, faulted or not) and lane width — directly or in
+host-staged chunks.  Every lane is bit-for-bit equal to a per-point
+``run(..., backend="jax")`` (padding is inert; a vmap lane equals a single
+call; the RC stepper's spectral e^{A·dt} keeps the thermal math batch-width
+independent).
 ``backend="ref"`` sweeps the same cross-product through the event-heap
 oracle lane by lane.
 """
@@ -50,8 +53,6 @@ from ..obs import metrics as _metrics
 from ..obs import telemetry as _obs_tel
 from . import faults as _faults
 from . import shardexec
-from .shardexec import (_sweep_grid, _sweep_grid_dtpm,
-                        _sweep_grid_dtpm_faults, _sweep_grid_faults)
 from .config import Scenario, TraceSpec
 from .errors import BackendCapabilityError, LaneAxisError, ScenarioError
 from .result import SweepResult
@@ -226,7 +227,6 @@ def _sweep(scenario: Scenario, axes: Dict[str, Sequence], backend: str,
         raise ScenarioError(f"unknown backend {backend!r}; have "
                             f"('ref', 'jax')")
     mesh = shardexec.resolve_mesh(shard)
-    lane_exec = chunk is not None or mesh is not None
 
     # fault lanes: every value of a 'faults'/'failures' axis is one fault
     # set; with no such axis the base scenario's failures apply to all lanes
@@ -331,9 +331,9 @@ def _sweep(scenario: Scenario, axes: Dict[str, Sequence], backend: str,
     rebuild_per_combo = design_batch is None and any_table
     with _metrics.span("repro.stage"):
         if design_batch is None and not rebuild_per_combo:
-            tables, node_of_pe = _design_lanes(lane_base, design_axes,
-                                               design_combos, pad_pes,
-                                               host=lane_exec)
+            tables, node_of_pe = _design_lanes(
+                lane_base, design_axes, design_combos, pad_pes,
+                host=shardexec.stages_on_host(chunk, mesh))
 
         gov_stack = stack_policies(policies) if dynamic else None
 
@@ -361,67 +361,15 @@ def _sweep(scenario: Scenario, axes: Dict[str, Sequence], backend: str,
         s_scn = _apply_axes(lane_base, static_axes, sc)
         if rebuild_per_combo:
             with _metrics.span("repro.stage"):
-                tables, node_of_pe = _design_lanes(s_scn, design_axes,
-                                                   design_combos, pad_pes,
-                                                   host=lane_exec)
+                tables, node_of_pe = _design_lanes(
+                    s_scn, design_axes, design_combos, pad_pes,
+                    host=shardexec.stages_on_host(chunk, mesh))
         count_scan_steps(lanes, num_jobs, int(tables.t_max), scan_steps)
-        if dynamic:
-            if plans is not None:
-                if lane_exec:
-                    out = shardexec.run_dtpm_grid(
-                        tables, gov_stack, arrival, app_idx,
-                        policy=s_scn.scheduler, num_jobs=num_jobs,
-                        chunk=chunk, mesh=mesh, fplans=plans,
-                        scan_steps=scan_steps)
-                else:
-                    with _metrics.span("repro.launch"):
-                        out = _sweep_grid_dtpm_faults(
-                            tables, gov_stack, plans, arrival, app_idx,
-                            policy=s_scn.scheduler, num_jobs=num_jobs,
-                            scan_steps=scan_steps)
-            elif lane_exec:
-                out = shardexec.run_dtpm_grid(tables, gov_stack, arrival,
-                                              app_idx,
-                                              policy=s_scn.scheduler,
-                                              num_jobs=num_jobs,
-                                              chunk=chunk, mesh=mesh)
-            else:
-                with _metrics.span("repro.launch"):
-                    out = _sweep_grid_dtpm(tables, gov_stack, arrival,
-                                           app_idx, policy=s_scn.scheduler,
-                                           num_jobs=num_jobs)
-            temps = out["peak_temp_c"]
-        else:
-            if plans is not None:
-                if lane_exec:
-                    out, temps = shardexec.run_static_grid(
-                        tables, node_of_pe, arrival, app_idx,
-                        policy=s_scn.scheduler, num_jobs=num_jobs,
-                        bins=s_scn.thermal.bins,
-                        repeats=s_scn.thermal.repeats,
-                        chunk=chunk, mesh=mesh, fplans=plans,
-                        scan_steps=scan_steps)
-                else:
-                    with _metrics.span("repro.launch"):
-                        out, temps = _sweep_grid_faults(
-                            tables, node_of_pe, plans, arrival, app_idx,
-                            policy=s_scn.scheduler, num_jobs=num_jobs,
-                            bins=s_scn.thermal.bins,
-                            repeats=s_scn.thermal.repeats,
-                            scan_steps=scan_steps)
-            elif lane_exec:
-                out, temps = shardexec.run_static_grid(
-                    tables, node_of_pe, arrival, app_idx,
-                    policy=s_scn.scheduler, num_jobs=num_jobs,
-                    bins=s_scn.thermal.bins, repeats=s_scn.thermal.repeats,
-                    chunk=chunk, mesh=mesh)
-            else:
-                with _metrics.span("repro.launch"):
-                    out, temps = _sweep_grid(tables, node_of_pe, arrival,
-                                             app_idx, policy=s_scn.scheduler,
-                                             num_jobs=num_jobs,
-                                             bins=s_scn.thermal.bins,
-                                             repeats=s_scn.thermal.repeats)
+        out, temps = shardexec.run_grid(
+            tables, node_of_pe, gov_stack, plans, arrival, app_idx,
+            policy=s_scn.scheduler, num_jobs=num_jobs,
+            bins=s_scn.thermal.bins, repeats=s_scn.thermal.repeats,
+            scan_steps=scan_steps, chunk=chunk, mesh=mesh)
         with _metrics.span("repro.wait"):
             jax.block_until_ready((out, temps))
         with _metrics.span("repro.assemble"):

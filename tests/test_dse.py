@@ -15,7 +15,6 @@ from repro.core.dvfs import get_governor
 # kernels imported directly: the re-exports are deprecation shims
 from repro.core.simkernel_jax import build_tables_host, fixed_order_sum, \
     simulate_jax
-from repro.dse.batch import simulate_design_batch
 from repro.dse import (DesignBatch, DesignPoint, DesignSpace,
                        binned_power_trace, build_design_batch,
                        crowding_distance, evaluate, non_dominated_sort,
@@ -24,8 +23,18 @@ from repro.dse import (DesignBatch, DesignPoint, DesignSpace,
                        successive_halving, transient_trace)
 from repro.dse import thermal_jax
 from repro.obs import metrics as _metrics
+from repro.scenario.shardexec import _sweep_grid
 
 APPS = ["wifi_tx", "wifi_rx"]
+
+
+def _design_grid(batch, policy, arrival, app_idx):
+    """(D designs, S traces) lanes of the sweep's one grid program."""
+    out, _ = _sweep_grid(batch.tables, batch.node_of_pe, None, None, arrival,
+                         app_idx, policy=policy,
+                         num_jobs=int(arrival.shape[1]), bins=32, repeats=3,
+                         scan_steps=None)
+    return out
 
 
 def _apps():
@@ -133,7 +142,7 @@ def test_padded_batch_matches_per_design(policy):
     traces = _traces(3)
     batch = build_design_batch(points, apps)
     arrival, app_idx = stack_traces(traces)
-    out = simulate_design_batch(batch, policy, arrival, app_idx)
+    out = _design_grid(batch, policy, arrival, app_idx)
     for d, p in enumerate(points):
         tables = build_tables(p.to_db(), apps, governor=p.governor())
         for s, tr in enumerate(traces):
@@ -487,7 +496,7 @@ def test_binned_power_conserves_energy():
     traces = _traces(2)
     batch = build_design_batch([p], apps)
     arrival, app_idx = stack_traces(traces)
-    out = simulate_design_batch(batch, "etf", arrival, app_idx)
+    out = _design_grid(batch, "etf", arrival, app_idx)
     for s in range(len(traces)):
         trace_kw, dt_us = binned_power_trace(
             out["start"][0, s], out["finish"][0, s], out["onpe"][0, s],
@@ -526,7 +535,7 @@ def test_peak_temperature_grid_monotone_in_power():
     traces = [poisson_trace(40.0, 16, ["wifi_tx"], seed=0)]
     batch = build_design_batch(points, apps)
     arrival, app_idx = stack_traces(traces)
-    out = simulate_design_batch(batch, "etf", arrival, app_idx)
+    out = _design_grid(batch, "etf", arrival, app_idx)
     temps = np.asarray(peak_temperature_grid(
         out, batch.node_of_pe, batch.tables.power_active,
         batch.tables.power_idle))

@@ -19,7 +19,7 @@ from repro.core import simkernel_ref as skr
 from repro.core.dvfs import OndemandGovernor, UserspaceGovernor
 from repro.core.resources import CPU_BIG, CPU_LITTLE, make_soc_table2
 from repro.core.schedulers import get_scheduler
-from repro.dse import DesignPoint, build_design_batch, stack_traces
+from repro.dse import DesignPoint
 from repro.scenario import (FaultSpec, Result, Scenario, ThermalSpec,
                             TraceSpec, run, sweep)
 from repro.scenario.sweep import compile_count
@@ -255,19 +255,6 @@ def test_core_simulate_jax_shim_warns_matches_and_aliases():
     res = run(SCN, backend="jax")
     np.testing.assert_array_equal(np.asarray(out["avg_job_latency_us"]),
                                   res.avg_latency_us)
-
-
-def test_dse_simulate_design_batch_shim_warns_and_matches():
-    points = [DesignPoint(2, 2, 1, 1, 0)]
-    batch = build_design_batch(points, MIX.applications())
-    arrival, app_idx = stack_traces([MIX.job_trace()])
-    with pytest.warns(DeprecationWarning, match="repro.scenario"):
-        out = dse.simulate_design_batch(batch, "etf", arrival, app_idx)
-    assert "energy_mj" not in out          # one-release alias key removed
-    sr = sweep(MIX.replace(governor="design"),
-               axes={"design": points, "seed": [MIX.trace.seed]})
-    assert np.asarray(out["avg_job_latency_us"])[0, 0] \
-        == sr.avg_latency_us[0, 0]
 
 
 def test_energy_mj_aliases_removed():

@@ -3,8 +3,8 @@
 The lane-scaling contract: ``sweep(..., chunk=N)`` and ``sweep(...,
 shard=True)`` are *bit-for-bit* equal to the plain single-device sweep —
 including uneven lane counts (pad lanes are dropped) and telemetry replay —
-because lanes are independent simulations and the chunk programs run the
-same fused grid bodies.  Multi-device sharding is exercised in a subprocess
+because lanes are independent simulations and every launch runs the one
+grid program (``shardexec._sweep_grid``).  Multi-device sharding is exercised in a subprocess
 (the forced 8-device CPU topology must not leak into other tests).
 """
 import os
@@ -19,9 +19,11 @@ import pytest
 from jax.sharding import Mesh
 
 from repro.dse import DesignPoint
+from repro.dse.batch import stack_traces
 from repro.obs import metrics
-from repro.scenario import Scenario, TraceSpec, sweep
+from repro.scenario import FaultSpec, Scenario, TraceSpec, run, sweep
 from repro.scenario import shardexec
+from repro.scenario.sweep import _apply_axes, _design_lanes, compile_count
 from repro.sharding import LANE_AXIS, lane_sharding
 
 SCN = Scenario(apps=("wifi_tx",), scheduler="etf", governor="design",
@@ -69,35 +71,99 @@ def test_pad_lane_axis_repeats_lane0():
     assert shardexec.pad_lane_axis(tree, 3, 3) is tree
 
 
-# ------------------------------------------------ chunked == plain (1 device)
+# ------------------------------------------------ the one grid program
 
-def test_chunked_static_sweep_bitexact():
-    """chunk=2 over 5 uneven design lanes: equal to the plain sweep, with
-    the streaming counters accounting for every chunk and pad lane."""
-    axes = {"design": POINTS, "seed": [0, 1]}
-    plain = sweep(SCN, axes=axes)
+FAULT_SETS = [(), (FaultSpec(0, 400.0),)]
+POLICY_PARAMS = [(("up_threshold", 0.5 + 0.08 * i),) for i in range(3)]
+# one job count per case below, so that every case is a variant shape no
+# other test has compiled yet
+CASE_JOBS = iter((23, 29, 31, 37, 41, 43, 47, 53))
+
+
+def _assert_lanes_equal_run(sr, base, axes):
+    """Every lane of ``sr`` is bit-equal to its per-point jax ``run()``."""
+    names = list(axes)
+    for idx in np.ndindex(*sr.shape):
+        scn = _apply_axes(base, names,
+                          [axes[n][i] for n, i in zip(names, idx)])
+        r = run(scn, backend="jax")
+        P = scn.design.num_pes
+        assert sr.avg_latency_us[idx] == r.avg_latency_us, idx
+        assert sr.makespan_us[idx] == r.makespan_us, idx
+        assert sr.energy_j[idx] == r.energy_j, idx
+        assert sr.peak_temp_c[idx] == r.peak_temp_c, idx
+        assert np.array_equal(sr.busy_per_pe_us[idx][:P],
+                              np.asarray(r.raw["busy_per_pe_us"])), idx
+
+
+@pytest.mark.parametrize("chunk", [None, 2], ids=["direct", "chunk2"])
+@pytest.mark.parametrize("faulted", [False, True],
+                         ids=["nofaults", "faults"])
+@pytest.mark.parametrize("dynamic", [False, True],
+                         ids=["static", "dynamic"])
+def test_grid_program_lanes_equal_run(dynamic, faulted, chunk):
+    """Each variant of the one grid program, launched directly or streamed
+    in 2-lane chunks: every lane of ``sweep()`` equals the per-point
+    ``run(backend="jax")``, and each new variant shape is one compile.
+    Chunked static grids stream 5 uneven design lanes; chunked DTPM grids
+    stream whichever lane axis is wider, the policy axis (G > D) and the
+    design axis (D > G) in turn."""
+    base = SCN.replace(**{"trace.num_jobs": next(CASE_JOBS)})
+    fault_axis = {"faults": FAULT_SETS} if faulted else {}
+    if dynamic:
+        base = base.replace(governor="ondemand")
+        grids = [{"governor_params": POLICY_PARAMS, "seed": [0]},
+                 {"design": POINTS[:3], "governor_params": POLICY_PARAMS[:2],
+                  "seed": [0]}]
+    else:
+        grids = [{"design": POINTS, "seed": [0, 1]}]
     chunks = metrics.counter("scenario.sweep.chunks")
     pads = metrics.counter("scenario.shard.pad_lanes")
-    c0, p0 = chunks.value, pads.value
-    chunked = sweep(SCN, axes=axes, chunk=2)
-    _assert_bitexact(plain, chunked)
-    assert chunks.value - c0 == 3          # ceil(5 / 2)
-    assert pads.value - p0 == 1            # last chunk holds 1 real lane
-    assert metrics.counter("scenario.shard.devices").value == 1
+    for grid in grids:
+        axes = {**fault_axis, **grid}
+        n0, c0, p0 = compile_count.value, chunks.value, pads.value
+        sr = sweep(base, axes=axes, chunk=chunk)
+        assert compile_count.value - n0 == 1
+        _assert_lanes_equal_run(sr, base, axes)
+        if chunk is None:
+            assert chunks.value == c0
+        elif not dynamic:
+            assert chunks.value - c0 == 3          # ceil(5 / 2)
+            assert pads.value - p0 == 1            # last chunk: 1 real lane
+            assert metrics.counter("scenario.shard.devices").value == 1
 
 
-def test_chunked_dtpm_sweep_bitexact_both_lane_axes():
-    """The DTPM grid streams whichever lane axis is wider: the design axis
-    (D >= G) and the stacked GovernorPolicy axis (G > D) both chunk clean."""
-    scn = SCN.replace(governor="ondemand")
-    params = [(("up_threshold", 0.5 + 0.08 * i),) for i in range(5)]
-    # G=5 > D=1: policy lanes stream
-    axes = {"governor_params": params, "seed": [0, 1]}
-    _assert_bitexact(sweep(scn, axes=axes), sweep(scn, axes=axes, chunk=2))
-    # D=3 > G=2: design lanes stream
-    axes = {"design": POINTS[:3], "governor_params": params[:2],
-            "seed": [0]}
-    _assert_bitexact(sweep(scn, axes=axes), sweep(scn, axes=axes, chunk=2))
+def test_stages_on_host_only_for_chunks_or_a_mesh():
+    mesh = Mesh(np.asarray(jax.devices()[:1]), (LANE_AXIS,))
+    assert not shardexec.stages_on_host(None, None)
+    assert shardexec.stages_on_host(2, None)
+    assert shardexec.stages_on_host(None, mesh)
+    assert shardexec.stages_on_host(2, mesh)
+
+
+def test_run_grid_direct_launch_takes_the_trees_as_given(monkeypatch):
+    """No chunk and no mesh: one launch on the device trees, with no host
+    pull, no slice and no re-placement, returning device arrays."""
+    sweep(SCN, axes={"design": POINTS[:2], "seed": [0]})  # warm the shape
+    tables, nodes = _design_lanes(SCN, ["design"], [(p,) for p in POINTS[:2]],
+                                  None)
+    arrival, app_idx = stack_traces([SCN.job_trace()])
+
+    def no_host(_):
+        raise AssertionError("the direct launch pulled lanes to the host")
+
+    monkeypatch.setattr(shardexec, "host_tree", no_host)
+    monkeypatch.setattr(shardexec, "_device_put_lanes", no_host)
+    c0, n0 = metrics.counter("scenario.sweep.chunks").value, \
+        compile_count.value
+    out, temps = shardexec.run_grid(
+        tables, nodes, None, None, arrival, app_idx, policy="etf",
+        num_jobs=SCN.trace.num_jobs, bins=SCN.thermal.bins,
+        repeats=SCN.thermal.repeats)
+    assert isinstance(temps, jax.Array) and temps.shape == (2, 1)
+    assert all(isinstance(v, jax.Array) for v in out.values())
+    assert metrics.counter("scenario.sweep.chunks").value == c0
+    assert compile_count.value == n0
 
 
 def test_chunked_telemetry_replay_bitexact():
@@ -161,8 +227,8 @@ def test_device_put_lanes_places_host_numpy_on_the_lane_sharding(
 
 def test_streaming_hides_no_warnings():
     """The streamer passes every launch warning through (a donation the
-    backend cannot use is reported, not silenced), and the grid programs
-    donate nothing, so a chunked sweep lowers without that warning."""
+    backend cannot use is reported, not silenced), and the grid program
+    donates nothing, so a chunked sweep lowers without that warning."""
     def launch(piece):
         warnings.warn("Some donated buffers were not usable: float32[4].")
         return {"x": np.asarray(piece["a"])}

@@ -12,10 +12,12 @@ from repro.core import (TableScheduler, build_tables, deterministic_trace,
                         get_application, get_scheduler, make_soc_table2,
                         poisson_trace, solve_optimal_table, wifi_tx)
 # kernels imported directly: the repro.core re-exports are deprecation shims
-from repro.core.simkernel_jax import simulate_batch, simulate_jax
+from repro.core.simkernel_jax import simulate_jax
 from repro.core.simkernel_ref import simulate
 from repro.core.applications import Application, Task
 from repro.core.resources import ALL_PROFILES, CommModel, ResourceDB, make_soc
+from repro.dse.batch import stack_tables
+from repro.scenario.shardexec import _sweep_grid
 
 
 APPS5 = ["wifi_tx", "wifi_rx", "single_carrier", "range_detection",
@@ -77,12 +79,15 @@ def test_batched_vmap_matches_loop():
     tables = build_tables(db, [app])
     traces = [poisson_trace(r, 40, ["wifi_tx"], seed=s)
               for r in (5.0, 30.0) for s in (0, 1)]
-    arr = np.stack([t.arrival_us for t in traces])
-    idx = np.stack([t.app_index for t in traces])
-    batch = simulate_batch(tables, "etf", arr, idx)
+    arr = np.stack([t.arrival_us for t in traces]).astype(np.float32)
+    idx = np.stack([t.app_index for t in traces]).astype(np.int32)
+    one = stack_tables([tables])                   # a single design lane
+    batch, _ = _sweep_grid(one, one.node_of_pe, None, None, arr, idx,
+                           policy="etf", num_jobs=40, bins=32, repeats=3,
+                           scan_steps=None)
     for k, t in enumerate(traces):
         single = simulate_jax(tables, "etf", t.arrival_us, t.app_index)
-        np.testing.assert_allclose(float(batch["avg_job_latency_us"][k]),
+        np.testing.assert_allclose(float(batch["avg_job_latency_us"][0, k]),
                                    float(single["avg_job_latency_us"]),
                                    rtol=1e-6)
 

@@ -104,8 +104,8 @@ def test_faulted_static_grid_compiles_for_v5e(one_chip):
     arrival, app_idx = stack_traces([scn.with_seed(s).job_trace()
                                      for s in range(4)])
     nodes = np.zeros((1, tables.num_pes), np.int32)
-    shardexec._sweep_grid_faults.lower(
-        _specs(tables, one_chip), _specs(nodes, one_chip),
+    shardexec._sweep_grid.lower(
+        _specs(tables, one_chip), _specs(nodes, one_chip), None,
         _specs(plans, one_chip), _specs(arrival, one_chip),
         _specs(app_idx, one_chip), policy="etf", num_jobs=64, bins=32,
         repeats=3,
@@ -126,9 +126,10 @@ def test_dse_grid_shards_over_four_chips_without_collectives(topo):
     arrival, app_idx = stack_traces([base.with_seed(s).job_trace()
                                      for s in range(4)])
     compiled = shardexec._sweep_grid.lower(
-        _specs(batch.tables, lanes), _specs(batch.node_of_pe, lanes),
-        _specs(arrival, replicated), _specs(app_idx, replicated),
-        policy="etf", num_jobs=32, bins=32, repeats=3).compile()
+        _specs(batch.tables, lanes), _specs(batch.node_of_pe, lanes), None,
+        None, _specs(arrival, replicated), _specs(app_idx, replicated),
+        policy="etf", num_jobs=32, bins=32, repeats=3,
+        scan_steps=None).compile()
     text = compiled.as_text()
     assert not [c for c in COLLECTIVES if c in text]
 
@@ -145,9 +146,10 @@ def test_cpi_grid_compiles_for_v5e(one_chip):
                                      for s in range(6)])
     compiled = shardexec._sweep_grid.lower(
         _specs(tb, one_chip),
-        _specs(np.zeros((1, tb.num_pes), np.int32), one_chip),
+        _specs(np.zeros((1, tb.num_pes), np.int32), one_chip), None, None,
         _specs(arrival, one_chip), _specs(app_idx, one_chip),
-        policy="etf", num_jobs=16, bins=32, repeats=3).compile()
+        policy="etf", num_jobs=16, bins=32, repeats=3,
+        scan_steps=None).compile()
     # most of it is the binned thermal scan's (lanes, J*T, bins, P) terms
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
@@ -170,15 +172,16 @@ def test_table2_space_shards_over_four_chips(topo):
     arrival, app_idx = stack_traces([base.with_seed(s).job_trace()
                                      for s in range(4)])
     compiled = shardexec._sweep_grid.lower(
-        _specs(batch.tables, lanes), _specs(batch.node_of_pe, lanes),
-        _specs(arrival, replicated), _specs(app_idx, replicated),
-        policy="etf", num_jobs=32, bins=32, repeats=3).compile()
+        _specs(batch.tables, lanes), _specs(batch.node_of_pe, lanes), None,
+        None, _specs(arrival, replicated), _specs(app_idx, replicated),
+        policy="etf", num_jobs=32, bins=32, repeats=3,
+        scan_steps=None).compile()
     assert not [c for c in COLLECTIVES if c in compiled.as_text()]
 
 
 @pytest.mark.parametrize("governor", ["performance", "ondemand"])
 def test_lane_grid_programs_hold_no_contraction(governor):
-    """The grid programs sum with masked reduces, never a contraction: at
+    """The grid program sums with masked reduces, never a contraction: at
     DEFAULT precision the TPU runs f32 contractions as bfloat16 passes, and
     it accumulates them in an order that changes with the lane width
     (DESIGN.md §1)."""
@@ -186,14 +189,12 @@ def test_lane_grid_programs_hold_no_contraction(governor):
     tb = jax.tree_util.tree_map(lambda x: jnp.asarray(x)[None],
                                 tables_for(scn))
     arrival, app_idx = stack_traces([scn.job_trace()])
-    if governor == "performance":
-        lowered = shardexec._sweep_grid.lower(
-            tb, jnp.zeros((1, tb.num_pes), jnp.int32), arrival, app_idx,
-            policy="etf", num_jobs=150, bins=32, repeats=3)
-    else:
-        lowered = shardexec._sweep_grid_dtpm.lower(
-            tb, stack_policies([scn.make_policy()]), arrival, app_idx,
-            policy="etf", num_jobs=150)
+    gov = (stack_policies([scn.make_policy()]) if governor == "ondemand"
+           else None)
+    lowered = shardexec._sweep_grid.lower(
+        tb, jnp.zeros((1, tb.num_pes), jnp.int32), gov, None, arrival,
+        app_idx, policy="etf", num_jobs=150, bins=32, repeats=3,
+        scan_steps=None)
     assert "dot_general" not in lowered.as_text()
 
 
