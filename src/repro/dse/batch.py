@@ -60,7 +60,7 @@ def stack_tables(tables: Sequence[SimTables], host: bool = False) -> SimTables:
     """Leaf-wise stack of identically-shaped SimTables into (D, …) tensors.
 
     ``host=True`` stacks into numpy leaves instead of device arrays — the
-    form the chunked/sharded executor (``scenario.shardexec``) streams from,
+    form the chunked executor (``scenario.shardexec``) streams from,
     so a grid larger than device memory is never device-resident at once.
     """
     shapes = {(t.t_max, t.num_pes) for t in tables}

@@ -76,7 +76,7 @@ def tables_for(scn: Scenario, pad_pes: Optional[int] = None,
                host: bool = False) -> SimTables:
     """The scenario's ``SimTables`` (identical to the legacy ``build_tables``
     call), cached across traces/thermal settings.  ``host=True`` returns the
-    numpy-leaf form the chunked/sharded sweep executor streams from
+    numpy-leaf form the chunked sweep executor streams from
     (DESIGN.md §13)."""
     if host:
         return _cached_tables_host(_tables_key(scn), pad_pes)

@@ -9,21 +9,26 @@ dynamic :class:`~repro.core.dvfs.GovernorPolicy` stacks (G) and traces
 stack runs the closed-loop DTPM kernel, whose inline RC loop gives the peak
 temperature.
 
-``run_grid`` is the only executor.  With no chunk and no lane mesh it
-launches the program once on the trees as they are.  Otherwise it scales
-the wider of the design and policy axes two ways, composably:
+``run_grid`` is the only executor.  With no chunk it launches the program
+once; otherwise it streams.  It scales the wider of the design and policy
+axes two ways, composably:
 
-* **lane sharding** — the per-chunk lane tensors are placed with a
-  ``NamedSharding`` over the 1-D lane mesh (``repro.sharding.lane_mesh``,
-  all local devices) before entering the jitted grid program, so XLA's SPMD
+* **lane sharding** — with a lane mesh (``repro.sharding.lane_mesh``, all
+  local devices) the lane tensors are placed with a ``NamedSharding`` over
+  its one axis before entering the jitted grid program, so XLA's SPMD
   partitioner splits the vmapped lanes across devices.  Lanes are
   independent, so partitioning never changes per-lane numerics: sharded
-  results are bit-for-bit equal to the single-device sweep.
+  results are bit-for-bit equal to the single-device sweep.  With no
+  chunk the device trees are placed device to device, never through host
+  numpy, and a design tree's placement is kept with the tree (so with its
+  ``DesignBatch``) per mesh and width: later launches on the same batch
+  find their lanes resident and transfer nothing.
 * **chunked streaming** — lanes stream through ONE compiled program in
   fixed-shape chunks (``sweep(..., chunk=N)``): the stacked lane tensors
-  stay host-resident (numpy leaves, see :func:`stages_on_host`) and only one
-  chunk is device-resident at a time, so peak device memory is O(chunk),
-  not O(grid).
+  stay host-resident (numpy leaves, see :func:`stages_on_host`, the one
+  rule for host staging: chunks only) and only one chunk is
+  device-resident at a time, so peak device memory is O(chunk), not
+  O(grid).
 
 Both pad the lane count up to the chunk/device quantum by repeating lane 0.
 Unlike ``dse.batch``'s *in-kernel* inert padding (BIG latency, zero power),
@@ -34,11 +39,13 @@ so chunking and uneven lane counts never add compiles: one trace per
 (policy shape, chunk width).
 
 Observability: ``scenario.shard.devices`` (lane-mesh width of the most
-recent launch), ``scenario.shard.pad_lanes`` (inert lanes added) and
-``scenario.sweep.chunks`` (chunks streamed) in the ``obs.metrics`` registry;
-each chunk's slice, pad and placement is a ``repro.stage`` span, its
-launch a ``repro.launch`` span, and the concatenation a ``repro.wait`` then
-``repro.assemble`` span (DESIGN.md §11).
+recent launch), ``scenario.shard.pad_lanes`` (inert lanes added),
+``scenario.shard.resident_hits`` (mesh launches that found their design
+lanes already placed) and ``scenario.sweep.chunks`` (chunks streamed) in
+the ``obs.metrics`` registry; each placement or chunk's slice, pad and
+placement is a ``repro.stage`` span, each launch a ``repro.launch`` span,
+and a chunked concatenation a ``repro.wait`` then ``repro.assemble`` span
+(DESIGN.md §11).
 """
 from __future__ import annotations
 
@@ -48,6 +55,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.simkernel_jax import _simulate, _simulate_dtpm
 from ..dse.thermal_jax import peak_temperature_grid
@@ -58,6 +66,8 @@ from ..sharding import lane_count, lane_mesh, lane_sharding
 shard_devices = _metrics.counter("scenario.shard.devices")
 # cumulative inert pad lanes added for chunk/device-count divisibility
 shard_pad_lanes = _metrics.counter("scenario.shard.pad_lanes")
+# cumulative unchunked mesh launches whose design lanes were already placed
+shard_resident_hits = _metrics.counter("scenario.shard.resident_hits")
 # cumulative fixed-shape chunks streamed through the grid program
 sweep_chunks = _metrics.counter("scenario.sweep.chunks")
 # the sweep's one-program-per-policy-shape trace counter (re-exported as
@@ -84,21 +94,23 @@ def padded_width(lanes: int, chunk: Optional[int], quantum: int) -> int:
 def pad_lane_axis(tree, lanes: int, width: int, axis: int = 0):
     """Pad every leaf's lane ``axis`` from ``lanes`` up to ``width`` by
     repeating lane 0 — pad lanes are real, independent simulations whose
-    outputs are dropped, so padding is inert by construction."""
+    outputs are dropped, so padding is inert by construction.  Host numpy
+    leaves pad on the host, device arrays on their device."""
     if lanes == width:
         return tree
 
     def _pad(x):
-        reps = np.take(x, np.zeros(width - lanes, np.intp), axis=axis)
-        return np.concatenate([np.asarray(x), reps], axis=axis)
+        xp = jnp if isinstance(x, jax.Array) else np
+        reps = xp.take(x, xp.zeros(width - lanes, xp.int32), axis=axis)
+        return xp.concatenate([x, reps], axis=axis)
 
     return jax.tree_util.tree_map(_pad, tree)
 
 
 def _device_put_lanes(tree, mesh):
-    """Place a chunk's host lane tensors: straight onto the lane sharding
-    when a mesh is installed (never staged through one device first),
-    default single-device placement otherwise."""
+    """Place lane tensors (a chunk's host numpy, or device arrays): straight
+    onto the lane sharding when a mesh is installed (never staged through
+    one device first), default single-device placement otherwise."""
     return jax.device_put(tree, None if mesh is None else lane_sharding(mesh))
 
 
@@ -163,11 +175,49 @@ def _sweep_grid(tables, node_of_pe, gov, fplans, arrival, app_idx, *,
 # The executor
 # --------------------------------------------------------------------------
 
-def stages_on_host(chunk: Optional[int], mesh) -> bool:
-    """Whether a sweep stacks its lane tensors on the host: the streamer
-    slices chunks from numpy leaves, while the direct launch takes the
-    device-resident trees as they are."""
-    return chunk is not None or mesh is not None
+def stages_on_host(chunk: Optional[int]) -> bool:
+    """Whether a sweep stacks its lane tensors on the host: only to stream
+    them in chunks, so that device memory stays O(chunk).  Without a chunk
+    the launch takes the device-resident trees, placing them device to
+    device when a lane mesh splits them."""
+    return chunk is not None
+
+
+def _place(tree, lanes: int, width: int, mesh, split: bool):
+    """``tree``'s device arrays on ``mesh``, device to device: padded to
+    ``width`` lanes and split on the lane sharding, or replicated whole."""
+    if not split:
+        return jax.device_put(tree, NamedSharding(mesh, P()))
+    shard_pad_lanes.inc(width - lanes)
+    return _device_put_lanes(pad_lane_axis(tree, lanes, width), mesh)
+
+
+def _on_mesh(tables, node_of_pe, gov, lanes: int, by_design: bool, mesh):
+    """The unchunked launch's trees on ``mesh``: the lane tree (designs, or
+    the wider policy stack) split, the other replicated.  Returns
+    ``(tables, node_of_pe, gov, width)``.
+
+    The design tree's placement is kept with ``tables``, keyed by the
+    mesh's device ids (``lane_mesh()`` builds a new ``Mesh`` a call) and
+    the padded width.  It lives in the instance's ``__dict__``, as
+    ``functools.cached_property`` keeps its values, so it dies with the
+    tables (and with the ``DesignBatch`` that holds them); tables are
+    frozen and their leaves immutable, so a kept placement never goes
+    stale."""
+    width = padded_width(lanes, None, lane_count(mesh))
+    shard_devices.set(lane_count(mesh))
+    kept = vars(tables).setdefault("_lane_placements", {})
+    key = (tuple(int(i) for i in mesh.device_ids.flat),
+           width if by_design else None)
+    designs = kept.get(key)
+    if designs is not None:
+        shard_resident_hits.inc()
+    else:
+        designs = kept[key] = _place((tables, node_of_pe), lanes, width,
+                                     mesh, by_design)
+    if gov is not None:
+        gov = _place(gov, lanes, width, mesh, not by_design)
+    return designs + (gov, width)
 
 
 def _stream(lane_tree, lanes: int, chunk: Optional[int], mesh,
@@ -212,24 +262,35 @@ def run_grid(tables, node_of_pe, gov, fplans, arrival, app_idx, *,
              mesh=None):
     """Run ``_sweep_grid`` over its lanes; returns ``(out, temps)``.
 
-    With no ``chunk`` and no ``mesh`` the program is launched once on the
-    trees as given.  Otherwise the wider of the design (D) and policy (G)
-    axes streams through :func:`_stream` from host numpy (the other is
-    placed whole), and the host-resident outputs hold exactly the real
-    lanes — bit-for-bit equal to the direct launch (DESIGN.md §10, §13)."""
+    With no ``chunk`` the program is launched once and returns device
+    arrays: on the trees as given when there is no ``mesh``, else on their
+    placement over it (:func:`_on_mesh`), with the pad lanes dropped.  With
+    a ``chunk`` the wider of the design (D) and policy (G) axes streams
+    through :func:`_stream` from host numpy (the other is placed whole),
+    and the host-resident outputs hold exactly the real lanes.  Every path
+    is bit-for-bit equal to the plain launch (DESIGN.md §10, §13)."""
     kw = dict(policy=policy, num_jobs=num_jobs, bins=bins, repeats=repeats,
               scan_steps=scan_steps)
-    if not stages_on_host(chunk, mesh):
+    D = tables.exec_us.shape[0]
+    G = 0 if gov is None else gov.up_threshold.shape[0]
+    by_design = D >= G
+    lanes = D if by_design else G
+    # the lane axis sits after the fault axis, the policy axis after D
+    axis = (fplans is not None) + (not by_design)
+    if chunk is None:
+        width = lanes
+        if mesh is not None:
+            with _metrics.span("repro.stage"):
+                tables, node_of_pe, gov, width = _on_mesh(
+                    tables, node_of_pe, gov, lanes, by_design, mesh)
         with _metrics.span("repro.launch"):
-            return _sweep_grid(tables, node_of_pe, gov, fplans, arrival,
-                               app_idx, **kw)
+            out = _sweep_grid(tables, node_of_pe, gov, fplans, arrival,
+                              app_idx, **kw)
+        return out if width == lanes else _slice_lanes(out, 0, lanes, axis)
     with _metrics.span("repro.stage"):
-        D = tables.exec_us.shape[0]
-        G = 0 if gov is None else gov.up_threshold.shape[0]
         designs = (host_tree(tables), host_tree(node_of_pe))
         fdev = None if fplans is None else jnp.asarray(fplans, jnp.float32)
-        by_design = D >= G
-        lane_tree, lanes = (designs, D) if by_design else (host_tree(gov), G)
+        lane_tree = designs if by_design else host_tree(gov)
         whole = jax.tree_util.tree_map(
             jnp.asarray, host_tree(gov) if by_design else designs)
 
@@ -237,8 +298,6 @@ def run_grid(tables, node_of_pe, gov, fplans, arrival, app_idx, *,
         (tb, nodes), g = (piece, whole) if by_design else (whole, piece)
         return _sweep_grid(tb, nodes, g, fdev, arrival, app_idx, **kw)
 
-    # the streamed axis sits after the fault axis, the policy axis after D
-    axis = (fplans is not None) + (not by_design)
     return _concat_out(_stream(lane_tree, lanes, chunk, mesh, launch), lanes,
                        axis=axis)
 

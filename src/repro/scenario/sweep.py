@@ -132,8 +132,8 @@ def _design_lanes(base: Scenario, design_axes: List[str],
                   host: bool = False):
     """Padded+stacked tables and thermal-node map for the design lanes.
 
-    ``host=True`` stacks numpy leaves (the chunked/sharded executor's
-    streaming source — the full grid never becomes device-resident)."""
+    ``host=True`` stacks numpy leaves (the chunked executor's streaming
+    source — the full grid never becomes device-resident)."""
     scns = [_apply_axes(base, design_axes, c) for c in combos]
     dbs = [s.soc() for s in scns]
     P = max(db.num_pes for db in dbs)
@@ -175,7 +175,9 @@ def sweep(scenario: Scenario, axes: Dict[str, Sequence],
     through ONE compiled program in fixed-shape N-lane chunks, bounding peak device memory at O(chunk) instead of
     O(grid).  Both are bit-for-bit equal to the unsharded sweep — lanes are
     independent, and uneven lane counts are padded with inert (dropped)
-    lanes — and neither adds compiles per policy shape.
+    lanes — and neither adds compiles per policy shape.  A sharded sweep
+    on a ``design_batch`` keeps the batch's placement on the lane mesh, so
+    later sweeps on the same batch transfer no tables.
 
     The whole call is the host span ``repro.sweep`` (DESIGN.md §11).
     """
@@ -333,7 +335,7 @@ def _sweep(scenario: Scenario, axes: Dict[str, Sequence], backend: str,
         if design_batch is None and not rebuild_per_combo:
             tables, node_of_pe = _design_lanes(
                 lane_base, design_axes, design_combos, pad_pes,
-                host=shardexec.stages_on_host(chunk, mesh))
+                host=shardexec.stages_on_host(chunk))
 
         gov_stack = stack_policies(policies) if dynamic else None
 
@@ -363,7 +365,7 @@ def _sweep(scenario: Scenario, axes: Dict[str, Sequence], backend: str,
             with _metrics.span("repro.stage"):
                 tables, node_of_pe = _design_lanes(
                     s_scn, design_axes, design_combos, pad_pes,
-                    host=shardexec.stages_on_host(chunk, mesh))
+                    host=shardexec.stages_on_host(chunk))
         count_scan_steps(lanes, num_jobs, int(tables.t_max), scan_steps)
         out, temps = shardexec.run_grid(
             tables, node_of_pe, gov_stack, plans, arrival, app_idx,

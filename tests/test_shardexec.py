@@ -71,6 +71,18 @@ def test_pad_lane_axis_repeats_lane0():
     assert shardexec.pad_lane_axis(tree, 3, 3) is tree
 
 
+def test_pad_lane_axis_pads_device_arrays_on_the_device():
+    """Device leaves pad with device ops (the unchunked mesh path places
+    device trees, never host numpy), equal to the host padding."""
+    host = {"a": np.arange(6.0).reshape(3, 2), "b": np.arange(3)}
+    dev = jax.tree_util.tree_map(jax.numpy.asarray, host)
+    out = shardexec.pad_lane_axis(dev, 3, 5)
+    assert all(isinstance(x, jax.Array) for x in out.values())
+    want = shardexec.pad_lane_axis(host, 3, 5)
+    for k in host:
+        np.testing.assert_array_equal(np.asarray(out[k]), want[k])
+
+
 # ------------------------------------------------ the one grid program
 
 FAULT_SETS = [(), (FaultSpec(0, 400.0),)]
@@ -133,12 +145,71 @@ def test_grid_program_lanes_equal_run(dynamic, faulted, chunk):
             assert metrics.counter("scenario.shard.devices").value == 1
 
 
-def test_stages_on_host_only_for_chunks_or_a_mesh():
-    mesh = Mesh(np.asarray(jax.devices()[:1]), (LANE_AXIS,))
-    assert not shardexec.stages_on_host(None, None)
-    assert shardexec.stages_on_host(2, None)
-    assert shardexec.stages_on_host(None, mesh)
-    assert shardexec.stages_on_host(2, mesh)
+def _one_device_mesh():
+    return Mesh(np.asarray(jax.devices()[:1]), (LANE_AXIS,))
+
+
+@pytest.mark.parametrize("chunk", [None, 2], ids=["nochunk", "chunk2"])
+@pytest.mark.parametrize("with_mesh", [False, True], ids=["nomesh", "mesh"])
+def test_stages_on_host_only_for_chunks(chunk, with_mesh, monkeypatch):
+    """Host staging is for chunks alone: ``run_grid`` pulls its lanes to
+    host numpy exactly when a chunk is set, with or without a lane mesh."""
+    mesh = _one_device_mesh() if with_mesh else None
+    assert shardexec.stages_on_host(chunk) == (chunk is not None)
+    tables, nodes = _design_lanes(SCN, ["design"], [(p,) for p in POINTS[:2]],
+                                  None, host=shardexec.stages_on_host(chunk))
+    arrival, app_idx = stack_traces([SCN.job_trace()])
+    pulled = []
+    real = shardexec.host_tree
+    monkeypatch.setattr(shardexec, "host_tree",
+                        lambda t: pulled.append(t) or real(t))
+    out, temps = shardexec.run_grid(
+        tables, nodes, None, None, arrival, app_idx, policy="etf",
+        num_jobs=SCN.trace.num_jobs, bins=SCN.thermal.bins,
+        repeats=SCN.thermal.repeats, chunk=chunk, mesh=mesh)
+    assert bool(pulled) == (chunk is not None)
+    assert np.asarray(temps).shape == (2, 1)
+
+
+def test_run_grid_mesh_launch_places_device_trees_once(monkeypatch):
+    """No chunk on a lane mesh: the device trees are placed device to
+    device (no host pull, no numpy leaf placed), the placement is kept with
+    the tables, and a second launch on the same tables finds it resident
+    and equals the plain launch bit for bit."""
+    tables, nodes = _design_lanes(SCN, ["design"], [(p,) for p in POINTS[:3]],
+                                  None)
+    arrival, app_idx = stack_traces([SCN.job_trace()])
+    kw = dict(policy="etf", num_jobs=SCN.trace.num_jobs,
+              bins=SCN.thermal.bins, repeats=SCN.thermal.repeats)
+    plain = shardexec.run_grid(tables, nodes, None, None, arrival, app_idx,
+                               **kw)
+
+    def no_host(_):
+        raise AssertionError("the mesh launch pulled lanes to the host")
+
+    placed = []
+    real_put = shardexec._device_put_lanes
+
+    def device_only(tree, mesh):
+        leaves = jax.tree_util.tree_leaves(tree)
+        assert all(isinstance(x, jax.Array) for x in leaves)
+        placed.append(len(leaves))
+        return real_put(tree, mesh)
+
+    monkeypatch.setattr(shardexec, "host_tree", no_host)
+    monkeypatch.setattr(shardexec, "_device_put_lanes", device_only)
+    hits = metrics.counter("scenario.shard.resident_hits")
+    for n in range(2):
+        h0 = hits.value
+        out = shardexec.run_grid(tables, nodes, None, None, arrival, app_idx,
+                                 mesh=_one_device_mesh(), **kw)
+        assert hits.value - h0 == n
+        assert len(placed) == 1
+        for a, b in zip(jax.tree_util.tree_leaves(plain),
+                        jax.tree_util.tree_leaves(out)):
+            assert isinstance(b, jax.Array)
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert metrics.counter("scenario.shard.devices").value == 1
 
 
 def test_run_grid_direct_launch_takes_the_trees_as_given(monkeypatch):
@@ -206,7 +277,7 @@ def test_device_put_lanes_places_host_numpy_on_the_lane_sharding(
         monkeypatch):
     """A host chunk goes straight onto the lane sharding in one placement,
     not first onto one device and then resharded."""
-    mesh = Mesh(np.asarray(jax.devices()[:1]), (LANE_AXIS,))
+    mesh = _one_device_mesh()
     calls = []
     real_put = jax.device_put
 
@@ -250,7 +321,7 @@ def test_resolve_mesh_single_device():
 
 # ------------------------------------------------ multi-device (subprocess)
 
-SCRIPT = textwrap.dedent("""
+PRELUDE = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np
@@ -265,7 +336,6 @@ SCRIPT = textwrap.dedent("""
                                    seed=3))
     points = [DesignPoint(cross_cluster_penalty=1.0 + 0.5 * i)
               for i in range(5)]
-    axes = {"design": points, "seed": [0, 1]}
     FIELDS = ("avg_latency_us", "makespan_us", "energy_j", "peak_temp_c",
               "busy_per_pe_us")
 
@@ -277,7 +347,10 @@ SCRIPT = textwrap.dedent("""
             for ta, tb in zip(a.telemetry.ravel(), b.telemetry.ravel()):
                 assert np.array_equal(ta.util, tb.util)
                 assert np.array_equal(ta.temps_c, tb.temps_c)
+""")
 
+SCRIPT = PRELUDE + textwrap.dedent("""
+    axes = {"design": points, "seed": [0, 1]}
     plain = sweep(SCN, axes=axes, shard=False)
     pads = metrics.counter("scenario.shard.pad_lanes")
 
@@ -301,11 +374,90 @@ SCRIPT = textwrap.dedent("""
     print("SHARD_OK")
 """)
 
+RESIDENT_SCRIPT = PRELUDE + textwrap.dedent("""
+    import gc
+    import weakref
+    from repro.core.applications import get_application
+    from repro.core.dvfs import get_governor
+    from repro.dse import build_design_batch, evaluate
+    from repro.scenario import shardexec
 
-def test_sharded_sweep_bitexact_8_virtual_devices():
+    apps = [get_application("wifi_tx")]
+    traces = [SCN.replace(**{"trace.seed": s}).job_trace() for s in (0, 1)]
+    batches = {"design": build_design_batch(points, apps),
+               "ondemand": build_design_batch(
+                   points, apps, governor=get_governor("ondemand"))}
+
+    def ev(gov, batch, shard):
+        return evaluate(points, apps, traces, batch=batch, governor=gov,
+                        shard=shard)
+
+    def same(a, b):
+        for f in ("latency_per_trace_us", "energy_per_trace_j",
+                  "temp_per_trace_c"):
+            assert np.array_equal(getattr(a, f), getattr(b, f)), f
+
+    plain = {g: ev(g, b, False) for g, b in batches.items()}
+
+    # the unchunked mesh path never stages on the host: no host pull, and
+    # every placement takes device arrays
+    def no_host(_):
+        raise AssertionError("the unchunked mesh path pulled lanes to host")
+
+    real_put = shardexec._device_put_lanes
+
+    def device_only(tree, mesh):
+        assert all(isinstance(x, jax.Array)
+                   for x in jax.tree_util.tree_leaves(tree))
+        return real_put(tree, mesh)
+
+    shardexec.host_tree = no_host
+    shardexec._device_put_lanes = device_only
+    hits = metrics.counter("scenario.shard.resident_hits")
+    pads = metrics.counter("scenario.shard.pad_lanes")
+
+    # 5 uneven designs over 8 devices (3 pad lanes dropped), static and
+    # dynamic-governor batches: bit for bit; the second call on a batch
+    # finds its lanes resident and pads nothing more
+    for g, batch in batches.items():
+        h0, p0 = hits.value, pads.value
+        same(plain[g], ev(g, batch, True))
+        assert (hits.value, pads.value) == (h0, p0 + 3)
+        assert metrics.counter("scenario.shard.devices").value == 8
+        same(plain[g], ev(g, batch, True))
+        assert (hits.value, pads.value) == (h0 + 1, p0 + 3)
+
+    # a fresh batch of the same designs misses, and its kept placement is
+    # released with it
+    fresh = build_design_batch(points, apps)
+    h0 = hits.value
+    same(plain["design"], ev("design", fresh, True))
+    assert hits.value == h0
+    placed, = vars(fresh.tables)["_lane_placements"].values()
+    refs = [weakref.ref(fresh)] + [weakref.ref(x) for x in
+                                   jax.tree_util.tree_leaves(placed)]
+    del fresh, placed
+    gc.collect()
+    assert all(r() is None for r in refs)
+    print("RESIDENT_OK")
+""")
+
+
+def _run_on_8_devices(script: str) -> str:
     env = dict(os.environ, PYTHONPATH="src")
-    out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
+    out = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=420,
                          cwd=os.path.dirname(os.path.dirname(__file__)))
     assert out.returncode == 0, out.stderr[-3000:]
-    assert "SHARD_OK" in out.stdout, out.stdout
+    return out.stdout
+
+
+def test_sharded_sweep_bitexact_8_virtual_devices():
+    assert "SHARD_OK" in _run_on_8_devices(SCRIPT)
+
+
+def test_resident_lanes_8_virtual_devices():
+    """``evaluate(batch=…, shard=True)`` with no chunk: no host staging,
+    bit-for-bit equal to ``shard=False`` (uneven lanes, static and dynamic
+    governors), the placement kept with the batch and released with it."""
+    assert "RESIDENT_OK" in _run_on_8_devices(RESIDENT_SCRIPT)
